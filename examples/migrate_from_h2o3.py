@@ -12,10 +12,6 @@ unchanged while retraining moves to the TPU-native builders.
 """
 import os
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 import h2o3_tpu as h2o
 
 FIXTURES = os.path.join(os.path.dirname(os.path.dirname(

@@ -11,6 +11,7 @@ has a pure-Python fallback — absence of a toolchain degrades, never breaks.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,21 +23,35 @@ _FAILED = False
 _PKG_DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_PKG_DIR, "..", "..", "native", "csv_parser.cpp")
 _SO = os.path.join(_PKG_DIR, "_libh2o3native.so")
+#: sha256 of the source the ``.so`` was built from, written beside it: the
+#: binary is reused only when this matches, so a stale or foreign ``.so``
+#: (git-ignored, but copied with the working tree) is rebuilt
+_SO_HASH = _SO + ".sha256"
 
 
 def _build() -> str | None:
     src = os.path.abspath(_SRC)
-    if not os.path.exists(src):
+    try:
+        with open(src, "rb") as f:
+            want = hashlib.sha256(f.read()).hexdigest()
+    except OSError:
         return None
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(src):
+    try:
+        with open(_SO_HASH) as f:
+            have = f.read().strip()
+    except OSError:
+        have = None
+    if have == want and os.path.exists(_SO):
         return _SO
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
            src, "-o", _SO]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return _SO
     except (subprocess.SubprocessError, FileNotFoundError):
         return None
+    with open(_SO_HASH, "w") as f:
+        f.write(want + "\n")
+    return _SO
 
 
 def get_lib() -> ctypes.CDLL | None:

@@ -37,12 +37,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from h2o3_tpu.parallel.mesh import ROWS, get_mesh
+from jax import shard_map as _shard_map
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from h2o3_tpu.parallel.mesh import ROWS, get_mesh
 
 
 # Compiled-program cache: jit executables are tied to the wrapper instance, so
@@ -164,9 +161,11 @@ def _backoff_ms(attempt: int) -> float:
 #: error-status tags that mark a RuntimeError DETERMINISTIC, not transient:
 #: re-dispatching an OOM or an invalid program burns device time on a
 #: failure that cannot change (XlaRuntimeError subclasses RuntimeError and
-#: carries the gRPC-style status name in its message)
+#: carries the gRPC-style status name in its message). INTERNAL is what a
+#: compiler refusal (Mosaic, XLA) surfaces as — compiling the same program
+#: again under back-off cannot succeed
 _NON_TRANSIENT = ("RESOURCE_EXHAUSTED", "INVALID_ARGUMENT",
-                  "FAILED_PRECONDITION", "UNIMPLEMENTED")
+                  "FAILED_PRECONDITION", "UNIMPLEMENTED", "INTERNAL")
 
 
 def retrying(what: str, thunk: Callable, *, span=None,

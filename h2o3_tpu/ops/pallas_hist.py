@@ -84,8 +84,16 @@ def _plan(n_nodes: int, n_feat: int, n_bins_tot: int):
     return Nb, Fb
 
 
-def pallas_available(n_nodes: int, n_feat: int, n_bins_tot: int) -> bool:
-    if jax.default_backend() != "tpu":
+def pallas_available(n_nodes: int, n_feat: int, n_bins_tot: int,
+                     one_device: bool = True) -> bool:
+    """Whether this level runs on the kernel: on a TPU (or anywhere in
+    interpret mode), inside ``_plan``'s envelope, and only when the operand
+    lives on ONE device — the kernel holds no collective, so over an array
+    that spans several devices each shard's partial histogram would pass
+    for the total."""
+    if not one_device:
+        return False
+    if jax.default_backend() != "tpu" and not _INTERPRET:
         return False
     return _plan(n_nodes, n_feat, n_bins_tot) is not None
 

@@ -3,10 +3,9 @@
 Reference (PAPERS.md, the TensorFlow-serving batching design): concurrent
 small inference requests for one model enqueue; a short accumulation
 window fuses them into ONE device dispatch and each caller gets back its
-slice. Dispatch overhead (host→device transfer, executable launch, the
-~40 ms tunneled round-trip on remote TPUs) is paid once per batch instead
-of once per request — p50 moves by at most the window, throughput
-multiplies under load.
+slice. Dispatch overhead (host→device transfer, executable launch) is paid
+once per batch instead of once per request — p50 moves by at most the
+window, throughput multiplies under load.
 
 The window: with no SLO configured it is the fixed
 ``H2O3TPU_SCORE_WINDOW_MS`` (default 1 ms) — resolved at batcher
@@ -304,8 +303,8 @@ class ModelBatcher:
                 scorer = self._cache.get(entry.model, entry.schema, bucket)
                 if bspan is not None:
                     with _tr.TRACER.span("score:dispatch", kind="dispatch",
-                                         attrs={"bucket": bucket, "rows": n,
-                                                "mode": scorer.mode}):
+                                         attrs={"bucket": bucket,
+                                                "rows": n}):
                         raw = scorer.score(pnum, pcat)
                 else:
                     raw = scorer.score(pnum, pcat)

@@ -13,9 +13,8 @@ Signatures are ``(model identity, n_num, n_cat, dtype, bucket)``. A hit
 returns the warm executable (counted — the bench and tests assert the
 second same-shape request compiles nothing); a miss traces + compiles
 eagerly via ``jit(...).lower(...).compile()`` so compile cost is paid at
-miss time, never mid-batch. Models whose ``_score_raw`` cannot trace
-(host-side branches on data) fall back to an eager scorer — still batched,
-still correct, just not fused into one executable.
+miss time, never mid-batch. A model whose ``_score_raw`` does not trace or
+compile fails its request with that error: nothing is served op by op.
 """
 
 from __future__ import annotations
@@ -51,8 +50,7 @@ class CompiledScorer:
     """One signature's executable: ``score(num, cat)`` over padded host
     arrays returns host predictions ([bucket] or [bucket, K])."""
 
-    __slots__ = ("bucket", "mode", "_fn", "site", "_ncalls", "_flops",
-                 "_bytes")
+    __slots__ = ("bucket", "_fn", "site", "_ncalls", "_flops", "_bytes")
 
     def __init__(self, model, schema: ServingSchema, bucket: int):
         self.bucket = bucket
@@ -74,28 +72,21 @@ class CompiledScorer:
         # still compiles, but nothing is recorded (utils/costs.py).
         from h2o3_tpu.utils.costs import enabled as _costs_on
         site = self.site = f"score:{getattr(model, 'algo', 'model')}"
-        try:
-            with COSTS.scope(site):
-                t0 = time.perf_counter()
-                self._fn = jax.jit(raw_fn).lower(num_spec, cat_spec).compile()
-                dt = time.perf_counter() - t0
-            self.mode = "compiled"
-            flops, nbytes = self._flops, self._bytes = cost_of(self._fn)
-            if _costs_on():
-                COSTS.record_compile(
-                    site,
-                    {"args": [{"shape": list(num_spec.shape),
-                               "dtype": "float32"},
-                              {"shape": list(cat_spec.shape),
-                               "dtype": "int32"}],
-                     "statics": {"model": str(getattr(model, "key", None)),
-                                 "bucket": str(bucket)}},
-                    dt, flops, nbytes, loop="scoring")
-        except Exception:   # noqa: BLE001 — host-side branches in _score_raw
-            self._fn = raw_fn
-            self.mode = "eager"
-            if _costs_on():
-                COSTS.record_eager_fallback(site, loop="scoring")
+        with COSTS.scope(site):
+            t0 = time.perf_counter()
+            self._fn = jax.jit(raw_fn).lower(num_spec, cat_spec).compile()
+            dt = time.perf_counter() - t0
+        flops, nbytes = self._flops, self._bytes = cost_of(self._fn)
+        if _costs_on():
+            COSTS.record_compile(
+                site,
+                {"args": [{"shape": list(num_spec.shape),
+                           "dtype": "float32"},
+                          {"shape": list(cat_spec.shape),
+                           "dtype": "int32"}],
+                 "statics": {"model": str(getattr(model, "key", None)),
+                             "bucket": str(bucket)}},
+                dt, flops, nbytes, loop="scoring")
 
     def score(self, num: np.ndarray, cat: np.ndarray) -> np.ndarray:
         # the device_get below is already a sync, so timing a sampled call
@@ -103,8 +94,7 @@ class CompiledScorer:
         # into /3/Compute next to the training loops
         from h2o3_tpu.utils import costs as _costs
         n, self._ncalls = self._ncalls, self._ncalls + 1
-        sampled = (self.mode == "compiled" and _costs.enabled()
-                   and n % _costs.sample_every() == 0)
+        sampled = _costs.enabled() and n % _costs.sample_every() == 0
         t0 = time.perf_counter() if sampled else 0.0
         out = np.asarray(jax.device_get(self._fn(num, cat)))
         if sampled:

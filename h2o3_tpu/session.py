@@ -41,8 +41,8 @@ def init(port: int = 54321, strict_port: bool = False,
     from h2o3_tpu.utils import compile_cache
     from h2o3_tpu.utils.telemetry import install_log_ring
     install_log_ring()   # session startup: /3/Logs serves from here on
-    # persistent XLA compile cache (H2O3TPU_COMPILE_CACHE=1|path; repeated
-    # same-shape builds across sessions skip compile — ROADMAP item 5)
+    # persistent XLA compile cache (H2O3TPU_COMPILE_CACHE=1; repeated
+    # same-shape builds across sessions skip compile)
     compile_cache.enable()
     global _server, _client
     if _client is not None:

@@ -1,25 +1,29 @@
 """Persistent XLA compilation cache — one switch, observable hit/miss counts.
 
-The standard TPU production setup: ``jax_compilation_cache_dir`` persists
+The standard TPU production setup: JAX's persistent compilation cache keeps
 compiled executables across processes, so repeated same-shape programs
 (an AutoML leaderboard's many model configs, every serving cold start)
-stop paying compile time. The r04→r05 ``automl_leaderboard_100k`` wobble
-(32.6s→42.2s) is mostly recompiles — ROADMAP item 5's compile-cache down
-payment lives here.
+stop paying compile time.
 
-Behavior is controlled by ``H2O3TPU_COMPILE_CACHE``:
+Where the cache lives is decided OUTSIDE the code, by one rule:
 
-- unset → caller's default (``enable()`` is opt-in; ``bench.py`` and
-  session init pass ``default_on=True``/``False`` respectively);
-- ``0``/``off`` → disabled;
-- ``1``/``on`` → enabled at the default directory
-  (``~/.cache/h2o3_tpu/jax`` or ``$XDG_CACHE_HOME``);
-- any other value → enabled at that path.
+- ``JAX_COMPILATION_CACHE_DIR`` set → JAX already reads it; :func:`enable`
+  sets no directory and only reports it in ``stats()["dir"]``;
+- otherwise → ``<checkout>/.jax_cache``, derived from this package's own
+  location (a fixed path: the path is part of the cache key's context, so a
+  directory that moves — a home directory, a temp name, a pid — never hits).
+
+Whether it is on is ``H2O3TPU_COMPILE_CACHE``: unset → the caller's default
+(``bench.py`` and ``chip_smoke.py`` pass ``default_on=True``; session init
+and the launcher leave it off), ``0``/``off`` → disabled, ``1``/``on`` →
+enabled. Any other value is an error — the directory is not this variable's
+business.
 
 Hit/miss counts come from JAX's own monitoring events
-(``/jax/compilation_cache/cache_hits`` / ``cache_misses``), registered
-once at enable time; :func:`stats` snapshots them plus the on-disk entry
-count so bench artifacts can carry cache effectiveness per round.
+(``/jax/compilation_cache/cache_hits`` / ``cache_misses``; a miss is
+recorded when an entry is written, so programs under JAX's minimum compile
+time count as neither), registered once at enable time; :func:`stats`
+snapshots them plus the on-disk entry count.
 """
 
 from __future__ import annotations
@@ -31,26 +35,24 @@ _lock = threading.Lock()
 _state = {"enabled": False, "dir": None, "hits": 0, "misses": 0,
           "listener": False, "by_site": {}}
 
+_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+           "/jax/compilation_cache/cache_misses": "misses"}
 
-def _default_dir() -> str:
-    base = os.environ.get("XDG_CACHE_HOME",
-                          os.path.join(os.path.expanduser("~"), ".cache"))
-    return os.path.join(base, "h2o3_tpu", "jax")
+
+def default_dir() -> str:
+    """``<checkout>/.jax_cache`` — beside the package, identical for every
+    process that imports this checkout."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".jax_cache")
 
 
 def _on_event(event: str, **_kw) -> None:
-    # cache_misses arrives as a duration event on some jax versions and a
-    # plain event on others; both funnel here
-    if event == "/jax/compilation_cache/cache_hits":
-        kind = "hits"
-    elif event == "/jax/compilation_cache/cache_misses":
-        kind = "misses"
-    else:
+    kind = _EVENTS.get(event)
+    if kind is None:
         return
     # per-site attribution: the CostMeter site scope active at compile time
     # (an AccountedJit AOT compile, a builder's fit scope) names which loop
-    # hit/missed the persistent cache — the bench's compile_cache_per_run
-    # can then say WHICH loop recompiled, not just that one did
+    # hit/missed the persistent cache
     from h2o3_tpu.utils.costs import COSTS
     site = COSTS.active_site() or "(unattributed)"
     with _lock:
@@ -59,39 +61,29 @@ def _on_event(event: str, **_kw) -> None:
         per[kind] += 1
 
 
-def enable(cache_dir: str | None = None, *, default_on: bool = False,
-           min_compile_secs: float = 1.0) -> bool:
-    """Configure the persistent compile cache per the env policy above.
-    Returns True when the cache is active. Idempotent; never raises (an
-    old jax without the feature simply reports disabled)."""
-    env = os.environ.get("H2O3TPU_COMPILE_CACHE", "").strip()
-    if env.lower() in ("0", "off", "false"):
+def enable(*, default_on: bool = False) -> bool:
+    """Turn the persistent compile cache on per the policy above. Returns
+    True when the cache is active. Idempotent."""
+    env = os.environ.get("H2O3TPU_COMPILE_CACHE", "").strip().lower()
+    if env in ("0", "off", "false"):
         return False
-    if not env and not default_on and cache_dir is None:
+    if env and env not in ("1", "on", "true"):
+        raise ValueError(
+            f"H2O3TPU_COMPILE_CACHE={env!r}: expected 0/off or 1/on — place "
+            "the cache with JAX_COMPILATION_CACHE_DIR")
+    if not env and not default_on:
         return False
-    if env and env.lower() not in ("1", "on", "true"):
-        cache_dir = env
-    cache_dir = cache_dir or _default_dir()
-    try:
-        import jax
+    import jax
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if not cache_dir:
+        cache_dir = default_dir()
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_secs))
-    except Exception:   # noqa: BLE001 — older jax: feature absent
-        return False
     with _lock:
         _state["enabled"] = True
         _state["dir"] = cache_dir
         if not _state["listener"]:
-            try:
-                from jax._src import monitoring as _mon
-                _mon.register_event_listener(
-                    lambda event, **kw: _on_event(event, **kw))
-                _mon.register_event_duration_secs_listener(
-                    lambda event, _dur, **kw: _on_event(event, **kw))
-                _state["listener"] = True
-            except Exception:   # noqa: BLE001 — private API may move
-                pass
+            jax.monitoring.register_event_listener(_on_event)
+            _state["listener"] = True
     return True
 
 

@@ -184,15 +184,10 @@ def signature_diff(old: dict, new: dict) -> list[str]:
 
 def cost_of(compiled) -> tuple[float | None, float | None]:
     """(flops, bytes accessed) from an executable's ``cost_analysis()``;
-    (None, None) when the backend doesn't provide it. jax returns a dict on
-    some versions and a one-element list of dicts on others."""
+    (None, None) when the backend doesn't provide it."""
     try:
         ca = compiled.cost_analysis()
-    except Exception:   # noqa: BLE001 — optional on some backends
-        return None, None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
+    except (NotImplementedError, RuntimeError):   # optional on some backends
         return None, None
     flops = ca.get("flops")
     nbytes = ca.get("bytes accessed")
@@ -215,7 +210,7 @@ class CostMeter:
         self._lock = threading.Lock()
         # site -> {"loop": str|None, "signatures": OrderedDict[key, rec],
         #          "recompiles": [event], "compiles": int,
-        #          "compile_seconds": float, "eager_fallbacks": int}
+        #          "compile_seconds": float}
         self._sites: "OrderedDict[str, dict]" = OrderedDict()
         # loop -> {"samples": int, "achieved_flops_per_sec": float, ...}
         self._loops: dict[str, dict] = {}
@@ -246,7 +241,7 @@ class CostMeter:
             # graftlint: ok(_locked suffix: every caller holds self._lock)
             rec = self._sites[site] = {
                 "loop": loop, "signatures": OrderedDict(), "recompiles": [],
-                "compiles": 0, "compile_seconds": 0.0, "eager_fallbacks": 0}
+                "compiles": 0, "compile_seconds": 0.0}
         elif loop is not None and rec["loop"] is None:
             rec["loop"] = loop
         return rec
@@ -292,14 +287,6 @@ class CostMeter:
         _tm.COMPILE_SECONDS.labels(site=site).inc(float(seconds))
         if recompiled:
             _tm.RECOMPILES.labels(site=site).inc()
-
-    def record_eager_fallback(self, site: str, loop: str | None = None
-                              ) -> None:
-        """A site whose program would not AOT-compile (host-side branches):
-        it runs eagerly/jit-path, unaccounted — counted so the table says so
-        instead of silently missing."""
-        with self._lock:
-            self._site_locked(site, loop)["eager_fallbacks"] += 1
 
     def latest_cost(self, site: str) -> tuple[float | None, float | None]:
         """(flops, bytes) of the site's most recently compiled signature —
@@ -401,7 +388,6 @@ class CostMeter:
                     "site": name, "loop": rec["loop"],
                     "compiles": rec["compiles"],
                     "compile_seconds": rec["compile_seconds"],
-                    "eager_fallbacks": rec["eager_fallbacks"],
                     "flops": next((s["flops"] for s in reversed(sigs)
                                    if s["flops"] is not None), None),
                     "bytes": next((s["bytes"] for s in reversed(sigs)
@@ -464,10 +450,6 @@ COSTS = CostMeter()
 # ---------------------------------------------------------------------------
 # The accounted jit wrapper.
 
-#: sentinel for signatures whose AOT compile failed — the call falls back
-#: to the plain jit path permanently (host-side branches, unhashables)
-_AOT_FAILED = object()
-
 _MAX_EXECUTABLES = 64
 
 
@@ -515,6 +497,12 @@ class AccountedJit:
     def clear_executables(self) -> None:
         with self._lock:
             self._compiled.clear()
+
+    def executables(self) -> list:
+        """The compiled programs this wrapper holds, oldest first — what
+        actually ran, for inspection (``as_text()``, ``memory_analysis()``)."""
+        with self._lock:
+            return list(self._compiled.values())
 
     def lower(self, *args, **kwargs):
         """AOT escape hatch — delegate to the underlying ``jax.jit``'s
@@ -582,8 +570,6 @@ class AccountedJit:
                 self._compiled.move_to_end(key)
         if entry is None:
             entry = self._compile(key, statics, leaves, args, kwargs)
-        if entry is _AOT_FAILED:
-            return self._jit(*args, **kwargs)
         self._last_key = key      # unsynchronized: observability-only hint
         n = next(self._calls)
         if self._sample and (n == 0 or n % sample_every() == 0):
@@ -598,21 +584,17 @@ class AccountedJit:
         return entry(*dyn_args, **dyn_kwargs)
 
     def _compile(self, key, statics, leaves, args, kwargs):
-        import jax
-        try:
-            with COSTS.scope(self.site):
-                t0 = time.perf_counter()
-                compiled = self._jit.lower(*args, **kwargs).compile()
-                dt = time.perf_counter() - t0
-        except Exception:   # noqa: BLE001 — host-side branches etc.
-            COSTS.record_eager_fallback(self.site, self.loop)
-            compiled = _AOT_FAILED
-        else:
-            flops, nbytes = cost_of(compiled)
-            signature = {"args": [_leaf_descr(x) for x in leaves],
-                         "statics": {k: repr(v) for k, v in statics}}
-            COSTS.record_compile(self.site, signature, dt, flops, nbytes,
-                                 loop=self.loop, key=key)
+        # a trace or compiler error surfaces here, ONCE: the plain jit path
+        # would trace and compile the same program into the same failure
+        with COSTS.scope(self.site):
+            t0 = time.perf_counter()
+            compiled = self._jit.lower(*args, **kwargs).compile()
+            dt = time.perf_counter() - t0
+        flops, nbytes = cost_of(compiled)
+        signature = {"args": [_leaf_descr(x) for x in leaves],
+                     "statics": {k: repr(v) for k, v in statics}}
+        COSTS.record_compile(self.site, signature, dt, flops, nbytes,
+                             loop=self.loop, key=key)
         with self._lock:
             won = self._compiled.setdefault(key, compiled)
             while len(self._compiled) > _MAX_EXECUTABLES:

@@ -1,0 +1,118 @@
+"""chip_smoke.py off the chip: it refuses without a TPU, its ``--dry-cpu``
+rehearsal runs every stage, and the kernel it will run compiles for the chip
+it will meet (AOT, for a described v5e topology — no chip attached).
+
+The run on silicon itself is ``python chip_smoke.py`` through the chip tool
+(``.claude/skills/verify/SKILL.md``).
+"""
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(REPO, "chip_smoke.py")
+
+
+def _cpu_env(tmp_path) -> dict:
+    """One CPU device (conftest's eight-device flag stays out: the one-chip
+    path is what the rehearsal covers) and a cache of its own."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
+    return env
+
+
+def test_refuses_without_a_tpu(tmp_path):
+    t0 = time.perf_counter()
+    proc = subprocess.run([sys.executable, SMOKE], cwd=REPO,
+                          env=_cpu_env(tmp_path), capture_output=True,
+                          text=True, timeout=120)
+    assert proc.returncode != 0
+    assert time.perf_counter() - t0 < 30
+    assert proc.stdout == ""                      # no result of any kind
+    reason = proc.stderr.strip().splitlines()
+    assert len(reason) == 1 and "needs a TPU" in reason[0], proc.stderr
+
+
+def test_no_entry_point_starts_a_process():
+    """One process per chip: neither the smoke nor the bench may spawn,
+    probe in a child or re-exec — a parent that touched JAX holds the chip."""
+    for name in ("chip_smoke.py", "bench.py"):
+        with open(os.path.join(REPO, name)) as f:
+            src = f.read()
+        for token in ("subprocess", "execv", "Popen", "os.system",
+                      "multiprocessing", "jax_platforms"):
+            assert token not in src, (name, token)
+
+
+def test_dry_cpu_runs_every_stage(tmp_path):
+    proc = subprocess.run([sys.executable, SMOKE, "--dry-cpu"], cwd=REPO,
+                          env=_cpu_env(tmp_path), capture_output=True,
+                          text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = proc.stdout.strip().splitlines()
+    # last: the verdict the driver reads, exactly these keys
+    device = {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert json.loads(lines[-1]) == {"ok": True, "device": device}
+    out = json.loads(lines[-2])                   # before it: the summary
+    assert out["ok"] is True and out["dry_run"] is True
+    assert out["claim"] is None
+    assert out["device"] == device
+    assert set(out["cold_wall_s"]) == {"kernel", "train", "serve", "glm",
+                                       "deeplearning"}
+    kernel_only = {"pallas": 6, "fused_scatter": 0, "scatter": 0}
+    assert out["train"]["gbm_hist_paths"] == kernel_only
+    assert out["train"]["xgboost_hist_paths"] == kernel_only
+    assert out["compile_cache"]["dir"] == str(tmp_path / "jax_cache")
+
+
+def test_hist_kernel_compiles_for_v5e(monkeypatch):
+    """AOT: libtpu compiles for a described topology with no chip attached.
+    Both ends of the bin-storage envelope must lower to a Mosaic call."""
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from h2o3_tpu.ops import pallas_hist
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    assert pallas_hist._INTERPRET is False
+    on_chip = SingleDeviceSharding(topo.devices[0])
+    rows, feats = 1_000_000, 28
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    for n_bins_tot, dtype in ((65, jnp.int8), (257, jnp.int16)):
+        exe = pallas_hist.hist_pallas.lower(
+            spec((feats, rows), dtype), spec((rows,), jnp.int32),
+            spec((rows,), jnp.float32), spec((rows,), jnp.float32),
+            spec((rows,), jnp.float32),
+            n_nodes=64, n_bins_tot=n_bins_tot).compile()
+        assert "tpu_custom_call" in exe.as_text(), n_bins_tot
+
+
+def test_kernel_refuses_an_operand_on_several_devices(monkeypatch):
+    """The kernel holds no collective: ``hist_mesh`` tells one device from
+    several, and only the former may reach ``hist_pallas``."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from h2o3_tpu.models import tree
+    from h2o3_tpu.ops import pallas_hist
+    monkeypatch.setattr(pallas_hist, "_INTERPRET", True)   # as if on a TPU
+    assert pallas_hist.pallas_available(4, 28, 65)
+    assert not pallas_hist.pallas_available(4, 28, 65, one_device=False)
+    x = jnp.zeros((64, 4), jnp.int8)
+    assert tree.hist_mesh(x) is None                       # one device
+    odd = Mesh(np.array(jax.devices()), ("other",))
+    spread = jax.device_put(x, NamedSharding(odd, P("other", None)))
+    assert tree.hist_mesh(spread) is tree.UNFUSED          # no rows axis

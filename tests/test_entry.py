@@ -1,13 +1,11 @@
-"""Gate-artifact robustness: the driver entry points must survive a sick or
-absent TPU backend (VERDICT r3 weak #1 — round 3 lost BOTH proof artifacts
-to one unavailable chip: ``dryrun_multichip`` hung 600 s because the parent
-called ``jax.devices()``, and ``bench.py`` recorded a traceback).
+"""Entry points against a sick or absent TPU backend: ``dryrun_multichip``
+(a CPU-only virtual-mesh audit by construction) must complete without ever
+touching the default backend, and ``bench.py`` — a measurement — must refuse.
 
 Reference analog: the N-JVM localhost cloud always forms regardless of
 cluster state (``scripts/multiNodeUtils.sh:21-26``).
 """
 
-import json
 import os
 import subprocess
 import sys
@@ -67,24 +65,19 @@ def test_dryrun_completes_with_sick_backend():
     assert dt < 90, f"dryrun took {dt:.0f}s with a sick backend"
 
 
-def test_bench_smoke_falls_back_to_cpu_with_sick_backend():
-    """bench.py must emit ONE parseable JSON line (rc=0) with an explicit
-    backend_fallback annotation when the TPU backend cannot initialize."""
-    env = _sick_env()
-    env["H2O3TPU_BENCH_SMOKE"] = "1"
-    # the sick platform plugin BLOCKS during discovery in this environment
-    # (exactly the round-3 failure mode); don't wait the production 240 s
-    env["H2O3TPU_BENCH_PREFLIGHT_TIMEOUT"] = "25"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO, env=env,
-        capture_output=True, text=True, timeout=420)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    line = [l for l in proc.stdout.splitlines() if l.startswith("{")][-1]
-    out = json.loads(line)
-    assert out["metric"] == "gbm_hist_train_rows_per_sec_per_chip"
-    assert out["value"] > 0
-    assert "backend_fallback" in out["extra"], out["extra"]
-    assert out["extra"]["backend"] == "cpu"
-    # a fallback capture is a liveness probe, not evidence vs the per-chip
-    # baseline: the ratio must be null so it can never be read as one
-    assert out["vs_baseline"] is None
+def test_bench_refuses_without_a_tpu():
+    """bench.py measures on the chip or not at all: with no TPU (a backend
+    that cannot start, or plain CPU) it exits non-zero with one line, before
+    any work — no CPU re-exec, no JSON, no number."""
+    for env in (_sick_env(), dict(os.environ, JAX_PLATFORMS="cpu")):
+        env.pop("H2O3TPU_BENCH_SMOKE", None)
+        t0 = time.perf_counter()
+        proc = subprocess.run(
+            [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
+            env=env, capture_output=True, text=True, timeout=120)
+        assert proc.returncode != 0
+        assert time.perf_counter() - t0 < 30
+        assert proc.stdout == ""
+        reason = proc.stderr.strip().splitlines()
+        assert len(reason) == 1 and reason[0].startswith("bench.py:") \
+            and "TPU" in reason[0], proc.stderr[-2000:]
