@@ -7,11 +7,9 @@ while it was slow*: ``POST /3/Profiler/capture`` wraps
 Perfetto-loadable artifact (the ``*.trace.json.gz`` Chrome-trace file the
 profiler writes) for listing and download.
 
-While a capture is open, every span the tracer starts additionally enters a
-``jax.profiler.TraceAnnotation`` named after the span (via
-``tracing.SPAN_HOOK``), so the profiler timeline carries the SAME names the
-span tree uses — host spans, device ops, and XLA runtime events line up in
-one Perfetto view.
+Every span is a ``jax.profiler.TraceAnnotation`` of the same name
+(``tracing.annotation``) in any profiler session, this one included: host
+spans, device ops, and XLA runtime events line up in one Perfetto view.
 
 One capture at a time: the profiler runtime is process-global state, so a
 second concurrent ``capture()`` raises :class:`CaptureBusy` (the REST layer
@@ -62,8 +60,8 @@ class DeviceProfiler:
 
     def capture(self, duration_ms: int = 500, exercise: bool = True) -> dict:
         """Open a profiler trace for ``duration_ms`` (clamped to
-        [10 ms, 30 s]), annotate spans for the window, and register the
-        artifact. ``exercise`` runs one tiny traced dispatch under a
+        [10 ms, 30 s]) and register the artifact. ``exercise`` runs one
+        tiny traced dispatch under a
         ``profiler:exercise`` span so an otherwise-idle server still yields
         a non-empty, annotation-carrying capture. Raises
         :class:`CaptureBusy` when a capture is already open."""
@@ -75,13 +73,11 @@ class DeviceProfiler:
                 "runtime is process-global; retry when it completes)")
         try:
             import jax
-            from h2o3_tpu.utils import tracing as _tr
             cap_id = f"cap_{uuid.uuid4().hex[:12]}"
             out_dir = os.path.join(_base_dir(), cap_id)
             os.makedirs(out_dir, exist_ok=True)
             t0 = time.time()
             jax.profiler.start_trace(out_dir)
-            _tr.SPAN_HOOK = _annotation_hook
             try:
                 deadline = time.perf_counter() + duration_ms / 1e3
                 if exercise:
@@ -90,7 +86,6 @@ class DeviceProfiler:
                     time.sleep(min(0.01, max(
                         deadline - time.perf_counter(), 0.0)))
             finally:
-                _tr.SPAN_HOOK = None
                 jax.profiler.stop_trace()
             rec = self._register(cap_id, out_dir, duration_ms, t0)
             return rec
@@ -153,20 +148,6 @@ class DeviceProfiler:
                 shutil.rmtree(os.path.join(_base_dir(), rec["capture_id"]),
                               ignore_errors=True)
             self._captures.clear()
-
-
-def _annotation_hook(name: str):
-    """``tracing.SPAN_HOOK`` payload: enter a ``TraceAnnotation`` carrying
-    the span's name (shows as the event's ``long_name`` in the Chrome
-    trace). Returns the live context manager, or None when jax is absent —
-    tracing must never break on a profiler problem."""
-    try:
-        import jax
-        ann = jax.profiler.TraceAnnotation(name)
-        ann.__enter__()
-        return ann
-    except Exception:   # noqa: BLE001 — annotation is best-effort
-        return None
 
 
 PROFILER = DeviceProfiler()

@@ -82,8 +82,9 @@ class timed_event:
     from one wrapper. The same wrapper also opens a child **span** under
     the active trace (:mod:`h2o3_tpu.utils.tracing`) — IRLS iterations,
     DL epochs, and GBM chunks become span-tree nodes with zero extra
-    instrumentation at the call sites (and zero cost when no trace is
-    active: the span hook is a contextvar read returning None)."""
+    instrumentation at the call sites. The interval is a profiler
+    annotation of the same name exactly once: the span's own, or, with no
+    trace active (``builder.train()`` called directly), this wrapper's."""
 
     def __init__(self, kind: str, what: str, observe=None):
         self.kind, self.what = kind, what
@@ -93,6 +94,8 @@ class timed_event:
     def __enter__(self):
         self._scope = _tracing.TRACER.span(self.what, kind=self.kind)
         self._span = self._scope.__enter__()
+        self._ann = (_tracing.annotation(self.what)
+                     if self._span is None else None)
         if self.kind == "model":
             # device-byte attribution at build granularity (two full samples
             # per fit — never per iteration, where the live-array fallback
@@ -127,6 +130,8 @@ class timed_event:
                         self._span.trace_id, peak_device_bytes=peak)
             except Exception:   # noqa: BLE001
                 pass
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         self._scope.__exit__(*exc)
         return False
 
@@ -400,21 +405,3 @@ class inject_faults:
         # chaos scenario must never leave a thread parked on its injector
         self.injector.release_stalls()
         return False
-
-
-def start_profiler(log_dir: str) -> None:
-    """Start an XLA-level trace (reference analog: /3/Profiler; here the
-    profile is a TensorBoard-compatible jax.profiler trace, the native tool
-    for TPU kernels)."""
-    import jax
-    jax.profiler.start_trace(log_dir)
-
-
-def stop_profiler() -> None:
-    import jax
-    jax.profiler.stop_trace()
-
-
-def device_memory_profile() -> bytes:
-    import jax
-    return jax.profiler.device_memory_profile()

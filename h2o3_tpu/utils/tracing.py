@@ -19,8 +19,8 @@ Model:
 - **W3C propagation**: incoming ``traceparent`` headers join the caller's
   trace; responses carry the root span's ``traceparent`` back.
 
-Everything here is host-side stdlib — nothing is ever traced into an XLA
-program, and a span begin/end is a lock-protected dict update (~µs).
+Nothing here is ever traced into an XLA program: a span begin/end is a
+lock-protected dict update plus one profiler annotation (~µs).
 ``H2O3TPU_TRACE_OFF=1`` disables root-span creation entirely (child spans
 never start without an active trace, so the whole stack quiesces).
 """
@@ -53,16 +53,25 @@ _TRACEPARENT = re.compile(
 _CURRENT: contextvars.ContextVar["SpanContext | None"] = \
     contextvars.ContextVar("h2o3_span", default=None)
 
-#: set by utils/profiling.py while a device-profiler capture is open: every
-#: span entered during the window additionally opens a
-#: ``jax.profiler.TraceAnnotation`` named after the span, so the Perfetto
-#: capture carries span-derived names. None (the default) costs one
-#: is-not-None check per span — the always-on tracer budget is untouched.
-SPAN_HOOK = None
-
 
 def enabled() -> bool:
     return os.environ.get("H2O3TPU_TRACE_OFF", "") != "1"
+
+
+def annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name`` (the
+    event's ``long_name`` in a Chrome trace), or None without jax. Every
+    span and every span-less ``timed_event`` opens one, so the program's
+    intervals sit on the device's clock in ANY ``jax.profiler`` session,
+    whoever opened it; with none open it is ``TraceMe``'s flag test. The
+    caller exits it on the same thread."""
+    try:
+        from jax.profiler import TraceAnnotation
+        ann = TraceAnnotation(name)
+        ann.__enter__()
+        return ann
+    except Exception:   # noqa: BLE001 — annotation is best-effort
+        return None
 
 
 def trace_partitions_enabled() -> bool:
@@ -159,17 +168,13 @@ class _SpanScope:
     def __enter__(self) -> Span | None:
         if self._span is not None:
             self._token = _CURRENT.set(self._span.context)
-            if SPAN_HOOK is not None:    # device-profiler capture open
-                self._ann = SPAN_HOOK(self._span.name)
+            self._ann = annotation(self._span.name)
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         if self._span is not None:
             if self._ann is not None:
-                try:
-                    self._ann.__exit__(None, None, None)
-                except Exception:   # noqa: BLE001 — annotation best-effort
-                    pass
+                self._ann.__exit__(None, None, None)
                 self._ann = None
             if self._token is not None:
                 _CURRENT.reset(self._token)
