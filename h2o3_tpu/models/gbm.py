@@ -379,41 +379,51 @@ def _boost_scan_jit(binned, edges, yc, w, fmask_base, Fcur0, keys, hp, *,
              for k in range(nclass)], axis=1)
         return Fval + (step if drf else lr * step)
 
+    def update(i, Fcur, Fval, heap, row_leaf):
+        """The round's tail: the margin update and the tracked metric."""
+        with jax.named_scope("update"):
+            Fnew = Fcur + (row_leaf if drf else lr * row_leaf)
+            Fval = update_val(Fval, heap)
+            return (Fnew, Fval), (heap, *scores(i, Fnew, Fval))
+
+    # the round's parts are named (sample, grad, level<d>/..., update) by
+    # jax.named_scope: metadata for a profile's op_name, no operation
     if nclass <= 1:
         def body(carry, xs):
             ks, i = xs
             Fcur, Fval = carry
-            wt = sample_w(ks[0])
-            if drf:
-                g, h = -yc * wt, wt      # leaf = weighted in-node mean
-            else:
-                g, h = _grad_hess(dist, Fcur, yc, wt, quantile_alpha,
-                                  huber_alpha, tweedie_power, custom_id)
-            out = grow(g, h, wt, sample_fmask(ks[1]), ks[2])
+            with jax.named_scope("sample"):
+                wt = sample_w(ks[0])
+                fmask = sample_fmask(ks[1])
+            with jax.named_scope("grad"):
+                if drf:
+                    g, h = -yc * wt, wt      # leaf = weighted in-node mean
+                else:
+                    g, h = _grad_hess(dist, Fcur, yc, wt, quantile_alpha,
+                                      huber_alpha, tweedie_power, custom_id)
+            out = grow(g, h, wt, fmask, ks[2])
             heap, row_leaf = out[:-1], out[-1]
-            Fnew = Fcur + (row_leaf if drf else lr * row_leaf)
-            Fval = update_val(Fval, heap)
-            return (Fnew, Fval), (heap, *scores(i, Fnew, Fval))
+            return update(i, Fcur, Fval, heap, row_leaf)
     else:
         yoh = jax.nn.one_hot(yc.astype(jnp.int32), nclass)
 
         def body(carry, xs):
             ks, i = xs
             Fcur, Fval = carry
-            wt = sample_w(ks[0])
-            if drf:
-                G = -(yoh * wt[:, None])
-                H = jnp.broadcast_to(wt[:, None], G.shape)
-            else:
-                G, H = _grad_hess_multinomial(Fcur, yc, wt)
-            fmask = sample_fmask(ks[1])
-            kk = jax.random.split(ks[2], nclass)
+            with jax.named_scope("sample"):
+                wt = sample_w(ks[0])
+                fmask = sample_fmask(ks[1])
+                kk = jax.random.split(ks[2], nclass)
+            with jax.named_scope("grad"):
+                if drf:
+                    G = -(yoh * wt[:, None])
+                    H = jnp.broadcast_to(wt[:, None], G.shape)
+                else:
+                    G, H = _grad_hess_multinomial(Fcur, yc, wt)
             outs = jax.vmap(lambda gk, hk, k: grow(gk, hk, wt, fmask, k))(
                 G.T, H.T, kk)
             heap, row_leaf = outs[:-1], outs[-1]       # row_leaf: [K, R]
-            Fnew = Fcur + (row_leaf.T if drf else lr * row_leaf.T)
-            Fval = update_val(Fval, heap)
-            return (Fnew, Fval), (heap, *scores(i, Fnew, Fval))
+            return update(i, Fcur, Fval, heap, row_leaf.T)
 
     Fval0 = val[3] if val is not None else None
     idx = jnp.arange(keys.shape[0], dtype=jnp.float32)
@@ -660,10 +670,15 @@ class SharedTreeBuilder(ModelBuilder):
             # weighted edges keep the weights-as-replication contract
             # (compute_bin_edges docstring); same strided sample of rows
             w_sample = np.asarray(jax.device_get(weights[idx])).astype(np.float64)
-        edges = jnp.asarray(compute_bin_edges(sample, int(self.params["nbins"]),
-                                              w_sample))
+        # the two phases a profile reads by name: host numpy while the
+        # device waits, then the instant binning is first dispatched (it
+        # runs on asynchronously and drains at _fit's f0 fetch)
+        with timed_event("phase", f"{self.algo}:prepare.edges"):
+            edges = jnp.asarray(compute_bin_edges(
+                sample, int(self.params["nbins"]), w_sample))
         self._setup_cat_info(frame, x)
-        binned = self._bin_frame(frame, x, edges)
+        with timed_event("phase", f"{self.algo}:prepare.bin"):
+            binned = self._bin_frame(frame, x, edges)
         from h2o3_tpu.models.data_info import response_as_float
         yy, valid = response_as_float(yvec)
         domains = {c: frame.vec(c).domain for c in x if frame.vec(c).is_categorical}

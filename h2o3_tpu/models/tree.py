@@ -329,104 +329,121 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
     # parent's histogram minus the computed child's — halving the one-hot
     # contraction's node dimension (its FLOPs are ∝ N) at every level
     prev_hists = prev_do = chosen_left = None
+    # each level, and its three parts, under a jax.named_scope: trace-time
+    # metadata only, so a profile reads ``.../level3/route/...`` by name
     for d in range(depth):
         N = 2 ** d
-        lmask = feat_mask
-        if do_col_sample:
-            key, kd, kf = jax.random.split(key, 3)
-            sub = jax.random.uniform(kd, (F,)) < col_rate
-            sub = sub.at[jax.random.randint(kf, (), 0, F)].set(True)
-            lmask = feat_mask & sub
-            # the forced index may miss feat_mask; never let the level go empty
-            lmask = jnp.where(lmask.any(), lmask, feat_mask)
-        if d == 0:
-            hists = _histograms(binned, binned_T, node_local, g, h, w, N, Bt,
-                                mesh=mesh)
-        else:
-            P = N // 2
-            # chosen child id per parent; rows elsewhere mask to -1
-            chosen = (jnp.arange(P) * 2
-                      + jnp.where(chosen_left, 0, 1).astype(jnp.int32))
-            act = node_local >= 0
-            par = jnp.where(act, node_local // 2, 0)
-            at_chosen = act & (node_local == chosen[par])
-            node_slot = jnp.where(at_chosen, par, -1)
-            part = _histograms(binned, binned_T, node_slot, g, h, w, P, Bt,
-                               mesh=mesh)
-            part4 = part.reshape(F, P, Bt, 3)
-            prev4 = prev_hists.reshape(F, P, Bt, 3)
-            # sibling by subtraction — only where the parent really split
-            # (a frozen parent's children hold no rows; its stale parent
-            # histogram must not leak into phantom nodes)
-            other4 = jnp.where(prev_do[None, :, None, None],
-                               prev4 - part4, 0.0)
-            cl = chosen_left[None, :, None, None]
-            left4 = jnp.where(cl, part4, other4)
-            right4 = jnp.where(cl, other4, part4)
-            hists = jnp.stack([left4, right4], axis=2).reshape(F, N * Bt, 3)
-        (gain, feat, t, na_left, G, H, W, vl_b, vr_b, wl_b, wr_b,
-         member) = _find_splits(
-            hists, B, min_rows, reg_lambda, reg_alpha, gamma, lmask,
-            mono=mono, allowed=allowed, cat_feats=cat_feats)
-        prev_hists = hists
-        chosen_left = wl_b <= wr_b
-        do = (gain > min_split_improvement) & jnp.isfinite(gain) & (W > 0)
-        prev_do = do
-        leaf = jnp.where(do, 0.0,
-                         clamp(_leaf_value(G, H, W, reg_lambda, reg_alpha),
-                               bounds))
-        lv_feat.append(jnp.where(do, feat, -1))
-        lv_t.append(jnp.where(do, t, 0))
-        lv_tv.append(jnp.where(do, edges[feat, jnp.maximum(t - 1, 0)], 0.0))
-        lv_na.append(do & na_left)
-        lv_sp.append(do)
-        lv_leaf.append(leaf)
-        lv_gain.append(jnp.where(do, gain, 0.0))
-        lv_cover.append(W)
-        if cat_feats is not None:
-            lv_mask.append(member & do[:, None])
-        # rows whose node froze at this level take its leaf value
-        active = node_local >= 0
-        nl = jnp.where(active, node_local, 0)
-        row_leaf = jnp.where(active & ~do[nl], leaf[nl], row_leaf)
-        node_local = _route_rows(binned, node_local, lv_feat[-1], member,
-                                 na_left, do, B)
-        if bounds is not None:
-            # monotone bound propagation: split midpoint bounds the children
-            lo, hi = bounds[:, 0], bounds[:, 1]
-            mid = jnp.clip(0.5 * (vl_b + vr_b), lo, hi)
-            c = mono[feat] * do          # 0 where unconstrained or no split
-            l_lo = jnp.where(c < 0, mid, lo)
-            l_hi = jnp.where(c > 0, mid, hi)
-            r_lo = jnp.where(c > 0, mid, lo)
-            r_hi = jnp.where(c < 0, mid, hi)
-            bounds = jnp.stack(
-                [jnp.stack([l_lo, l_hi], 1), jnp.stack([r_lo, r_hi], 1)],
-                axis=1).reshape(2 * N, 2)
-        if allowed is not None:
-            child_allowed = jnp.where(do[:, None],
-                                      allowed & reach[feat], allowed)
-            allowed = jnp.repeat(child_allowed, 2, axis=0)
+        with jax.named_scope(f"level{d}"):
+            with jax.named_scope("split"):
+                lmask = feat_mask
+                if do_col_sample:
+                    key, kd, kf = jax.random.split(key, 3)
+                    sub = jax.random.uniform(kd, (F,)) < col_rate
+                    sub = sub.at[jax.random.randint(kf, (), 0, F)].set(True)
+                    lmask = feat_mask & sub
+                    # the forced index may miss feat_mask; never let the
+                    # level go empty
+                    lmask = jnp.where(lmask.any(), lmask, feat_mask)
+            with jax.named_scope("hist"):
+                if d == 0:
+                    hists = _histograms(binned, binned_T, node_local, g, h,
+                                        w, N, Bt, mesh=mesh)
+                else:
+                    P = N // 2
+                    # chosen child id per parent; rows elsewhere mask to -1
+                    chosen = (jnp.arange(P) * 2
+                              + jnp.where(chosen_left, 0, 1).astype(jnp.int32))
+                    act = node_local >= 0
+                    par = jnp.where(act, node_local // 2, 0)
+                    at_chosen = act & (node_local == chosen[par])
+                    node_slot = jnp.where(at_chosen, par, -1)
+                    part = _histograms(binned, binned_T, node_slot, g, h, w,
+                                       P, Bt, mesh=mesh)
+                    part4 = part.reshape(F, P, Bt, 3)
+                    prev4 = prev_hists.reshape(F, P, Bt, 3)
+                    # sibling by subtraction — only where the parent really
+                    # split (a frozen parent's children hold no rows; its
+                    # stale parent histogram must not leak into phantom
+                    # nodes)
+                    other4 = jnp.where(prev_do[None, :, None, None],
+                                       prev4 - part4, 0.0)
+                    cl = chosen_left[None, :, None, None]
+                    left4 = jnp.where(cl, part4, other4)
+                    right4 = jnp.where(cl, other4, part4)
+                    hists = jnp.stack([left4, right4],
+                                      axis=2).reshape(F, N * Bt, 3)
+            with jax.named_scope("split"):
+                (gain, feat, t, na_left, G, H, W, vl_b, vr_b, wl_b, wr_b,
+                 member) = _find_splits(
+                    hists, B, min_rows, reg_lambda, reg_alpha, gamma, lmask,
+                    mono=mono, allowed=allowed, cat_feats=cat_feats)
+                prev_hists = hists
+                chosen_left = wl_b <= wr_b
+                do = ((gain > min_split_improvement) & jnp.isfinite(gain)
+                      & (W > 0))
+                prev_do = do
+                leaf = jnp.where(
+                    do, 0.0,
+                    clamp(_leaf_value(G, H, W, reg_lambda, reg_alpha),
+                          bounds))
+                lv_feat.append(jnp.where(do, feat, -1))
+                lv_t.append(jnp.where(do, t, 0))
+                lv_tv.append(jnp.where(
+                    do, edges[feat, jnp.maximum(t - 1, 0)], 0.0))
+                lv_na.append(do & na_left)
+                lv_sp.append(do)
+                lv_leaf.append(leaf)
+                lv_gain.append(jnp.where(do, gain, 0.0))
+                lv_cover.append(W)
+                if cat_feats is not None:
+                    lv_mask.append(member & do[:, None])
+            with jax.named_scope("route"):
+                # rows whose node froze at this level take its leaf value
+                active = node_local >= 0
+                nl = jnp.where(active, node_local, 0)
+                row_leaf = jnp.where(active & ~do[nl], leaf[nl], row_leaf)
+                node_local = _route_rows(binned, node_local, lv_feat[-1],
+                                         member, na_left, do, B)
+            with jax.named_scope("split"):
+                if bounds is not None:
+                    # monotone bound propagation: split midpoint bounds the
+                    # children
+                    lo, hi = bounds[:, 0], bounds[:, 1]
+                    mid = jnp.clip(0.5 * (vl_b + vr_b), lo, hi)
+                    c = mono[feat] * do   # 0 where unconstrained or no split
+                    l_lo = jnp.where(c < 0, mid, lo)
+                    l_hi = jnp.where(c > 0, mid, hi)
+                    r_lo = jnp.where(c > 0, mid, lo)
+                    r_hi = jnp.where(c < 0, mid, hi)
+                    bounds = jnp.stack(
+                        [jnp.stack([l_lo, l_hi], 1),
+                         jnp.stack([r_lo, r_hi], 1)],
+                        axis=1).reshape(2 * N, 2)
+                if allowed is not None:
+                    child_allowed = jnp.where(do[:, None],
+                                              allowed & reach[feat], allowed)
+                    allowed = jnp.repeat(child_allowed, 2, axis=0)
 
     # final level: all surviving nodes become leaves; only per-node totals
     # are needed (no split search), so skip the full histogram build
     N = 2 ** depth
-    tot = _node_totals(node_local, g, h, w, N)
-    leaf = clamp(_leaf_value(tot[:, 0], tot[:, 1], tot[:, 2], reg_lambda,
-                             reg_alpha), bounds)
-    lv_feat.append(jnp.full(N, -1, jnp.int32))
-    lv_t.append(jnp.zeros(N, jnp.int32))
-    lv_tv.append(jnp.zeros(N, jnp.float32))
-    lv_na.append(jnp.zeros(N, bool))
-    lv_sp.append(jnp.zeros(N, bool))
-    lv_leaf.append(leaf)
-    lv_gain.append(jnp.zeros(N, jnp.float32))
-    lv_cover.append(tot[:, 2])
-    if cat_feats is not None:
-        lv_mask.append(jnp.zeros((N, B), bool))
-    active = node_local >= 0
-    nl = jnp.where(active, node_local, 0)
-    row_leaf = jnp.where(active, leaf[nl], row_leaf)
+    with jax.named_scope("leaves"):
+        tot = _node_totals(node_local, g, h, w, N)
+        leaf = clamp(_leaf_value(tot[:, 0], tot[:, 1], tot[:, 2], reg_lambda,
+                                 reg_alpha), bounds)
+        lv_feat.append(jnp.full(N, -1, jnp.int32))
+        lv_t.append(jnp.zeros(N, jnp.int32))
+        lv_tv.append(jnp.zeros(N, jnp.float32))
+        lv_na.append(jnp.zeros(N, bool))
+        lv_sp.append(jnp.zeros(N, bool))
+        lv_leaf.append(leaf)
+        lv_gain.append(jnp.zeros(N, jnp.float32))
+        lv_cover.append(tot[:, 2])
+        if cat_feats is not None:
+            lv_mask.append(jnp.zeros((N, B), bool))
+        active = node_local >= 0
+        nl = jnp.where(active, node_local, 0)
+        row_leaf = jnp.where(active, leaf[nl], row_leaf)
 
     out = (jnp.concatenate(lv_feat), jnp.concatenate(lv_t),
            jnp.concatenate(lv_tv), jnp.concatenate(lv_na),
