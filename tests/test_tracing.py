@@ -612,3 +612,142 @@ def test_automl_trace_acceptance(server, tmp_path, monkeypatch):
     xs = [e for e in export["traceEvents"] if e["ph"] == "X"]
     assert xs and all({"ph", "ts", "dur", "pid", "tid", "name"} <= set(e)
                       for e in xs)
+
+
+# -- one clock: spans in ANY profiler session; the boost program by name ------
+
+
+def _tiny_gbm_frame():
+    from h2o3_tpu.frame.frame import Frame
+    rng = np.random.default_rng(7)
+    X = rng.normal(size=(2000, 4)).astype(np.float32)
+    cols = {f"x{i}": X[:, i] for i in range(4)}
+    cols["y"] = (X[:, 0] + X[:, 1] * X[:, 2]).astype(np.float32)
+    return Frame.from_arrays(cols)
+
+
+def _tiny_gbm(frame):
+    from h2o3_tpu.models.gbm import GBM
+    return GBM(ntrees=3, max_depth=3, nbins=16, seed=1).train(
+        y="y", training_frame=frame)
+
+
+def test_span_hook_is_gone_and_spans_annotate_themselves():
+    """The profiler hook global went: every span and every span-less
+    ``timed_event`` opens its own ``TraceAnnotation`` (``/3/Profiler/
+    capture`` still carries ``profiler:exercise``: tests/test_compute.py)."""
+    from h2o3_tpu.utils import profiling, timeline
+    assert not hasattr(tracing, "SPAN_HOOK")
+    assert not hasattr(profiling, "_annotation_hook")
+    for gone in ("start_profiler", "stop_profiler", "device_memory_profile"):
+        assert not hasattr(timeline, gone)
+    ann = tracing.annotation("test:annotation")
+    assert ann is not None
+    ann.__exit__(None, None, None)
+
+
+def test_timed_event_without_profiler_or_trace_still_records():
+    """No profiler session, no root trace (``builder.train()`` called
+    directly): the ring and the histogram get the event, nothing raises,
+    and no span is invented."""
+    from h2o3_tpu.utils.timeline import TIMELINE, timed_event
+
+    class Hist:
+        seen = []
+
+        def observe(self, seconds):
+            self.seen.append(seconds)
+
+    assert TRACER.current() is None
+    before = len(TRACER.list_traces())
+    with timed_event("phase", "test:no_session", observe=Hist()) as ev:
+        assert ev._span is None and ev._ann is not None
+    assert len(Hist.seen) == 1 and Hist.seen[0] >= 0.0
+    mine = [e for e in TIMELINE.snapshot() if e["what"] == "test:no_session"]
+    assert len(mine) == 1 and mine[0]["kind"] == "phase"
+    assert len(TRACER.list_traces()) == before
+    # under a root trace the span annotates, the wrapper does not: once
+    tr_before = TRACER.list_traces()
+    with TRACER.span("test:root", kind="server", root=True):
+        with timed_event("phase", "test:under_root") as ev:
+            assert ev._span is not None and ev._ann is None
+    assert len(TRACER.list_traces()) == len(tr_before) + 1
+
+
+def test_builder_phases_land_in_a_profiler_session_the_test_opened(tmp_path):
+    """Under ``jax.profiler.start_trace`` opened HERE (not by ``PROFILER``),
+    with no root trace, a build leaves its phases on the host plane: each
+    once a build, ``prepare.bin`` opening before ``chunk``. The build runs
+    on a thread of its own so that this test has a time limit of its own."""
+    import glob
+    import os
+
+    import jax
+    frame = _tiny_gbm_frame()
+    _tiny_gbm(frame)                        # compile outside the session
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    failure = []
+
+    def traced_builds():
+        try:
+            jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+            try:
+                _tiny_gbm(frame)
+                _tiny_gbm(frame)
+            finally:
+                jax.profiler.stop_trace()
+        except Exception as e:   # noqa: BLE001 — handed to the test thread
+            failure.append(e)
+
+    worker = threading.Thread(target=traced_builds, daemon=True)
+    worker.start()
+    worker.join(timeout=120.0)
+    assert not worker.is_alive(), "two toy builds under the profiler: 120 s"
+    assert not failure, failure
+    found = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert found
+    data = jax.profiler.ProfileData.from_file(found[-1])
+    starts: dict[str, list[float]] = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("gbm:"):
+                    starts.setdefault(e.name, []).append(e.start_ns)
+    assert {k: len(v) for k, v in starts.items()} == {
+        "gbm:fit": 2, "gbm:prepare.edges": 2, "gbm:prepare.bin": 2,
+        "gbm:chunk": 2}
+    for fit, edges, bins, chunk in zip(*(sorted(starts[k]) for k in (
+            "gbm:fit", "gbm:prepare.edges", "gbm:prepare.bin", "gbm:chunk"))):
+        assert fit < edges < bins < chunk
+
+
+def test_boost_program_parts_are_named_in_the_compiled_module():
+    """``jax.named_scope`` around the round's parts and the tree's levels:
+    the compiled module's ``op_name``s carry them (the ``scatter`` path,
+    which the CPU takes), so a profile reads a level's routing by name."""
+    import re
+
+    from h2o3_tpu.models import gbm
+    _tiny_gbm(_tiny_gbm_frame())
+    text = gbm._boost_scan_jit.executables()[-1].as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    paths = {tuple(n.split("/")) for n in op_names}
+
+    def has(*scope):
+        return any(scope == p[i:i + len(scope)]
+                   for p in paths for i in range(len(p)))
+
+    for d in range(3):
+        for part in ("hist", "split", "route"):
+            assert has(f"level{d}", part), (d, part)
+    assert not has("level3")                # max_depth levels, then leaves
+    for scope in ("leaves", "grad", "update"):
+        assert has(scope), scope
+    # the histogram build itself (segment_sum's scatter-add) sits under hist
+    assert any("hist" in p and p[-1] == "scatter-add" for p in paths)
+    assert any("route" in p and p[-1] == "gather" for p in paths)
