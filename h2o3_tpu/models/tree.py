@@ -334,16 +334,6 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
     for d in range(depth):
         N = 2 ** d
         with jax.named_scope(f"level{d}"):
-            with jax.named_scope("split"):
-                lmask = feat_mask
-                if do_col_sample:
-                    key, kd, kf = jax.random.split(key, 3)
-                    sub = jax.random.uniform(kd, (F,)) < col_rate
-                    sub = sub.at[jax.random.randint(kf, (), 0, F)].set(True)
-                    lmask = feat_mask & sub
-                    # the forced index may miss feat_mask; never let the
-                    # level go empty
-                    lmask = jnp.where(lmask.any(), lmask, feat_mask)
             with jax.named_scope("hist"):
                 if d == 0:
                     hists = _histograms(binned, binned_T, node_local, g, h,
@@ -373,6 +363,15 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
                     hists = jnp.stack([left4, right4],
                                       axis=2).reshape(F, N * Bt, 3)
             with jax.named_scope("split"):
+                lmask = feat_mask
+                if do_col_sample:
+                    key, kd, kf = jax.random.split(key, 3)
+                    sub = jax.random.uniform(kd, (F,)) < col_rate
+                    sub = sub.at[jax.random.randint(kf, (), 0, F)].set(True)
+                    lmask = feat_mask & sub
+                    # the forced index may miss feat_mask; never let the
+                    # level go empty
+                    lmask = jnp.where(lmask.any(), lmask, feat_mask)
                 (gain, feat, t, na_left, G, H, W, vl_b, vr_b, wl_b, wr_b,
                  member) = _find_splits(
                     hists, B, min_rows, reg_lambda, reg_alpha, gamma, lmask,
@@ -397,14 +396,6 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
                 lv_cover.append(W)
                 if cat_feats is not None:
                     lv_mask.append(member & do[:, None])
-            with jax.named_scope("route"):
-                # rows whose node froze at this level take its leaf value
-                active = node_local >= 0
-                nl = jnp.where(active, node_local, 0)
-                row_leaf = jnp.where(active & ~do[nl], leaf[nl], row_leaf)
-                node_local = _route_rows(binned, node_local, lv_feat[-1],
-                                         member, na_left, do, B)
-            with jax.named_scope("split"):
                 if bounds is not None:
                     # monotone bound propagation: split midpoint bounds the
                     # children
@@ -423,6 +414,13 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
                     child_allowed = jnp.where(do[:, None],
                                               allowed & reach[feat], allowed)
                     allowed = jnp.repeat(child_allowed, 2, axis=0)
+            with jax.named_scope("route"):
+                # rows whose node froze at this level take its leaf value
+                active = node_local >= 0
+                nl = jnp.where(active, node_local, 0)
+                row_leaf = jnp.where(active & ~do[nl], leaf[nl], row_leaf)
+                node_local = _route_rows(binned, node_local, lv_feat[-1],
+                                         member, na_left, do, B)
 
     # final level: all surviving nodes become leaves; only per-node totals
     # are needed (no split search), so skip the full histogram build

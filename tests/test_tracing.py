@@ -399,11 +399,28 @@ def _get(server, path, headers=None):
         return json.loads(r.read()), dict(r.headers)
 
 
+def _never_stored(trace_id, timeout=2.0):
+    """The reply is written INSIDE the request's root span (the span times
+    it), so a client holds the reply while the handler thread is still
+    ending the span, and until then the trace is in flight. Poll until the
+    tracer has let go of it; True if it then is in no store. (A trace that
+    IS stored is awaited with ``_wait_trace``.)"""
+    import time
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        try:
+            TRACER.get_trace(trace_id)
+        except KeyError:
+            return True
+        time.sleep(0.01)
+    return False
+
+
 def test_response_carries_traceparent_and_trace_completes(server):
     _, headers = _get(server, "/3/Capabilities")
     tp = parse_traceparent(headers.get("traceparent"))
     assert tp is not None
-    trace = TRACER.get_trace(tp.trace_id)
+    trace = _wait_trace(tp.trace_id, timeout=2.0)
     assert trace["name"] == "GET /3/Capabilities"   # renamed to the pattern
     [root] = [s for s in trace["spans"] if s["parent_id"] is None]
     assert root.get("attrs", {}).get("http_status") == 200
@@ -416,10 +433,7 @@ def test_polling_routes_are_ephemeral(server):
     _, headers = _get(server, "/3/Ping")
     tp = parse_traceparent(headers["traceparent"])
     assert tp is not None                      # propagation still works
-    import time
-    time.sleep(0.05)
-    with pytest.raises(KeyError):
-        TRACER.get_trace(tp.trace_id)          # ...but nothing was stored
+    assert _never_stored(tp.trace_id)          # ...but nothing was stored
     assert all(t["trace_id"] != tp.trace_id for t in TRACER.list_traces())
 
 
@@ -429,7 +443,7 @@ def test_incoming_traceparent_joins_callers_trace(server):
     tp = parse_traceparent(headers["traceparent"])
     assert tp.trace_id == "ab" * 16           # joined, not re-minted
     assert tp.span_id != "cd" * 8             # our root span, fresh id
-    trace = TRACER.get_trace("ab" * 16)
+    trace = _wait_trace("ab" * 16, timeout=2.0)
     [root] = [s for s in trace["spans"] if s["kind"] == "server"]
     assert root["parent_id"] == "cd" * 8      # caller's span is our parent
 
@@ -459,35 +473,18 @@ def test_health_polling_routes_are_ephemeral(server):
     scraper must not churn the completed-trace ring. Propagation still
     works: each reply carries a traceparent, and sending one records the
     call in the caller's trace as usual."""
-    import time
-
-    def settled(trace_id):
-        # the reply is written INSIDE the root span (the span times it), so
-        # the client holds it while the handler thread is still ending the
-        # span: until then the trace is in flight, with no sealed span
-        deadline = time.monotonic() + 2.0
-        while True:
-            try:
-                trace = TRACER.get_trace(trace_id)
-            except KeyError:
-                return None
-            if not trace.get("in_progress") \
-                    or time.monotonic() > deadline:
-                return trace
-            time.sleep(0.01)
-
     for path in ("/3/Health", "/3/Incidents"):
         _, headers = _get(server, path)
         tp = parse_traceparent(headers["traceparent"])
         assert tp is not None                  # propagation still works
-        assert settled(tp.trace_id) is None    # ...but nothing was stored
+        assert _never_stored(tp.trace_id)      # ...but nothing was stored
         assert all(t["trace_id"] != tp.trace_id
                    for t in TRACER.list_traces())
     # an explicit caller traceparent opts the call INTO recording
     caller = f"00-{'5e' * 16}-{'7a' * 8}-01"
     _, headers = _get(server, "/3/Health", headers={"traceparent": caller})
     assert parse_traceparent(headers["traceparent"]).trace_id == "5e" * 16
-    trace = settled("5e" * 16)
+    trace = _wait_trace("5e" * 16, timeout=2.0)
     assert any(s["name"] == "GET /3/Health" for s in trace["spans"])
 
 
