@@ -50,24 +50,33 @@ def program_module(r) -> str | None:
 
 def seconds_by_part(r) -> dict[str, float] | None:
     """Self seconds inside the window, averaged over the chips, of the
-    configuration's program by part, with what carries no part under
-    ``(unscoped)``; None where no operation carries one. Logged once a
-    run, as shares of the program's device time and of busy time."""
+    configuration's program by part; None where no operation carries one.
+    What carries no part is split in two: ``(loops)``, the self time of
+    the control flow that only holds others (the scan's own ``while``), and
+    ``(unscoped)``, the rest. ``self_seconds`` takes an operation whose
+    event starts a hair before its predecessor's ends for that one's child,
+    and its whole duration then stays in the enclosing ``while``: so
+    ``(loops)`` swings from run to run (0.004 to 0.8 s on the v5e, PR 24)
+    and the sum can pass the program's own time. Logged once a run."""
     if r.trace is None:
         return None
     cached = getattr(r.trace, "seconds_by_part", None)
     if cached is not None:
         return cached or None
+    from benchmark.trace_reduce import CONTROL_FLOW
     module = program_module(r) or ""
 
-    def under(part):
+    def under(part, loops=False):
         return lambda name, stats: (
             name.startswith(module + "/")
-            and part_of(stats.get("op_name", "")) == part)
+            and part_of(stats.get("op_name", "")) == part
+            and (part is not None or loops == bool(CONTROL_FLOW.match(
+                stats.get("opcode") or name.rpartition("/")[2]))))
 
     out = {part: r.trace.op_seconds(under(part)) for part in PARTS}
     out = {part: s for part, s in out.items() if s > 0}
     if out:
+        out["(loops)"] = r.trace.op_seconds(under(None, loops=True))
         out["(unscoped)"] = r.trace.op_seconds(under(None))
         total = sum(out.values())
         log(f"{module} by scope, {total:.3f} s of {r.trace.busy_s:.3f} s "

@@ -82,9 +82,10 @@ def test_shares_by_scope_are_self_time_over_busy_time():
     assert read("program.hist_scope_share", r) == pytest.approx(100 * 2.0 / 14.0)
     assert read("program.split_share", r) == pytest.approx(100 * 0.5 / 14.0)
     by_part = plugins.load("layer_metrics", "_scopes").seconds_by_part(r)
-    # the while's own self time and the copy carry no scope; binning is
-    # another program and is not the boost program's remainder
-    assert by_part["(unscoped)"] == pytest.approx(10.0 - 5.25 + 0.25)
+    # the scan's own while and the copy carry no scope; binning is another
+    # program and is not the boost program's remainder
+    assert by_part["(loops)"] == pytest.approx(10.0 - 5.25)
+    assert by_part["(unscoped)"] == pytest.approx(0.25)
     assert by_part["update"] == 0.5 and "grad" not in by_part
     assert sum(by_part.values()) == pytest.approx(10.0)
 

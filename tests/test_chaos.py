@@ -168,6 +168,11 @@ def test_gbm_deadline_cancels_and_keeps_built_trees(rng):
     from h2o3_tpu.utils.telemetry import JOB_DEADLINE_EXCEEDED
     n0 = JOB_DEADLINE_EXCEEDED._default().value
     fr = _binfr(rng)
+    # compile every program of this build first: the deadline is meant to
+    # fall between chunks, and in a cold process (xdist hands this test to
+    # any worker) 0.8 s pass before the first chunk is even dispatched
+    GBM(ntrees=2, max_depth=3, seed=1, trees_per_dispatch=2).train(
+        y="y", training_frame=fr)
     b = GBM(ntrees=500, max_depth=3, seed=1, trees_per_dispatch=2,
             max_runtime_secs=0.8)
     m = b.train(y="y", training_frame=fr)
