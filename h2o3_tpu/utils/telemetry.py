@@ -534,6 +534,12 @@ MODEL_BUILD_SECONDS = METRICS.histogram(
     "h2o3_model_build_seconds", "model build wall time", ("algo",),
     buckets=BUILD_BUCKETS)
 
+# tree builders' binning (ops/quantile.py): one increment a column binned
+# against quantile edges, by the mechanism that binned it. One path today
+# (compare-and-count); the label is there so that a second one shows.
+BIN_COLUMNS = METRICS.counter(
+    "h2o3_bin_columns", "columns binned against quantile edges", ("path",))
+
 # host-driven convergence loops (models/*.py drivers): per-iteration wall
 # time — IRLS steps, boosting chunks, DL epochs. The before/after evidence
 # for host-sync batching fixes (graftlint TRC003) lives here: fewer
