@@ -89,13 +89,14 @@ class DataInfo:
             if v.domain != train_dom:
                 codes = _remap_codes(codes, v.domain, train_dom)
             cats.append(codes)
-        nums = [frame.vec(c).data for c in self.num_cols] if self.num_cols else []
-        cat_stack = jnp.stack(cats, axis=1) if cats else jnp.zeros((frame.plen, 0), jnp.int32)
-        num_stack = jnp.stack(nums, axis=1) if nums else jnp.zeros((frame.plen, 0), jnp.float32)
+        nums = [frame.vec(c).data for c in self.num_cols]
+        if not cats and not nums:
+            return jnp.zeros((frame.plen, 0), jnp.float32)
+        # the columns go in as they are: one dispatch, nothing stacked first
         cards = tuple(len(d) for d in self.cat_domains)
-        return _expand(cat_stack, num_stack, cards, self.use_all_factor_levels,
-                       jnp.asarray(self.num_sub), jnp.asarray(self.num_mul),
-                       jnp.asarray(self.num_means))
+        return _expand(tuple(cats), tuple(nums), cards,
+                       self.use_all_factor_levels, self.num_sub, self.num_mul,
+                       self.num_means)
 
     def response(self, frame: Frame, y: str) -> tuple[jax.Array, int]:
         """Response column as f32 (codes for cat) + number of classes (0=regression)."""
@@ -184,27 +185,67 @@ def _remap_codes(codes: jax.Array, src_dom: tuple[str, ...], dst_dom: tuple[str,
     return jnp.where(codes >= 0, lut[jnp.clip(codes, 0, len(lut_host) - 1)], -1)
 
 
+_STRIP = 128   # design columns made at a time
+
+
 @partial(jax.jit, static_argnames=("cards", "use_all"))
-def _expand(cat_codes, nums, cards: tuple[int, ...], use_all: bool, sub, mul, impute):
-    """Dense one-hot + standardized-numeric expansion, fully fused.
+def _expand(cats: tuple, nums: tuple, cards: tuple[int, ...], use_all: bool,
+            sub, mul, impute):
+    """Dense one-hot + standardized-numeric expansion of a frame's columns
+    (``cats``: the level codes of each categorical column, ``nums``: each
+    numeric column, all [rows]), fully fused.
 
     Missing values: cat NA (-1) → all-zero block; numeric NaN → imputed to the
     mean, i.e. 0 after standardization (reference MeanImputation semantics).
+
+    Which source column and which level a design column stands for are
+    constants of its index, so a one-hot column is ``code of its source ==
+    its level``: ONE compare an element, once the source's codes stand over
+    the source's columns. The design is made in strips of 128 columns, each by
+    elementwise work over its own [rows, 128] alone: the codes are laid over
+    the strip by one select a source that reaches into it (one categorical
+    column that fills a strip costs none, a hundred narrow ones their count),
+    so a frame pays for the sources a strip holds and not for all it has.
+    XLA writes each strip into the output in place (the design's layout on a
+    TPU is column-major). Blocks of the sources' own widths built apart and
+    concatenated cost a second buffer as large as the design (7.7 GB at 3M x
+    668 on a v5e, reserved by the program and not shown in
+    ``peak_bytes_in_use``; PERF.md, PR 26).
     """
-    blocks = []
+    lo = 0 if use_all else 1
+    rows = (cats or nums)[0].shape[0]
+    # first design column of every source, left to right; sources without a
+    # column (one level, first dropped) have none
+    first, k = [], 0
     for j, card in enumerate(cards):
-        c = cat_codes[:, j]
-        lo = 0 if use_all else 1
-        width = card - lo
-        if width <= 0:
-            continue
-        oh = (c[:, None] == jnp.arange(lo, card)[None, :]).astype(jnp.float32)
-        blocks.append(oh)
-    if nums.shape[1]:
-        # mean imputation always (reference MeanImputation), independent of
-        # whether standardization is on (sub is 0 when standardize=False)
-        imputed = jnp.where(jnp.isnan(nums), impute[None, :], nums)
-        blocks.append((imputed - sub) * mul)
-    if not blocks:
-        return jnp.zeros((cat_codes.shape[0], 0), jnp.float32)
-    return jnp.concatenate(blocks, axis=1)
+        if card - lo > 0:
+            first.append((k, j))
+            k += card - lo
+    k_cat = k
+    k += len(nums)
+    # mean imputation always (reference MeanImputation), independent of
+    # whether standardization is on (sub is 0 when standardize=False)
+    scaled = [(jnp.where(jnp.isnan(x), impute[i], x) - sub[i]) * mul[i]
+              for i, x in enumerate(nums)]
+    starts = np.array([off for off, _ in first])
+    strips = []
+    for start in range(0, k, _STRIP):
+        col = np.arange(start, min(start + _STRIP, k))
+        strip = jnp.zeros((rows, len(col)), jnp.float32)
+        if start < k_cat:
+            # the sources of this strip's columns; a numeric column falls to
+            # the last one and takes a level no code has
+            owner = np.searchsorted(starts, col, side="right") - 1
+            level = np.where(col < k_cat, col - starts[owner] + lo, -2)
+            code = cats[first[owner[0]][1]][:, None]
+            for o in range(owner[0] + 1, owner[-1] + 1):
+                code = jnp.where((col >= starts[o])[None, :],
+                                 cats[first[o][1]][:, None], code)
+            strip = (code == jnp.asarray(level, code.dtype)[None, :]
+                     ).astype(jnp.float32)
+        for i in range(max(start - k_cat, 0), min(col[-1] + 1 - k_cat, len(nums))):
+            strip = jnp.where((col == k_cat + i)[None, :], scaled[i][:, None], strip)
+        strips.append(strip)
+    if not strips:
+        return jnp.zeros((rows, 0), jnp.float32)
+    return jnp.concatenate(strips, axis=1)

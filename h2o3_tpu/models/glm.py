@@ -48,31 +48,66 @@ def _fam(family: str, tweedie_p: float):
     return get_family(family)
 
 
-def _weighted_gram(X, W, z, l2, nobs, jitter):
-    """Normal equations for weighted LS with an unpenalized intercept column:
-    gram = [X,1]'W[X,1] + l2*nobs*diag(1..1,0) + jitter*I, rhs = [X,1]'Wz.
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _eta(X, beta, off=0.0):
+    """Linear predictor ``X·beta[:-1] + beta[-1] + off`` with the product at
+    HIGHEST precision. At the default a TPU rounds both operands to bf16 (8
+    bits) whenever the product goes to the MXU, which moves eta by
+    ~4e-3·|beta|: the working response, the weights and the deviance would
+    then be computed from another model than the float32 Gram solves for
+    (with bf16 inputs the 668-wide airline fit ends 0.6 standard errors off,
+    PERF.md PR 26). With ONE right-hand side XLA keeps the product on the
+    vector unit in float32 whatever is asked, and a multinomial fit's [P, 5]
+    coefficients compiled and ran the same at both settings (my chip runs,
+    PR 26); stating the precision makes that the program's property and not
+    the compiler's choice. It is bound by the read of X either way."""
+    return jnp.matmul(X, beta[:-1], precision=_HI) + beta[-1] + off
+
+
+def _weighted_gram(X, W, l2, nobs, jitter):
+    """Left side of the normal equations for weighted LS with an unpenalized
+    intercept column: ``gram = [X,1]'W[X,1] + diag(ridge)``, returned with
+    ``ridge = l2*nobs*(1..1,0) + j``, the diagonal that was added.
     One contraction over the row-sharded X — XLA reduces per-chip partials over
     ICI (the reference's ``GLMIterationTask`` Gram reduce).
 
     Contractions run at HIGHEST precision: the TPU MXU's default bf16 inputs
     lose ~1e-2 relative on the Gram, which breaks the Cholesky on
     ill-conditioned designs (the solve is [K,K] — full f32 costs nothing).
+
+    ``j = jitter * (mean diagonal + 1)`` is a ridge towards 0 on every
+    coefficient, the intercept too. It keeps collinear designs (e.g. a
+    RuleFit rule matrix with complementary 0/1 rules) factorizable, and it is
+    what keeps an unpenalised fit of separable data finite (RuleFit's L1 is
+    applied after the IRLS phase): both lean on it being a true ridge, so it
+    stays one. Its price is a departure from plain IRLS wherever a direction
+    of the design is weak: at the airline design (smallest eigenvalue 0.2
+    against j = 0.019) it moves the intercept, and the Origin coefficients
+    against it, by 1.0e-2, 0.08 standard errors (PERF.md, PR 26, with the
+    tolerance it consumes in ``benchmark/checks/glm_coef_vs_reference.py``).
+    As a proximal term (a step's right-hand side without ``j*beta``) it would
+    cost nothing there, and lets coefficients run to 4e6 on RuleFit's
+    separable rules.
     """
     k = X.shape[1]
-    hi = jax.lax.Precision.HIGHEST
     Xw = X * W[:, None]
     gram = jnp.empty((k + 1, k + 1), X.dtype)
-    gram = gram.at[:k, :k].set(jnp.matmul(Xw.T, X, precision=hi))
+    gram = gram.at[:k, :k].set(jnp.matmul(Xw.T, X, precision=_HI))
     xw_sum = Xw.sum(axis=0)
     gram = gram.at[:k, k].set(xw_sum).at[k, :k].set(xw_sum).at[k, k].set(W.sum())
-    rhs = jnp.concatenate([jnp.matmul(Xw.T, z, precision=hi),
-                           (W * z).sum()[None]])
     penalty = l2 * nobs * jnp.concatenate([jnp.ones(k), jnp.zeros(1)])
-    # ridge jitter relative to the Gram scale: collinear designs (e.g. a
-    # RuleFit rule matrix with complementary 0/1 rules) stay factorizable
-    j = jitter * (jnp.trace(gram) / (k + 1) + 1.0)
-    gram = gram + jnp.diag(penalty) + j * jnp.eye(k + 1)
-    return gram, rhs
+    ridge = penalty + jitter * (jnp.trace(gram) / (k + 1) + 1.0)
+    return gram + jnp.diag(ridge), ridge
+
+
+def _weighted_rhs(X, W, z):
+    """``[X,1]'Wz``: the right side of the normal equations where ``z`` is the
+    working response, the log-likelihood's gradient where it is the working
+    residual."""
+    return jnp.concatenate([jnp.matmul((X * W[:, None]).T, z, precision=_HI),
+                            (W * z).sum()[None]])
 
 
 def _nn_solve(gram, rhs, beta0, tol: float = 1e-7, max_passes: int = 100):
@@ -112,20 +147,41 @@ def _irls_step(family: str, tweedie_p: float, X, y, w, beta, l2,
     (graftlint TRC003: two separate device_gets per iteration doubled the
     host round-trips on the IRLS hot path)."""
     fam = _fam(family, tweedie_p)
-    eta = X @ beta[:-1] + beta[-1] + off
-    mu = fam.linkinv(eta)
-    d = fam.dmu_deta(eta)
-    var = fam.variance(mu)
-    W = w * d * d / jnp.maximum(var, 1e-12)
-    z = eta + (y - mu) / jnp.maximum(d, 1e-12) - off
-    nobs = jnp.maximum(w.sum(), 1.0)
-    gram, rhs = _weighted_gram(X, W, z, l2, nobs, 1e-5)
-    if non_negative:
-        new_beta = _nn_solve(gram, rhs, jnp.maximum(beta, 0.0).at[-1].set(beta[-1]))
-    else:
-        chol = jax.scipy.linalg.cho_factor(gram, lower=True)
-        new_beta = jax.scipy.linalg.cho_solve(chol, rhs)
-    dev = (w * fam.deviance(y, mu)).sum()
+    # jax.named_scope: metadata for a profile's op_name, no operation
+    with jax.named_scope("eta"):
+        eta = _eta(X, beta, off)
+    with jax.named_scope("weights"):
+        mu = fam.linkinv(eta)
+        d = fam.dmu_deta(eta)
+        var = fam.variance(mu)
+        W = w * d * d / jnp.maximum(var, 1e-12)
+        # the working response is eta - off + resid
+        resid = (y - mu) / jnp.maximum(d, 1e-12)
+        nobs = jnp.maximum(w.sum(), 1.0)
+    with jax.named_scope("gram"):
+        gram, ridge = _weighted_gram(X, W, l2, nobs, 1e-5)
+        if non_negative:
+            # the projected solver wants the system in b' itself
+            rhs = _weighted_rhs(X, W, eta - off + resid)
+        else:
+            # the Newton step, gram @ (b' - beta) = gradient of the penalised
+            # log-likelihood: gram @ b' = [X,1]'W(eta - off + resid) in exact
+            # arithmetic, but a sum of terms that cancel and not the
+            # difference of two sums of 1e5 that float32 rounds to 1e-2 each.
+            # At a one-hot design whose dropped level is rare (condition
+            # number 1e6) that rounding moved the intercept by up to 4e-3,
+            # forty times what benchmark/checks/glm_coef_vs_reference.py
+            # allows against the same ridge in float64 (PERF.md, PR 26)
+            rhs = _weighted_rhs(X, W, resid) - ridge * beta
+    with jax.named_scope("solve"):
+        if non_negative:
+            new_beta = _nn_solve(gram, rhs,
+                                 jnp.maximum(beta, 0.0).at[-1].set(beta[-1]))
+        else:
+            chol = jax.scipy.linalg.cho_factor(gram, lower=True)
+            new_beta = beta + jax.scipy.linalg.cho_solve(chol, rhs)
+    with jax.named_scope("deviance"):
+        dev = (w * fam.deviance(y, mu)).sum()
     return new_beta, dev, jnp.max(jnp.abs(new_beta - beta))
 
 
@@ -193,7 +249,7 @@ def _l1_threshold(family: str, tweedie_p: float, X, y, w, beta, lam1, lam2,
                   off=0.0):
     """Per-coefficient proximal threshold lam1*nobs/(gram_jj + lam2*nobs)."""
     fam = _fam(family, tweedie_p)
-    eta = X @ beta[:-1] + beta[-1] + off
+    eta = _eta(X, beta, off)
     d = fam.dmu_deta(eta)
     W = w * d * d / jnp.maximum(fam.variance(fam.linkinv(eta)), 1e-12)
     nobs = jnp.maximum(w.sum(), 1.0)
@@ -207,12 +263,12 @@ def _wald_inference(family: str, tw: float, X, yy, w, beta, dev: float,
     ``computePValues`` — inverse information matrix at the MLE; dispersion
     estimated for gaussian/gamma/tweedie, fixed 1 for binomial/poisson)."""
     fam = _fam(family, tw)
-    eta = X @ beta[:-1] + beta[-1] + off
+    eta = _eta(X, beta, off)
     d = fam.dmu_deta(eta)
     var = fam.variance(fam.linkinv(eta))
     W = w * d * d / jnp.maximum(var, 1e-12)
     nobs = jnp.maximum(w.sum(), 1.0)
-    gram, _ = _weighted_gram(X, W, jnp.zeros_like(yy), 0.0, nobs, 1e-8)
+    gram, _ = _weighted_gram(X, W, 0.0, nobs, 1e-8)
     inv = jnp.linalg.inv(gram)
     n_eff = float(jax.device_get((w > 0).sum()))
     pdim = X.shape[1] + 1
@@ -228,7 +284,7 @@ def _wald_inference(family: str, tw: float, X, yy, w, beta, dev: float,
 @partial(jax.jit, static_argnames=("family", "tweedie_p"))
 def _deviance_at(family: str, tweedie_p: float, X, y, w, beta, off=0.0):
     fam = _fam(family, tweedie_p)
-    mu = fam.linkinv(X @ beta[:-1] + beta[-1] + off)
+    mu = fam.linkinv(_eta(X, beta, off))
     return (w * fam.deviance(y, mu)).sum()
 
 
@@ -243,9 +299,9 @@ def _null_deviance(family: str, tweedie_p: float, y, w):
 def _glm_score(family: str, nclasses: int, tweedie_p: float, X, beta,
                off=0.0):
     if family == "multinomial":
-        return jax.nn.softmax(X @ beta[:-1, :] + beta[-1, :][None, :], axis=1)
+        return jax.nn.softmax(_eta(X, beta), axis=1)
     fam = _fam(family, tweedie_p)
-    mu = fam.linkinv(X @ beta[:-1] + beta[-1] + off)
+    mu = fam.linkinv(_eta(X, beta, off))
     if nclasses == 2:
         return jnp.stack([1.0 - mu, mu], axis=1)
     return mu
@@ -264,12 +320,13 @@ def _multinomial_step(nclasses: int, X, yoh, w, B, l2, l1, non_negative: bool = 
     k_feat = X.shape[1]
     nobs = jnp.maximum(w.sum(), 1.0)
     for c in range(nclasses):
-        eta = X @ B[:-1, :] + B[-1, :][None, :]
+        eta = _eta(X, B)
         p = jax.nn.softmax(eta, axis=1)
         pc = p[:, c]
         W = w * jnp.maximum(pc * (1 - pc), 1e-10)
         z = eta[:, c] + (yoh[:, c] - pc) / jnp.maximum(pc * (1 - pc), 1e-10)
-        gram, rhs = _weighted_gram(X, W, z, l2, nobs, 1e-5)
+        gram, _ = _weighted_gram(X, W, l2, nobs, 1e-5)
+        rhs = _weighted_rhs(X, W, z)
         if non_negative:
             bc = _nn_solve(gram, rhs, jnp.maximum(B[:, c], 0.0).at[-1].set(B[-1, c]))
         else:
@@ -278,7 +335,7 @@ def _multinomial_step(nclasses: int, X, yoh, w, B, l2, l1, non_negative: bool = 
         thr = l1 * nobs / jnp.maximum(jnp.diag(gram)[:k_feat], 1e-12)
         bc = bc.at[:-1].set(jnp.sign(bc[:-1]) * jnp.maximum(jnp.abs(bc[:-1]) - thr, 0.0))
         B = B.at[:, c].set(bc)
-    eta = X @ B[:-1, :] + B[-1, :][None, :]
+    eta = _eta(X, B)
     logp = jax.nn.log_softmax(eta, axis=1)
     dev = -2.0 * (w * (yoh * logp).sum(axis=1)).sum()
     return B, dev
@@ -335,7 +392,7 @@ class GLMModel(Model):
             return eta
         if self.params["family"] == "ordinal":
             X = self.data_info.expand(frame)
-            eta = X @ self.output["beta"]
+            eta = jnp.matmul(X, self.output["beta"], precision=_HI)
             theta = self.output["ordinal_theta"]
             cum = jax.nn.sigmoid(theta[None, :] - eta[:, None])
             cdf = jnp.concatenate(
@@ -354,7 +411,8 @@ class GLMModel(Model):
             frame = expand_interactions(
                 frame, self.params["interactions"],
                 self.output.get("interaction_domains"))
-        X = self.data_info.expand(frame)
+        with timed_event("phase", f"{self.algo}:expand"):
+            X = self.data_info.expand(frame)
         return _glm_score(self.params["family"], self.nclasses or 0,
                           float(self.params.get("theta", 1.0))
                           if self.params["family"] == "negativebinomial"
@@ -541,7 +599,7 @@ class GLM(ModelBuilder):
 
         def nll(p):
             beta, theta = unpack(p)
-            eta = X @ beta
+            eta = jnp.matmul(X, beta, precision=_HI)
             cum = jax.nn.sigmoid(theta[None, :] - eta[:, None])   # [n, J-1]
             cdf = jnp.concatenate(
                 [jnp.zeros((X.shape[0], 1)), cum,
@@ -690,12 +748,14 @@ class GLM(ModelBuilder):
                     np.asarray, jax.device_get((devs_d, ran_d, done_d)))
                 return b, devs, ran, done
 
-            with timed_event("iteration", "glm_irls"):
+            with timed_event("iteration", f"{self.algo}:megastep"):
                 # transient dispatch failures retry with backoff (the
                 # megastep is functional over beta — a re-run is exact)
                 beta, devs, ran, done = retrying("glm_megastep", _megastep)
             megasteps += 1
             n = int(ran.sum())
+            _tm.GLM_MEGASTEPS.inc()
+            _tm.GLM_ITERATIONS.inc(n)
             steps = [float(d) for d in devs[:n]]
             dev = steps[-1] if steps else dev
             dev_prev = dev
@@ -732,7 +792,8 @@ class GLM(ModelBuilder):
         alpha = max(float(params["alpha"]), 1e-3)   # glmnet λmax convention
         mu_bar = (w * yy).sum() / jnp.maximum(w.sum(), 1e-30)
         lam_max = float(jax.device_get(
-            jnp.max(jnp.abs(X.T @ (w * (yy - mu_bar))))
+            jnp.max(jnp.abs(jnp.matmul(X.T, w * (yy - mu_bar),
+                                       precision=_HI)))
             / jnp.maximum(w.sum(), 1e-30))) / alpha
         lam_max = max(lam_max, 1e-6)
         nlam = int(params["nlambdas"])
@@ -900,8 +961,10 @@ class GLM(ModelBuilder):
                                         self._interaction_domains)
             x = list(x) + [c for c in frame.names if c not in before]
 
-        di = self._make_data_info(frame, x)
-        X = di.expand(frame)
+        with timed_event("phase", f"{self.algo}:expand"):
+            di = self._make_data_info(frame, x)
+            X = di.expand(frame)
+        _tm.GLM_EXPANDED_WIDTH.set(X.shape[1])
         from h2o3_tpu.models.data_info import response_as_float
         yy, valid = response_as_float(yvec)
         w = weights * valid
@@ -911,8 +974,10 @@ class GLM(ModelBuilder):
         mu0 = fam.initialize_mu(yy)
         k = X.shape[1]
         beta = jnp.zeros(k + 1, jnp.float32)
-        beta = beta.at[-1].set(float(jax.device_get(
-            fam.link((w * mu0).sum() / jnp.maximum(w.sum(), 1e-30)))))
+        beta = beta.at[-1].set(
+            fam.link((w * mu0).sum() / jnp.maximum(w.sum(), 1e-30)))
+        # needs the response alone: queued before the fit, fetched after it
+        null_dev = _null_deviance(family, tw, yy, w)
 
         self._beta_bounds = self._build_beta_bounds(di, params, family)
         oc = params.get("offset_column")
@@ -928,8 +993,10 @@ class GLM(ModelBuilder):
             beta, dev, it, lambda_best, reg_path = self._lambda_search(
                 job, family, tw, X, yy, w, beta, params)
         else:
-            beta, dev, it = self._irls_fit(job, family, tw, X, yy, w, beta,
-                                           float(params["lambda_"]), params)
+            with timed_event("phase", f"{self.algo}:irls"):
+                beta, dev, it = self._irls_fit(job, family, tw, X, yy, w,
+                                               beta, float(params["lambda_"]),
+                                               params)
             lambda_best, reg_path = float(params["lambda_"]), None
 
         # destandardize for reporting: X_std = (x - sub) * mul
@@ -941,7 +1008,7 @@ class GLM(ModelBuilder):
             coef[di.ncats_expanded:-1] = b[di.ncats_expanded:-1] * mul
             coef[-1] = b[-1] - float((b[di.ncats_expanded:di.ncats_expanded + nnum] * mul * sub).sum())
 
-        null_dev = float(jax.device_get(_null_deviance(family, tw, yy, w)))
+        null_dev = float(jax.device_get(null_dev))
         from h2o3_tpu.models.model_base import ModelParameters
         mparams = ModelParameters(self.params)   # snapshot: builder stays reusable
         mparams["family"] = family

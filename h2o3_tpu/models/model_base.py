@@ -504,8 +504,9 @@ class ModelBuilder:
                 cmf = _udf.metric_callable(_udf.load_cfunc(cmf), key_name,
                                            model=model)
             if y is not None:
-                model.training_metrics = self._holdout_metrics(model, frame,
-                                                               y, w_metrics)
+                with timed_event("phase", f"{self.algo}:metrics"):
+                    model.training_metrics = self._holdout_metrics(
+                        model, frame, y, w_metrics)
                 if cmf is not None and model.training_metrics is not None:
                     self._apply_custom_metric(model, frame, y, w_metrics, cmf)
             if validation_frame is not None and y is not None:

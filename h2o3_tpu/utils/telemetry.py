@@ -558,6 +558,17 @@ DISPATCHES_PER_ITER = METRICS.gauge(
     "blocking host syncs per logical iteration of a convergence loop "
     "(1/K under K-step megasteps)", ("loop",))
 
+# GLM's IRLS loop (models/glm.py _irls_fit): iterations the megasteps
+# carried and megasteps dispatched (one blocking fetch each), and the width
+# of the newest expanded design matrix (DataInfo.expand, intercept excluded)
+GLM_ITERATIONS = METRICS.counter(
+    "h2o3_glm_iterations", "IRLS iterations run")
+GLM_MEGASTEPS = METRICS.counter(
+    "h2o3_glm_megasteps", "IRLS megasteps dispatched (one host fetch each)")
+GLM_EXPANDED_WIDTH = METRICS.gauge(
+    "h2o3_glm_expanded_width",
+    "columns of the newest expanded GLM design matrix")
+
 # mesh-slice scheduler (orchestration/scheduler.py): utilization of the
 # disjoint device slices concurrent builds run on (docs/ORCHESTRATION.md).
 # Slice labels are indices ("0".."k-1") or "full" for whole-mesh leases.

@@ -719,10 +719,11 @@ def test_builder_phases_land_in_a_profiler_session_the_test_opened(tmp_path):
                     starts.setdefault(e.name, []).append(e.start_ns)
     assert {k: len(v) for k, v in starts.items()} == {
         "gbm:fit": 2, "gbm:prepare.edges": 2, "gbm:prepare.bin": 2,
-        "gbm:chunk": 2}
-    for fit, edges, bins, chunk in zip(*(sorted(starts[k]) for k in (
-            "gbm:fit", "gbm:prepare.edges", "gbm:prepare.bin", "gbm:chunk"))):
-        assert fit < edges < bins < chunk
+        "gbm:chunk": 2, "gbm:metrics": 2}
+    for fit, edges, bins, chunk, metrics in zip(*(sorted(starts[k]) for k in (
+            "gbm:fit", "gbm:prepare.edges", "gbm:prepare.bin", "gbm:chunk",
+            "gbm:metrics"))):
+        assert fit < edges < bins < chunk < metrics
 
 
 def test_boost_program_parts_are_named_in_the_compiled_module():
