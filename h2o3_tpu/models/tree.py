@@ -12,9 +12,12 @@ TPU-native redesign (the "hard part #1" of SURVEY.md §7): growth is
 **level-synchronous with static shapes**, and — unlike the reference's
 per-level driver round-trips — the ENTIRE tree grows inside one compiled XLA
 program: the level loop is unrolled at trace time (depth is static), each
-level being a feature-scanned ``segment_sum`` histogram build (XLA reduces
-per-chip partials over ICI), a vectorized cumsum+argmax split search over
-[F, nodes, bins, dir], and a gather re-route of rows. One tree = one device
+level being a histogram build (the Pallas MXU kernel on one chip, a
+feature-scanned ``segment_sum`` elsewhere; XLA reduces per-chip partials
+over ICI), a vectorized cumsum+argmax split search over
+[F, nodes, bins, dir], and a re-route of rows by broadcast compare-and-select
+over the level's nodes and the features (:func:`_route_rows`: no per-row
+gather, which costs the TPU 7-14 ns a row). One tree = one device
 dispatch; a whole K-class round = one ``vmap``-ed dispatch
 (:func:`grow_trees_batched`). Trees are stored as dense heaps (arrays
 indexed 2i+1/2i+2), so prediction is D gather steps.
@@ -37,6 +40,7 @@ from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from h2o3_tpu.utils.costs import accounted_jit
+from h2o3_tpu.utils.telemetry import ROUTE_LEVELS
 
 
 @dataclasses.dataclass
@@ -269,22 +273,119 @@ def _find_splits(hists, n_bins: int, min_rows, reg_lambda, reg_alpha, gamma,
             wl_b, wr_b, member)
 
 
-def _route_rows(binned, node_local, feat, member, na_left, do_split,
-                n_bins: int):
-    """Advance rows to next-level node ids; frozen (leaf) rows get -1.
+#: the largest table :func:`_lookup` reads by compare-and-select; a larger
+#: one it gathers from. Select costs entries x rows, the gather rows. On the
+#: v5e (PERF.md section 6, PR 27; ms a level's whole route, select / gather):
+#: 22M rows of int8 bins 38 / 267 at 1,024 entries, 111 / 308 at 2,048,
+#: 403 / 308 at 4,096; 11M rows of int16 bins 25 / 134, 46 / 155, 85 / 155,
+#: and 202 / 155 at 8,192. 2,048 is the largest size measured at which the
+#: select wins at both.
+_SELECT_MAX_ENTRIES = 2048
 
-    ``member`` [N, B]: left-membership of each bin at each node (covers both
-    ordinal thresholds and categorical group splits)."""
-    active = node_local >= 0
-    nl = jnp.where(active, node_local, 0)
-    f = feat[nl]
-    split = do_split[nl] & active
-    b = jnp.take_along_axis(binned, f[:, None], axis=1)[:, 0]
-    is_na = b >= n_bins
-    left = jnp.where(is_na, na_left[nl],
-                     member[nl, jnp.minimum(b, n_bins - 1)])
-    child = nl * 2 + jnp.where(left, 0, 1)
-    return jnp.where(split, child, -1)
+
+def _select(values, idx):
+    """``values[idx[r], r]`` for every row r, ``values`` [n, rows] (or
+    [n, 1], broadcast) int32, as a broadcast compare-and-select reduced over
+    the small axis, rows on the minor axis: XLA fuses compare, select and
+    reduce into one loop over the rows (the [n, rows] predicate is never
+    stored) and the program holds no ``gather``. Exactly one term of a
+    row's sum is not 0 (none where ``idx`` is -1, which reads 0), so bits
+    pass through unchanged."""
+    n = values.shape[0]
+    hit = idx[None, :] == jnp.arange(n, dtype=idx.dtype)[:, None]
+    return jnp.where(hit, values, 0).sum(0, dtype=values.dtype)
+
+
+def _lookup(table, idx):
+    """``table[idx]`` for every row: ``table`` [n] int32, ``idx`` [rows] in
+    [-1, n); a row whose ``idx`` is -1 reads 0. A :func:`_select` up to
+    :data:`_SELECT_MAX_ENTRIES` entries; past the crossover a gather, which
+    costs this chip 7-14 ns a row from any table of more than 64 entries,
+    is the cheaper one."""
+    if table.shape[0] <= _SELECT_MAX_ENTRIES:
+        return _select(table[:, None], idx)
+    return jnp.where(idx >= 0, table[jnp.maximum(idx, 0)], 0)
+
+
+def _lookup_f32(table, idx):
+    """:func:`_lookup` of a float32 table, selected as bits (so ``-0.0``
+    and every other value come back exact)."""
+    bits = _lookup(lax.bitcast_convert_type(table, jnp.int32), idx)
+    return lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _row_splits(node_local, feat, t, na_left, do_split, n_features: int,
+                n_bins: int):
+    """Each row's split, read from its node's: ``(split, na_left, t,
+    feat)`` per row, ``split`` False for a frozen row (node -1) and for a
+    row of a node that does not split. A node's four values pack into one
+    int32 — bit 0 ``do_split``, bit 1 ``na_left``, then ``t`` in
+    ``n_bins.bit_length()`` bits and the feature above it — so ONE
+    :func:`_lookup` by node gives a row all four; where the frame is too
+    wide for the bits left, the feature rides in a word of its own."""
+    t_bits = n_bins.bit_length()
+    one_word = max(n_features - 1, 0).bit_length() + t_bits + 2 <= 31
+    f = jnp.maximum(feat, 0).astype(jnp.int32)
+    words = (do_split.astype(jnp.int32) | (na_left.astype(jnp.int32) << 1)
+             | (t.astype(jnp.int32) << 2))
+    if one_word:
+        words = words | (f << (t_bits + 2))
+    word = _lookup(jnp.where(do_split, words, 0), node_local)
+    f_row = word >> (t_bits + 2) if one_word else _lookup(f, node_local)
+    return ((word & 1) == 1, (word & 2) == 2,
+            (word >> 2) & ((1 << t_bits) - 1), f_row)
+
+
+def _pack_member(member):
+    """``member`` [N, B] bool -> [N * W] int32, W = ceil(B / 32) words a
+    node, bit ``b % 32`` of word ``b // 32`` set where bin b goes left."""
+    N, B = member.shape
+    W = -(-B // 32)
+    m = jnp.pad(member, ((0, 0), (0, W * 32 - B))).reshape(N, W, 32)
+    bit = jnp.left_shift(jnp.uint32(1), jnp.arange(32, dtype=jnp.uint32))
+    words = jnp.sum(jnp.where(m, bit, jnp.uint32(0)), axis=2, dtype=jnp.uint32)
+    return lax.bitcast_convert_type(words, jnp.int32).reshape(N * W)
+
+
+def _route_rows(binned_T, node_local, row_leaf, feat, t, na_left, do_split,
+                leaf, member, n_bins: int):
+    """One level's routing: rows of a node that split advance to a child
+    (``2 * node + (0 left | 1 right)``), rows of a node that froze take its
+    ``leaf`` value into ``row_leaf`` and get node -1, as frozen rows keep.
+    Returns ``(next node_local, row_leaf)``.
+
+    Every per-row read is a :func:`_lookup` by the row's node (its split:
+    :func:`_row_splits`; ``leaf`` [N] float32 as bits) or a :func:`_select`
+    over the feature axis of ``binned_T`` [F, rows], the layout the
+    histogram kernel already reads (one streaming read of it, F x rows
+    compares: a few percent of the level's histogram) — no gather up to
+    :data:`_SELECT_MAX_ENTRIES` table entries, no ``take_along_axis`` at
+    all. ``member`` is ``None`` for a numeric-only model, where a bin goes
+    left iff ``bin < t`` (``_find_splits``' own definition of ``member``);
+    with categorical features it is the [N, B] left-membership of each bin
+    at each node, packed to bit masks and tested by bit."""
+    N, F = feat.shape[0], binned_T.shape[0]
+    width = 1 if member is None else -(-n_bins // 32)
+    if N * width <= _SELECT_MAX_ENTRIES:     # the largest table of the level
+        ROUTE_LEVELS.labels(path="select").inc()
+    else:
+        ROUTE_LEVELS.labels(path="gather").inc()
+
+    split, row_na_left, row_t, f_row = _row_splits(
+        node_local, feat, t, na_left, do_split, F, n_bins)
+    # rows whose node froze at this level take its leaf value
+    row_leaf = jnp.where((node_local >= 0) & ~split,
+                         _lookup_f32(leaf, node_local), row_leaf)
+    b = _select(binned_T.astype(jnp.int32), f_row)    # the row's bin of ITS feature
+    if member is None:
+        in_left = b < row_t
+    else:
+        bc = jnp.minimum(b, n_bins - 1)
+        slot = jnp.where(node_local >= 0, node_local * width + (bc >> 5), -1)
+        in_left = ((_lookup(_pack_member(member), slot) >> (bc & 31)) & 1) == 1
+    left = jnp.where(b >= n_bins, row_na_left, in_left)
+    child = node_local * 2 + jnp.where(left, 0, 1)
+    return jnp.where(split, child, -1), row_leaf
 
 
 def _leaf_value(G, H, W, reg_lambda, reg_alpha):
@@ -415,12 +516,9 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
                                               allowed & reach[feat], allowed)
                     allowed = jnp.repeat(child_allowed, 2, axis=0)
             with jax.named_scope("route"):
-                # rows whose node froze at this level take its leaf value
-                active = node_local >= 0
-                nl = jnp.where(active, node_local, 0)
-                row_leaf = jnp.where(active & ~do[nl], leaf[nl], row_leaf)
-                node_local = _route_rows(binned, node_local, lv_feat[-1],
-                                         member, na_left, do, B)
+                node_local, row_leaf = _route_rows(
+                    binned_T, node_local, row_leaf, feat, t, na_left, do,
+                    leaf, member if cat_feats is not None else None, B)
 
     # final level: all surviving nodes become leaves; only per-node totals
     # are needed (no split search), so skip the full histogram build
@@ -439,9 +537,8 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
         lv_cover.append(tot[:, 2])
         if cat_feats is not None:
             lv_mask.append(jnp.zeros((N, B), bool))
-        active = node_local >= 0
-        nl = jnp.where(active, node_local, 0)
-        row_leaf = jnp.where(active, leaf[nl], row_leaf)
+        row_leaf = jnp.where(node_local >= 0, _lookup_f32(leaf, node_local),
+                             row_leaf)
 
     out = (jnp.concatenate(lv_feat), jnp.concatenate(lv_t),
            jnp.concatenate(lv_tv), jnp.concatenate(lv_na),

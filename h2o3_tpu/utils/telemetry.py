@@ -540,6 +540,14 @@ MODEL_BUILD_SECONDS = METRICS.histogram(
 BIN_COLUMNS = METRICS.counter(
     "h2o3_bin_columns", "columns binned against quantile edges", ("path",))
 
+# the tree engine's row routing (models/tree.py ``_route_rows``): one
+# increment a level of a tree program, counted at TRACE time where the
+# branch is taken (a cached program adds nothing), by how the level reads
+# its node tables: ``select`` (broadcast compare-and-select, no gather) up
+# to tree._SELECT_MAX_ENTRIES table entries, ``gather`` past it.
+ROUTE_LEVELS = METRICS.counter(
+    "h2o3_route_levels", "tree levels traced, by row-routing path", ("path",))
+
 # host-driven convergence loops (models/*.py drivers): per-iteration wall
 # time — IRLS steps, boosting chunks, DL epochs. The before/after evidence
 # for host-sync batching fixes (graftlint TRC003) lives here: fewer

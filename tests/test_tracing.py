@@ -750,4 +750,6 @@ def test_boost_program_parts_are_named_in_the_compiled_module():
         assert has(scope), scope
     # the histogram build itself (segment_sum's scatter-add) sits under hist
     assert any("hist" in p and p[-1] == "scatter-add" for p in paths)
-    assert any("route" in p and p[-1] == "gather" for p in paths)
+    # routing is compare-and-select reduced over nodes and features (PR 27)
+    assert any("route" in p and p[-1] == "reduce_sum" for p in paths)
+    assert not any("route" in p and p[-1] == "gather" for p in paths)
