@@ -67,6 +67,43 @@ def timed(walls: dict, name: str, fn, *args):
     return out
 
 
+# -- the frames, each from a seed ----------------------------------------------
+
+def _higgs_frame(rows: int):
+    from h2o3_tpu.frame.frame import Frame
+    rng = np.random.default_rng(11)
+    X = rng.normal(size=(rows, NFEAT)).astype(np.float32)
+    logit = X[:, :4] @ np.array([1.2, -0.8, 0.5, 0.3], np.float32) \
+        + 0.2 * X[:, 4] * X[:, 5]
+    y = (rng.random(rows) < 1.0 / (1.0 + np.exp(-logit))).astype(np.int32)
+    cols = {f"x{i}": X[:, i] for i in range(NFEAT)}
+    cols["y"] = np.where(y == 1, "s", "b")
+    return Frame.from_arrays(cols)
+
+
+def _glm_frame(n: int):
+    """Airlines-shaped n×12 float32 + binomial ``dep_delayed``, from a seed."""
+    from h2o3_tpu.frame.frame import Frame
+    rng = np.random.default_rng(13)
+    X = rng.normal(size=(n, 12)).astype(np.float32)
+    logit = X[:, :5] @ np.array([0.8, -0.5, 0.3, -0.2, 0.4], np.float32)
+    y = (rng.random(n) < 1.0 / (1.0 + np.exp(-logit)))
+    cols = {f"x{i}": X[:, i] for i in range(12)}
+    cols["dep_delayed"] = np.where(y, "YES", "NO")
+    return Frame.from_arrays(cols)
+
+
+def _dl_frame(n: int):
+    """MNIST-shaped n×784 float32 + 10-class ``y``, from a seed."""
+    from h2o3_tpu.frame.frame import Frame
+    rng = np.random.default_rng(5)
+    X = rng.normal(size=(n, 784)).astype(np.float32)
+    yv = rng.integers(0, 10, size=n)
+    cols = {f"p{i}": X[:, i] for i in range(784)}
+    cols["y"] = np.array([str(d) for d in yv], dtype=object)
+    return Frame.from_arrays(cols)
+
+
 # -- stage 0: the device ------------------------------------------------------
 
 def stage_device(dry: bool) -> dict:
@@ -173,7 +210,6 @@ def _hist_paths(want_kernel: bool) -> dict:
 def stage_train(rows: int, dry: bool) -> tuple[object, dict]:
     import jax
 
-    import bench
     from h2o3_tpu.models.gbm import GBM, _boost_scan_jit
     from h2o3_tpu.models.tree import HIST_PATHS
     from h2o3_tpu.models.xgboost import XGBoost
@@ -183,7 +219,7 @@ def stage_train(rows: int, dry: bool) -> tuple[object, dict]:
     ndev = jax.device_count()
     walls: dict = {}
     out: dict = {"rows": rows, "cold_wall_s": walls}
-    fr = timed(walls, "train:frame", bench._higgs_frame, rows)
+    fr = timed(walls, "train:frame", _higgs_frame, rows)
     assert fr.nrows == rows and fr.ncols == NFEAT + 1
     if ndev > 1:
         out["spread"] = _check_spread(fr)
@@ -283,10 +319,9 @@ def stage_serve(model) -> dict:
 # -- stage 4: the other two loops, briefly -------------------------------------
 
 def stage_glm(rows: int) -> dict:
-    import bench
     from h2o3_tpu.models.glm import GLM
     from h2o3_tpu.utils.registry import DKV
-    fr = bench._glm_frame(rows)
+    fr = _glm_frame(rows)
     m = GLM(family="binomial", max_iterations=5).train(
         y="dep_delayed", training_frame=fr)
     assert DKV.get(m.key) is m
@@ -296,10 +331,9 @@ def stage_glm(rows: int) -> dict:
 
 
 def stage_dl(rows: int) -> dict:
-    import bench
     from h2o3_tpu.models.deeplearning import DeepLearning
     from h2o3_tpu.utils.registry import DKV
-    fr = bench._dl_frame(rows)
+    fr = _dl_frame(rows)
     m = DeepLearning(hidden=[50, 50], epochs=1, mini_batch_size=128,
                      seed=7).train(y="y", training_frame=fr)
     assert DKV.get(m.key) is m

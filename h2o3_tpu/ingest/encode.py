@@ -53,7 +53,7 @@ class CompressedChunk:
         self.payload = payload
         self.bias = float(bias)
         # what the uncompressed (float32/int32) column would have occupied —
-        # the numerator of the compression ratio the bench artifact reports
+        # the numerator of the compression ratio
         self.raw_bytes = int(raw_bytes if raw_bytes is not None
                              else len(payload) * 4)
 

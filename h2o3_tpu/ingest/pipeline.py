@@ -65,8 +65,8 @@ class _Aborted(Exception):
 
 
 class IngestStats:
-    """One streaming parse's accounting — rides into ``extra.ingest`` and
-    the ``h2o3_ingest_*`` metrics."""
+    """One streaming parse's accounting — rides into the
+    ``h2o3_ingest_*`` metrics."""
 
     def __init__(self):
         self.rows = 0

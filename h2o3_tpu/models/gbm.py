@@ -32,8 +32,8 @@ from h2o3_tpu.utils.costs import accounted_jit
 from h2o3_tpu.utils.timeline import timed_event
 from jax import lax
 
-from h2o3_tpu.models.tree import (Tree, _grow_tree_device, fold_binned,
-                                  predict_binned, predict_raw)
+from h2o3_tpu.models.tree import (Tree, _grow_tree_device, _walk_binned,
+                                  fold_binned, predict_binned, predict_raw)
 from h2o3_tpu.ops.quantile import (bin_column, bin_dtype, bin_features,
                                     compute_bin_edges)
 
@@ -207,22 +207,9 @@ def _traverse_heap_device(binned_v, heap, n_bins: int, has_mask: bool):
     the device heap channels (feat, thresh_bin, thresh_val, na_left,
     is_split, leaf, gain, cover[, left_mask]) — lets the fused scan carry
     validation margins without leaving the device."""
-    feat, tbin, na_l, is_sp, leaf = heap[0], heap[1], heap[3], heap[4], heap[5]
-    mask = heap[8] if has_mask else None
-    rows = binned_v.shape[0]
-    depth = int(np.log2(feat.shape[0] + 1)) - 1
-    idx = jnp.zeros(rows, jnp.int32)
-    for _ in range(depth):
-        f = jnp.maximum(feat[idx], 0)
-        b = jnp.take_along_axis(binned_v, f[:, None], axis=1)[:, 0]
-        if mask is None:
-            left = jnp.where(b >= n_bins, na_l[idx], b < tbin[idx])
-        else:
-            left = jnp.where(b >= n_bins, na_l[idx],
-                             mask[idx, jnp.minimum(b, n_bins - 1)])
-        nxt = idx * 2 + jnp.where(left, 1, 2)
-        idx = jnp.where(is_sp[idx], nxt, idx)
-    return leaf[idx]
+    split = {"left_mask": heap[8]} if has_mask else {"thresh_bin": heap[1]}
+    idx = _walk_binned(binned_v, heap[0], heap[3], heap[4], n_bins, **split)
+    return heap[5][idx]
 
 
 @jax.jit

@@ -67,8 +67,8 @@ def publish_dispatch_audit(builder, loop: str, iterations: int,
     """Record a convergence loop's host-sync economy: how many blocking
     device→host fetches and compiled dispatches the loop paid for how many
     logical iterations. Feeds ``h2o3_dispatches_per_iteration{loop}`` and
-    the builder's ``_dispatch_audit`` (bench embeds it as
-    ``extra.dispatch_audit`` and refuses to stamp on a regression)."""
+    the builder's ``_dispatch_audit`` (``tests/test_dispatch_audit.py``
+    pins the counts)."""
     iters = max(int(iterations), 1)
     audit = getattr(builder, "_dispatch_audit", None)
     if audit is None:

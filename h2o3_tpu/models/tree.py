@@ -131,7 +131,8 @@ def _level_histograms_fused(binned, node_local, g, h, w, n_nodes: int,
     stacked ``[F, n_nodes*n_bins_tot, 3]`` payload over the row axis — the
     FireCaffe shape: few, large, tree-reduced collectives. The implicit-SPMD
     path instead lowers one small all-reduce per feature-scan step, which is
-    exactly the 4-tiny-collectives-per-level pattern MULTICHIP_r05 flagged."""
+    exactly the 4-tiny-collectives-per-level pattern the multi-chip dry run
+    (``__graft_entry__.dryrun_multichip``) flagged."""
     from h2o3_tpu.parallel.mesh import ROWS
     rows = P(ROWS)
 
@@ -618,37 +619,89 @@ def grow_tree(binned: jax.Array, edges: jax.Array, g: jax.Array, h: jax.Array,
     return trees[0]
 
 
-def predict_binned(binned, trees: list[Tree], n_bins: int) -> jax.Array:
-    """Sum of leaf values over stacked trees, traversing binned features."""
-    stack = lambda attr: jnp.stack([getattr(t, attr) for t in trees])
-    if trees[0].left_mask is not None:
-        return _predict_binned_masked(binned, stack("feat"),
-                                      stack("left_mask"), stack("na_left"),
-                                      stack("is_split"), stack("leaf"), n_bins)
-    return _predict_binned_impl(binned, stack("feat"), stack("thresh_bin"),
-                                stack("na_left"), stack("is_split"), stack("leaf"),
-                                n_bins)
+def _walk(feat, na_left, is_split, vals, is_missing, goes_left) -> jax.Array:
+    """Each row's final heap index in ONE tree: the level loop every
+    traversal shares (the numpy loop ``genmodel/codegen.py`` emits is its
+    model). ``vals`` holds the rows' values ``[rows, F]`` — bins or raw
+    values; a tuple of such arrays is fetched member by member — and the walk
+    fetches the split feature's. ``is_missing(v)`` marks the rows that take
+    the node's NA direction; ``goes_left(idx, f, v)`` is the split test for
+    the rest. What a row's value is compared with lives in those two."""
+    rows = jax.tree.leaves(vals)[0].shape[0]
+    depth = int(np.log2(feat.shape[0] + 1)) - 1
+    idx = jnp.zeros(rows, jnp.int32)
+    for _ in range(depth):
+        f = jnp.maximum(feat[idx], 0)
+        v = jax.tree.map(
+            lambda a: jnp.take_along_axis(a, f[:, None], axis=1)[:, 0], vals)
+        left = jnp.where(is_missing(v), na_left[idx], goes_left(idx, f, v))
+        nxt = idx * 2 + jnp.where(left, 1, 2)
+        idx = jnp.where(is_split[idx], nxt, idx)
+    return idx
+
+
+def _walk_binned(binned, feat, na_left, is_split, n_bins: int,
+                 thresh_bin=None, left_mask=None) -> jax.Array:
+    """:func:`_walk` over bin indices: the NA bin (``b >= n_bins``) is
+    missing; a row goes left below its node's ``thresh_bin`` or, with group
+    splits, where the node's ``left_mask`` holds its bin."""
+    if left_mask is None:
+        def goes_left(idx, f, b):
+            return b < thresh_bin[idx]
+    else:
+        def goes_left(idx, f, b):
+            return left_mask[idx, jnp.minimum(b, n_bins - 1)]
+    return _walk(feat, na_left, is_split, binned, lambda b: b >= n_bins,
+                 goes_left)
+
+
+def _add_leaves(walk_one, stacked, acc0, lr=None) -> jax.Array:
+    """``acc0`` plus every stacked tree's leaf values, in tree order:
+    ``((acc0 + l1) + l2) + ...``, or with ``lr`` the boosting scan's own
+    ``((acc0 + lr*l1) + lr*l2) + ...``. ``stacked`` ends in the leaf values;
+    ``walk_one`` takes one tree's other arrays to its rows' heap indices."""
+    def one_tree(acc, tr):
+        *tree, leaf = tr
+        step = leaf[walk_one(*tree)]
+        return acc + (step if lr is None else lr * step), None
+
+    acc, _ = lax.scan(one_tree, acc0, stacked)
+    return acc
+
+
+def _stack(trees: list[Tree], *attrs: str) -> tuple:
+    return tuple(jnp.stack([getattr(t, a) for t in trees]) for a in attrs)
+
+
+def _stacked(trees: list[Tree]):
+    """The binned programs' operands over ``trees``: feat, na_left, is_split
+    and leaf stacked, then the split test as :func:`_walk_binned`'s keyword:
+    the left-membership masks where the trees carry them (group splits),
+    else the bin thresholds."""
+    test = "thresh_bin" if trees[0].left_mask is None else "left_mask"
+    return (*_stack(trees, "feat", "na_left", "is_split", "leaf"),
+            {test: _stack(trees, test)[0]})
 
 
 @partial(jax.jit, static_argnames=("n_bins",))
-def _predict_binned_impl(binned, feat_s, t_s, na_s, sp_s, leaf_s, n_bins: int):
-    rows = binned.shape[0]
-    depth = int(np.log2(feat_s.shape[1] + 1)) - 1
+def _binned_margins(binned, feat_s, na_s, sp_s, leaf_s, split_s, n_bins: int,
+                    lr=None, F0=None):
+    """Stacked trees over binned features. One compiled program per
+    (thresholds or masks, sum or fold): ``split_s``'s key and whether
+    ``lr`` / ``F0`` are given are part of what is traced."""
+    acc0 = (jnp.zeros(binned.shape[0], jnp.float32) if F0 is None
+            else F0.astype(jnp.float32))
 
-    def one_tree(acc, tr):
-        feat, t, na_l, is_sp, leaf = tr
-        idx = jnp.zeros(rows, jnp.int32)
-        for _ in range(depth):
-            f = jnp.maximum(feat[idx], 0)
-            b = jnp.take_along_axis(binned, f[:, None], axis=1)[:, 0]
-            left = jnp.where(b >= n_bins, na_l[idx], b < t[idx])
-            nxt = idx * 2 + jnp.where(left, 1, 2)
-            idx = jnp.where(is_sp[idx], nxt, idx)
-        return acc + leaf[idx], None
+    def walk_one(feat, na_l, is_sp, split):
+        return _walk_binned(binned, feat, na_l, is_sp, n_bins, **split)
 
-    acc, _ = lax.scan(one_tree, jnp.zeros(rows, jnp.float32),
-                      (feat_s, t_s, na_s, sp_s, leaf_s))
-    return acc
+    return _add_leaves(walk_one, (feat_s, na_s, sp_s, split_s, leaf_s),
+                       acc0, lr)
+
+
+def predict_binned(binned, trees: list[Tree], n_bins: int) -> jax.Array:
+    """Sum of leaf values over stacked trees, traversing binned features."""
+    return _binned_margins(binned, *_stacked(trees), n_bins=n_bins)
 
 
 def fold_binned(binned, trees: "list[Tree]", n_bins: int, lr, F0) -> jax.Array:
@@ -664,106 +717,19 @@ def fold_binned(binned, trees: "list[Tree]", n_bins: int, lr, F0) -> jax.Array:
         # a zero-tree checkpoint (deadline tripped before the first chunk)
         # legally resumes from the bare f0 margins
         return jnp.asarray(F0, jnp.float32)
-    stack = lambda attr: jnp.stack([getattr(t, attr) for t in trees])
-    lr = jnp.float32(lr)
-    if trees[0].left_mask is not None:
-        return _fold_binned_masked(binned, stack("feat"), stack("left_mask"),
-                                   stack("na_left"), stack("is_split"),
-                                   stack("leaf"), lr, F0, n_bins)
-    return _fold_binned_impl(binned, stack("feat"), stack("thresh_bin"),
-                             stack("na_left"), stack("is_split"),
-                             stack("leaf"), lr, F0, n_bins)
-
-
-@partial(jax.jit, static_argnames=("n_bins",))
-def _fold_binned_impl(binned, feat_s, t_s, na_s, sp_s, leaf_s, lr, F0,
-                      n_bins: int):
-    rows = binned.shape[0]
-    depth = int(np.log2(feat_s.shape[1] + 1)) - 1
-
-    def one_tree(acc, tr):
-        feat, t, na_l, is_sp, leaf = tr
-        idx = jnp.zeros(rows, jnp.int32)
-        for _ in range(depth):
-            f = jnp.maximum(feat[idx], 0)
-            b = jnp.take_along_axis(binned, f[:, None], axis=1)[:, 0]
-            left = jnp.where(b >= n_bins, na_l[idx], b < t[idx])
-            nxt = idx * 2 + jnp.where(left, 1, 2)
-            idx = jnp.where(is_sp[idx], nxt, idx)
-        return acc + lr * leaf[idx], None
-
-    acc, _ = lax.scan(one_tree, F0.astype(jnp.float32),
-                      (feat_s, t_s, na_s, sp_s, leaf_s))
-    return acc
-
-
-@partial(jax.jit, static_argnames=("n_bins",))
-def _fold_binned_masked(binned, feat_s, mask_s, na_s, sp_s, leaf_s, lr, F0,
-                        n_bins: int):
-    rows = binned.shape[0]
-    depth = int(np.log2(feat_s.shape[1] + 1)) - 1
-
-    def one_tree(acc, tr):
-        feat, mask, na_l, is_sp, leaf = tr
-        idx = jnp.zeros(rows, jnp.int32)
-        for _ in range(depth):
-            f = jnp.maximum(feat[idx], 0)
-            b = jnp.take_along_axis(binned, f[:, None], axis=1)[:, 0]
-            left = jnp.where(b >= n_bins, na_l[idx],
-                             mask[idx, jnp.minimum(b, n_bins - 1)])
-            nxt = idx * 2 + jnp.where(left, 1, 2)
-            idx = jnp.where(is_sp[idx], nxt, idx)
-        return acc + lr * leaf[idx], None
-
-    acc, _ = lax.scan(one_tree, F0.astype(jnp.float32),
-                      (feat_s, mask_s, na_s, sp_s, leaf_s))
-    return acc
-
-
-@partial(jax.jit, static_argnames=("n_bins",))
-def _predict_binned_masked(binned, feat_s, mask_s, na_s, sp_s, leaf_s,
-                           n_bins: int):
-    """Traversal by left-membership masks (group splits)."""
-    rows = binned.shape[0]
-    depth = int(np.log2(feat_s.shape[1] + 1)) - 1
-
-    def one_tree(acc, tr):
-        feat, mask, na_l, is_sp, leaf = tr
-        idx = jnp.zeros(rows, jnp.int32)
-        for _ in range(depth):
-            f = jnp.maximum(feat[idx], 0)
-            b = jnp.take_along_axis(binned, f[:, None], axis=1)[:, 0]
-            left = jnp.where(b >= n_bins, na_l[idx],
-                             mask[idx, jnp.minimum(b, n_bins - 1)])
-            nxt = idx * 2 + jnp.where(left, 1, 2)
-            idx = jnp.where(is_sp[idx], nxt, idx)
-        return acc + leaf[idx], None
-
-    acc, _ = lax.scan(one_tree, jnp.zeros(rows, jnp.float32),
-                      (feat_s, mask_s, na_s, sp_s, leaf_s))
-    return acc
+    return _binned_margins(binned, *_stacked(trees), n_bins=n_bins,
+                           lr=jnp.float32(lr), F0=F0)
 
 
 @jax.jit
 def _predict_raw_impl(X, feat_s, tv_s, na_s, sp_s, leaf_s):
     """Raw-value traversal for scoring new frames (threshold = edge value)."""
-    rows = X.shape[0]
-    depth = int(np.log2(feat_s.shape[1] + 1)) - 1
+    def walk_one(feat, tv, na_l, is_sp):
+        return _walk(feat, na_l, is_sp, X, jnp.isnan,
+                     lambda idx, f, x: x < tv[idx])
 
-    def one_tree(acc, tr):
-        feat, tv, na_l, is_sp, leaf = tr
-        idx = jnp.zeros(rows, jnp.int32)
-        for _ in range(depth):
-            f = jnp.maximum(feat[idx], 0)
-            x = jnp.take_along_axis(X, f[:, None], axis=1)[:, 0]
-            left = jnp.where(jnp.isnan(x), na_l[idx], x < tv[idx])
-            nxt = idx * 2 + jnp.where(left, 1, 2)
-            idx = jnp.where(is_sp[idx], nxt, idx)
-        return acc + leaf[idx], None
-
-    acc, _ = lax.scan(one_tree, jnp.zeros(rows, jnp.float32),
-                      (feat_s, tv_s, na_s, sp_s, leaf_s))
-    return acc
+    return _add_leaves(walk_one, (feat_s, tv_s, na_s, sp_s, leaf_s),
+                       jnp.zeros(X.shape[0], jnp.float32))
 
 
 @partial(jax.jit, static_argnames=("n_bins",))
@@ -772,28 +738,20 @@ def _predict_raw_masked(X, cat_card, feat_s, tv_s, mask_s, na_s, sp_s, leaf_s,
     """Raw traversal with group splits: categorical features map raw codes
     to their histogram bin (range-grouped when cardinality > bins) and test
     membership; numeric features compare against the edge threshold."""
-    rows = X.shape[0]
-    depth = int(np.log2(feat_s.shape[1] + 1)) - 1
     cat_bin = cat_bins_for_codes(X, cat_card, n_bins)   # [rows, F] int32
 
-    def one_tree(acc, tr):
-        feat, tv, mask, na_l, is_sp, leaf = tr
-        idx = jnp.zeros(rows, jnp.int32)
-        for _ in range(depth):
-            f = jnp.maximum(feat[idx], 0)
-            x = jnp.take_along_axis(X, f[:, None], axis=1)[:, 0]
-            is_cat = cat_card[f] > 0
-            b = jnp.take_along_axis(cat_bin, f[:, None], axis=1)[:, 0]
-            left_cat = mask[idx, jnp.clip(b, 0, n_bins - 1)]
-            left = jnp.where(jnp.isnan(x), na_l[idx],
-                             jnp.where(is_cat, left_cat, x < tv[idx]))
-            nxt = idx * 2 + jnp.where(left, 1, 2)
-            idx = jnp.where(is_sp[idx], nxt, idx)
-        return acc + leaf[idx], None
+    def walk_one(feat, tv, mask, na_l, is_sp):
+        def goes_left(idx, f, v):
+            x, b = v
+            return jnp.where(cat_card[f] > 0,
+                             mask[idx, jnp.clip(b, 0, n_bins - 1)],
+                             x < tv[idx])
 
-    acc, _ = lax.scan(one_tree, jnp.zeros(rows, jnp.float32),
-                      (feat_s, tv_s, mask_s, na_s, sp_s, leaf_s))
-    return acc
+        return _walk(feat, na_l, is_sp, (X, cat_bin),
+                     lambda v: jnp.isnan(v[0]), goes_left)
+
+    return _add_leaves(walk_one, (feat_s, tv_s, mask_s, na_s, sp_s, leaf_s),
+                       jnp.zeros(X.shape[0], jnp.float32))
 
 
 def cat_bins_for_codes(X, cat_card, n_bins: int) -> jax.Array:
@@ -809,11 +767,9 @@ def cat_bins_for_codes(X, cat_card, n_bins: int) -> jax.Array:
 
 
 def predict_raw(X, trees: list[Tree], cat_card=None, n_bins: int = 0) -> jax.Array:
-    stack = lambda attr: jnp.stack([getattr(t, attr) for t in trees])
     if trees[0].left_mask is not None:
-        return _predict_raw_masked(X, cat_card, stack("feat"),
-                                   stack("thresh_val"), stack("left_mask"),
-                                   stack("na_left"), stack("is_split"),
-                                   stack("leaf"), n_bins)
-    return _predict_raw_impl(X, stack("feat"), stack("thresh_val"),
-                             stack("na_left"), stack("is_split"), stack("leaf"))
+        return _predict_raw_masked(
+            X, cat_card, *_stack(trees, "feat", "thresh_val", "left_mask",
+                                 "na_left", "is_split", "leaf"), n_bins)
+    return _predict_raw_impl(X, *_stack(trees, "feat", "thresh_val", "na_left",
+                                        "is_split", "leaf"))

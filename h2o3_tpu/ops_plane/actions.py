@@ -340,7 +340,7 @@ class ActionLog:
             return len(self._records)
 
     def reset(self) -> None:
-        """Drop the trail (tests/bench isolation only)."""
+        """Drop the trail (test isolation only)."""
         with self._lock:
             self._records.clear()
             self._rollbacks.clear()

@@ -136,7 +136,7 @@ class RemediationEngine:
         }
 
     def reset(self) -> None:
-        """Forget cooldowns (tests/bench isolation only)."""
+        """Forget cooldowns (test isolation only)."""
         with self._lock:
             self._last_action.clear()
 

@@ -312,7 +312,7 @@ class QuotaManager:
         return sized[0][1] if sized and sized[0][0] > 0 else None
 
     def reset(self) -> None:
-        """Drop all quotas/ledgers (tests/bench isolation only)."""
+        """Drop all quotas/ledgers (test isolation only)."""
         with self._lock:
             self._quotas.clear()
             self._requests.clear()

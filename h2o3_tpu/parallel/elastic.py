@@ -184,7 +184,7 @@ def drain(timeout: float = 30.0) -> None:
 
     An EJECTED worker released from a stalled dispatch finishes that
     dispatch in the background (daemon thread, result discarded) — harmless
-    in a server, but a test/bench process exiting the interpreter while XLA
+    in a server, but a test process exiting the interpreter while XLA
     is mid-dispatch aborts. Chaos scenarios call this after releasing their
     injected stalls."""
     deadline = time.monotonic() + timeout
@@ -608,7 +608,7 @@ class ElasticGroup:
             return {w.wid: w.state for w in self._workers.values()}
 
     def summary(self) -> dict:
-        """Build-level rollup for model output / bench ``extra.elastic``."""
+        """Build-level rollup for model output."""
         with self._cond:
             by_reason: dict = {}
             for e in self.ejections:

@@ -222,7 +222,7 @@ class ScoringReplica:
         cache in the background (fed by the persistent compile cache, so
         a previously-seen signature is a fast cache hit): a fresh replica
         serves warm from its first request. Returns the worker thread so
-        tests/bench can join it."""
+        tests can join it."""
         if buckets is None:
             buckets = precompile_buckets_from_env()
         with self._lock:

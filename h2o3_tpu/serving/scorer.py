@@ -10,7 +10,7 @@ columns (:meth:`ServingSchema.build_frame`), so the serving path cannot
 drift from ``model.predict``.
 
 Signatures are ``(model identity, n_num, n_cat, dtype, bucket)``. A hit
-returns the warm executable (counted — the bench and tests assert the
+returns the warm executable (counted — tests assert the
 second same-shape request compiles nothing); a miss traces + compiles
 eagerly via ``jit(...).lower(...).compile()`` so compile cost is paid at
 miss time, never mid-batch. A model whose ``_score_raw`` does not trace or

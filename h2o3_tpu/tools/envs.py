@@ -5,7 +5,7 @@ batch windows, SLO targets, budgets, retry counts. A module-level read —
 ``WINDOW_S = float(os.environ.get("H2O3TPU_SCORE_WINDOW_MS", ...))`` —
 freezes the value at IMPORT time, so anything that sets the variable
 after the first import is silently ignored: ``monkeypatch.setenv`` in
-tests, a launcher exporting config before calling ``serve()``, a bench
+tests, a launcher exporting config before calling ``serve()``, a
 scenario tuning a knob between runs. That is exactly the bug ISSUE 13's
 batcher satellite fixed (the fixed scoring window could never be changed
 once ``serving.batcher`` was imported).

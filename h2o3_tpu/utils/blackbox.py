@@ -136,7 +136,7 @@ DUMP_MEMBERS = (
 class BlackBox:
     """The watchdog + exit-hook post-mortem dumper. One process-wide
     instance (:data:`BLACKBOX`) is armed by ``H2OServer.start`` and
-    disarmed by ``H2OServer.stop``; private instances (tests/bench)
+    disarmed by ``H2OServer.stop``; private instances (tests)
     carry their own once-per-instance fire flag and dump directory."""
 
     def __init__(self, dump_dir: "str | None" = None):
@@ -358,7 +358,7 @@ class BlackBox:
         return path
 
     def reset(self) -> None:
-        """Forget the fired flag and watches (tests/bench only — a real
+        """Forget the fired flag and watches (tests only — a real
         process fires at most once)."""
         with self._lock:
             self._fired = False

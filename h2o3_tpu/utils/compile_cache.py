@@ -14,7 +14,7 @@ Where the cache lives is decided OUTSIDE the code, by one rule:
   directory that moves — a home directory, a temp name, a pid — never hits).
 
 Whether it is on is ``H2O3TPU_COMPILE_CACHE``: unset → the caller's default
-(``bench.py`` and ``chip_smoke.py`` pass ``default_on=True``; session init
+(``chip_smoke.py`` and ``benchmark/run.py`` pass ``default_on=True``; session init
 and the launcher leave it off), ``0``/``off`` → disabled, ``1``/``on`` →
 enabled. Any other value is an error — the directory is not this variable's
 business.

@@ -18,7 +18,7 @@ Three pieces:
   bytes. When a site compiles a *second* signature the :class:`CostMeter`
   records a **recompile event** with the signature diff (which dim / dtype /
   device set / static changed) — recompile attribution becomes a live table
-  instead of forensic bench archaeology.
+  instead of forensic archaeology.
 - :class:`CostMeter` (``COSTS``) — the process-wide registry behind
   ``GET /3/Compute``. Sampled execution probes (the wrapper's own, or the
   ``map_reduce`` dispatch probe feeding :meth:`CostMeter.observe`) combine
@@ -59,7 +59,7 @@ _SITE: contextvars.ContextVar["str | None"] = \
 
 def enabled() -> bool:
     """Cost accounting on? (``H2O3TPU_COSTS_OFF=1`` disables; read per call
-    so tests and the bench overhead probe can flip it at runtime.)"""
+    so tests can flip it at runtime.)"""
     return os.environ.get("H2O3TPU_COSTS_OFF", "") != "1"
 
 
@@ -412,9 +412,9 @@ class CostMeter:
             return {k: dict(v) for k, v in self._loops.items()}
 
     def signature_count(self) -> int:
-        """Total distinct signatures across sites — the bench's
-        steady-state recompile probe: a warm scenario re-run must not grow
-        this."""
+        """Total distinct signatures across sites — a steady-state
+        recompile probe: a warm re-run must not grow this
+        (``benchmark/counters.py`` reads it around a window)."""
         with self._lock:
             return sum(len(r["signatures"]) for r in self._sites.values())
 

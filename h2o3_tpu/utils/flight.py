@@ -38,8 +38,8 @@ Consumers:
 - the black-box post-mortem (``utils/blackbox.py``) and the diagnostics
   bundle ship :meth:`FlightRecorder.export` as ``timeseries.json``.
 
-``H2O3TPU_FLIGHT_OFF=1`` disables everything (sampler, passive ingest);
-the bench's overhead comparator. The recorder never imports REST and the
+``H2O3TPU_FLIGHT_OFF=1`` disables everything (sampler, passive ingest).
+The recorder never imports REST and the
 sampler never raises out of its loop — a sick registry is a skipped
 sample, not a dead recorder.
 """
@@ -436,12 +436,12 @@ class FlightRecorder:
         return {"stats": self.stats(), "series": self.query()}
 
     def ticks(self) -> int:
-        """Sampler ticks taken (the bench's hollow-sampler proof)."""
+        """Sampler ticks taken (zero means a hollow recorder)."""
         with self._lock:
             return self._ticks
 
     def reset(self) -> None:
-        """Drop every series and counter (tests/bench isolation only)."""
+        """Drop every series and counter (test isolation only)."""
         with self._lock:
             self._series.clear()
             self._dropped_series = 0

@@ -30,7 +30,7 @@ hardware fingerprint, and a secrets-redacted config dump
 
 Thresholds are env-tunable per rule (``H2O3TPU_HEALTH_*``, see
 docs/OBSERVABILITY.md "Health & incidents"); ``H2O3TPU_HEALTH_OFF=1``
-disables the evaluator entirely (the bench's overhead comparator).
+disables the evaluator entirely.
 Everything is host-side stdlib; a probe that raises is reported and
 skipped, never fatal to the sweep.
 """
@@ -127,7 +127,7 @@ def _cleaner_stats() -> dict:
 def _leak_growth_flags() -> list:
     """Keys the leak detector flags as GROWING (bytes strictly rising
     across sweeps). Idle-only flags are expected from back-to-back sweeps
-    and annotate, not page — same policy as the bench memory gate."""
+    and annotate, not page."""
     from h2o3_tpu.utils.memory import MEMORY
     return [f for f in MEMORY.leak_report()["flagged"]
             if "growing" in f.get("reasons", ())]
@@ -524,7 +524,7 @@ class HealthEvaluator:
                     return
             # heartbeat BEFORE the sweep: the black-box watchdog pages on
             # silence, and the sweep body is exactly what can wedge (the
-            # chaos seam below is the injectable stall the bench drives;
+            # chaos seam below is the injectable stall tests drive;
             # BLACKBOX looked up per sweep so tests can swap the instance)
             _bb.BLACKBOX.beat("health_sweep")
             if _tl.FAULTS is not None:
@@ -539,8 +539,8 @@ class HealthEvaluator:
                     return
                 with self._eval_lock:
                     # thread-driven sweeps counted apart from inline
-                    # evaluate() calls: the bench's hollow-watchdog guard
-                    # must prove the WATCHDOG ran, not its own probes
+                    # evaluate() calls: a hollow-watchdog check must prove
+                    # the WATCHDOG ran, not its own probes
                     self._thread_sweeps += 1
             except Exception:   # noqa: BLE001 — the watcher must outlive
                 _LOG.exception("health sweep failed")   # what it watches
@@ -702,7 +702,7 @@ class HealthEvaluator:
             return self._thread_sweeps
 
     def reset(self) -> None:
-        """Forget window baselines/streaks/verdict (tests/bench)."""
+        """Forget window baselines/streaks/verdict (tests)."""
         with self._eval_lock:
             self._prev.clear()
             self._streaks.clear()
@@ -746,7 +746,7 @@ def redacted_config() -> dict:
 
 def hardware_fingerprint() -> dict:
     """Backend identity for the bundle — which hardware produced these
-    numbers (the bench artifact's `extra.hardware` sibling)."""
+    numbers."""
     import platform
     out: dict = {"python": platform.python_version(),
                  "platform": platform.platform()}

@@ -327,8 +327,7 @@ class IncidentLog:
             return [dict(self._ring[iid]) for iid in reversed(self._order)]
 
     def opened_total(self) -> int:
-        """Monotonic count of incidents OPENED this process — the bench
-        hollow-watchdog guard windows on its delta."""
+        """Monotonic count of incidents OPENED this process."""
         with self._lock:
             return self._opened_total
 
@@ -337,7 +336,7 @@ class IncidentLog:
             return sorted(self._open_by_rule)
 
     def reset(self) -> None:
-        """Drop everything (tests/bench isolation only)."""
+        """Drop everything (test isolation only)."""
         with self._lock:
             self._ring.clear()
             self._order.clear()

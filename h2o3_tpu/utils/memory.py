@@ -28,8 +28,7 @@ On top of the keyed accounting a **leak detector** snapshots keyed bytes
 across :class:`~h2o3_tpu.utils.cleaner.Cleaner` sweeps and flags keys that
 keep growing, or that stay resident above a size floor with no DKV access,
 for N consecutive sweeps. Surfaced via ``GET /3/Memory``, the ``/metrics``
-gauges, real numbers in ``/3/Cloud``, and the bench artifact
-(``bench.py`` refuses to stamp when the detector fires on a real run).
+gauges and real numbers in ``/3/Cloud``.
 
 Everything here is host-side stdlib bookkeeping: byte registration is a
 dict write under one lock, and nothing is ever traced into an XLA program.
@@ -290,9 +289,9 @@ class LeakDetector:
 
     Semantics (documented in docs/OBSERVABILITY.md): a *sweep* is one
     :meth:`MemoryMeter.leak_sweep` generation — the Cleaner advances it on
-    every LRU sweep, and diagnostics (``bench.py``, tests) may advance it
-    explicitly. Per key the detector tracks a **growth streak** (consecutive
-    sweeps where registered bytes strictly increased) and an **idle streak**
+    every LRU sweep, and diagnostics (tests) may advance it explicitly. Per
+    key the detector tracks a **growth streak** (consecutive sweeps where
+    registered bytes strictly increased) and an **idle streak**
     (consecutive sweeps with no DKV put/get of the key). A key is flagged
     once either streak reaches ``LEAK_SWEEPS``, provided its bytes are at or
     above ``LEAK_MIN_BYTES`` (jobs and tiny models never page anyone)."""
@@ -491,8 +490,7 @@ class MemoryMeter:
         every frame put under an HBM budget — so it must stay O(keys):
         put/remove already keep the registered view current, and growth
         from in-place mutation is caught when the key is re-put or when a
-        ``/3/Memory`` read refreshes). ``bench.py`` and tests call it
-        directly."""
+        ``/3/Memory`` read refreshes). Tests call it directly."""
         with self._lock:
             keyed = {k: (kind, nbytes)
                      for k, (kind, nbytes, _host) in self._keyed.items()}

@@ -273,8 +273,7 @@ class MetricsRegistry:
 
     def snapshot(self, include_buckets: bool = True) -> list[dict]:
         """Flat sample rows — uniform {name, type, labels, value} dicts, so
-        the REST layer can serve them TwoDimTable-style and ``bench.py`` can
-        embed them in an artifact."""
+        the REST layer can serve them TwoDimTable-style."""
         # the registry RLock also guards every child mutation, so holding it
         # across the read pass yields a consistent snapshot (no torn
         # bucket-vs-count reads mid-observe); exports are rare and fast
@@ -559,8 +558,8 @@ ITER_SECONDS = METRICS.histogram(
 # dispatch economy of the same loops: blocking host fetches per logical
 # iteration (1.0 = the classic sync-per-step driver; 1/K under K-step
 # megasteps). Set by models/model_base.publish_dispatch_audit at the end of
-# every fit; bench gates on it so a per-iteration fetch cannot silently
-# return to a hot path.
+# every fit; tests/test_dispatch_audit.py pins it so a per-iteration fetch
+# cannot silently return to a hot path.
 DISPATCHES_PER_ITER = METRICS.gauge(
     "h2o3_dispatches_per_iteration",
     "blocking host syncs per logical iteration of a convergence loop "

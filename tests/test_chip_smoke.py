@@ -41,14 +41,13 @@ def test_refuses_without_a_tpu(tmp_path):
 
 
 def test_no_entry_point_starts_a_process():
-    """One process per chip: neither the smoke nor the bench may spawn,
-    probe in a child or re-exec — a parent that touched JAX holds the chip."""
-    for name in ("chip_smoke.py", "bench.py"):
-        with open(os.path.join(REPO, name)) as f:
-            src = f.read()
-        for token in ("subprocess", "execv", "Popen", "os.system",
-                      "multiprocessing", "jax_platforms"):
-            assert token not in src, (name, token)
+    """One process per chip: the smoke may not spawn, probe in a child or
+    re-exec — a parent that touched JAX holds the chip."""
+    with open(SMOKE) as f:
+        src = f.read()
+    for token in ("subprocess", "execv", "Popen", "os.system",
+                  "multiprocessing", "jax_platforms"):
+        assert token not in src, token
 
 
 def test_dry_cpu_runs_every_stage(tmp_path):

@@ -4,8 +4,7 @@ future reintroduction of a per-iteration ``device_get`` fails here fast.
 
 Counting strategy: ``jax.device_get`` is monkeypatched with a counting
 wrapper for the duration of each fit (every blocking batched fetch in the
-drivers goes through it), and the builders' ``_dispatch_audit`` — the same
-record bench embeds as ``extra.dispatch_audit`` and gates on — pins the
+drivers goes through it), and the builders' ``_dispatch_audit`` pins the
 loop-level accounting (iterations, host syncs, compiled dispatches).
 """
 

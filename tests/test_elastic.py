@@ -107,8 +107,8 @@ def test_kill_one_worker_completes_with_ejection(rng, monkeypatch):
     reassigned to the survivor, final quality within tolerance of the
     uninterrupted (k-1)-worker run, and the wall bounded far below the
     stall — the dead worker degrades throughput instead of stalling the
-    cloud. (The strict slowdown < 1/k gate runs in bench `extra.elastic`
-    on real hardware, where wall clocks mean something.)"""
+    cloud. (The strict slowdown < 1/k bound is a timing on real hardware,
+    where wall clocks mean something: not measured, ROADMAP S9.)"""
     monkeypatch.setenv("H2O3TPU_ELASTIC_ROUND_DEADLINE_SECS", "2.0")
     monkeypatch.setenv("H2O3TPU_ELASTIC_LEASE_SECS", "1.0")
     fr = _frame(rng)
@@ -129,6 +129,7 @@ def test_kill_one_worker_completes_with_ejection(rng, monkeypatch):
     assert b.job.workers_ejected == 1
     el = m.output["elastic"]
     assert el["shards_per_worker"] == 4
+    assert el["rounds"] == 3       # every epoch trained: no early exit
     assert el["ejections_by_reason"] == {"heartbeat": 1}
     assert el["per_worker"][1]["state"] == EJECTED
     # shard reassignment: the survivor picked up the dead worker's

@@ -1,6 +1,6 @@
 """Entry points against a sick or absent TPU backend: ``dryrun_multichip``
 (a CPU-only virtual-mesh audit by construction) must complete without ever
-touching the default backend, and ``bench.py`` — a measurement — must refuse.
+touching the default backend.
 
 Reference analog: the N-JVM localhost cloud always forms regardless of
 cluster state (``scripts/multiNodeUtils.sh:21-26``).
@@ -64,20 +64,3 @@ def test_dryrun_completes_with_sick_backend():
     assert "weak_scaling" in proc.stdout
     assert dt < 90, f"dryrun took {dt:.0f}s with a sick backend"
 
-
-def test_bench_refuses_without_a_tpu():
-    """bench.py measures on the chip or not at all: with no TPU (a backend
-    that cannot start, or plain CPU) it exits non-zero with one line, before
-    any work — no CPU re-exec, no JSON, no number."""
-    for env in (_sick_env(), dict(os.environ, JAX_PLATFORMS="cpu")):
-        env.pop("H2O3TPU_BENCH_SMOKE", None)
-        t0 = time.perf_counter()
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
-            env=env, capture_output=True, text=True, timeout=120)
-        assert proc.returncode != 0
-        assert time.perf_counter() - t0 < 30
-        assert proc.stdout == ""
-        reason = proc.stderr.strip().splitlines()
-        assert len(reason) == 1 and reason[0].startswith("bench.py:") \
-            and "TPU" in reason[0], proc.stderr[-2000:]

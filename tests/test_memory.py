@@ -124,6 +124,13 @@ def test_watermarks_are_monotonic():
     del _fr
 
 
+def test_sample_leaves_a_nonzero_host_watermark():
+    """A zero host watermark after a sample means the accounting broke
+    (``/proc`` unread, or the sample not folded into the peak)."""
+    rss, _dev = MEMORY.sample()
+    assert MEMORY.watermarks["host_rss_peak_bytes"] >= rss > 0
+
+
 # -- leak detector ------------------------------------------------------------
 
 
@@ -170,9 +177,9 @@ def test_meter_leak_sweep_end_to_end():
 
 
 def test_growth_detection_through_refresh_and_sweeps():
-    """The bench gate's signal end-to-end: a key growing in place across
+    """The leak detector's signal end-to-end: a key growing in place across
     interleaved refresh+sweep generations accumulates a growth streak and
-    flags as 'growing' (bench.py gates exit 3 on exactly this)."""
+    flags as 'growing'."""
     from h2o3_tpu.frame.vec import Vec
     fr = _frame(nrows=300_000, ncols=1, seed=11)     # above the byte floor
     DKV.put("grower", fr)
@@ -184,8 +191,8 @@ def test_growth_detection_through_refresh_and_sweeps():
     growing = [f for f in MEMORY.leak_report()["flagged"]
                if "growing" in f["reasons"]]
     assert any(f["key"] == "grower" for f in growing)
-    # one static sweep resets the growth streak (why bench captures growth
-    # BEFORE its post-hoc idle passes)
+    # one static sweep resets the growth streak (read growth flags BEFORE
+    # any back-to-back idle sweeps)
     MEMORY.leak_sweep()
     assert not any("growing" in f["reasons"]
                    for f in MEMORY.leak_report()["flagged"])

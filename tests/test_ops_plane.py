@@ -429,6 +429,36 @@ def test_stalled_worker_heals_with_one_preemptive_reassign(monkeypatch):
         eng.uninstall()
 
 
+def test_clean_sweeps_take_no_action(rng, monkeypatch):
+    """The negative of the three heals: sweeps over clean registries with a
+    warm build between them open no incident and record no action — an
+    engine that remediates normal operation pages ops with changes nobody
+    asked for."""
+    import numpy as np
+
+    from h2o3_tpu.frame.frame import Frame
+    from h2o3_tpu.models.glm import GLM
+    X = rng.normal(size=(400, 4)).astype(np.float32)
+    cols = {f"x{i}": X[:, i] for i in range(4)}
+    cols["y"] = np.where(X[:, 0] - X[:, 1] > 0, "Y", "N")
+    fr = Frame.from_arrays(cols)
+
+    def build():
+        GLM(family="binomial", lambda_=1e-4, max_iterations=8).train(
+            y="y", training_frame=fr)
+
+    build()                 # compiles land outside the watched window
+    ev, eng = _healing_rig(monkeypatch)
+    try:
+        ev.evaluate()                              # window baseline
+        build()
+        ev.evaluate()
+        assert ev.incidents.opened_total() == 0
+        assert eng.actions.recorded_total() == 0
+    finally:
+        eng.uninstall()
+
+
 def test_observe_mode_heals_nothing_but_logs_the_decision(monkeypatch):
     cleaner = _StubCleaner(budget=1 << 20)
     monkeypatch.setattr(oa, "_cleaner", lambda: cleaner)

@@ -256,7 +256,7 @@ class TestShedding:
         def caller():
             try:
                 b.submit(*entry.schema.adapt_rows(_rows(frame, 2)), 2)
-            except BaseException as e:   # noqa: BLE001 — asserted below
+            except Exception as e:   # noqa: BLE001 — asserted below
                 errs.append(e)
 
         t = threading.Thread(target=caller)
@@ -305,7 +305,7 @@ class TestShedding:
         def caller():
             try:
                 SCORING.score(gbm.key, _rows(frame, 2))
-            except BaseException as e:   # noqa: BLE001 — asserted below
+            except Exception as e:   # noqa: BLE001 — asserted below
                 errs.append(e)
 
         t = threading.Thread(target=caller)
@@ -593,6 +593,37 @@ class TestRestSurface:
                    for s in st["shed"])
         text = client.metrics_text()
         assert "h2o3_score_shed_total" in text
+
+    def test_build_beside_serving_completes_and_compiles_nothing(
+            self, frame, gbm, client):
+        """A GBM build in the same process while ``/3/Score`` is served:
+        the build completes, every reply equals the warm one, and the warm
+        window compiles no scorer."""
+        from h2o3_tpu.models.gbm import GBM
+        from h2o3_tpu.utils.telemetry import SCORER_CACHE
+        rows = _rows(frame, 16)
+        warm = client.score(gbm.key, rows)["predictions"]
+        misses0 = SCORER_CACHE.labels(event="miss").value
+        err: list = []
+
+        def train():
+            try:
+                GBM(ntrees=6, max_depth=4, seed=9,
+                    model_id="slo_beside").train(y="y", training_frame=frame)
+            except Exception as e:   # noqa: BLE001 — asserted below
+                err.append(e)
+
+        trainer = threading.Thread(target=train, daemon=True)
+        trainer.start()
+        served = 0
+        while served < 10 or (trainer.is_alive() and served < 500):
+            assert client.score(gbm.key, rows)["predictions"] == warm
+            served += 1
+        trainer.join(timeout=120)
+        assert not trainer.is_alive() and not err, err
+        assert DKV.get("slo_beside") is not None
+        assert SCORER_CACHE.labels(event="miss").value == misses0
+        assert client.serving()["shed_total"] == 0
 
     def test_serving_view_carries_replicas(self, frame, gbm, client):
         from h2o3_tpu.orchestration.scheduler import MeshScheduler

@@ -45,6 +45,8 @@ def test_h2o_py_connects_over_https(cert, tmp_path):
     """The REAL h2o-py client over https with a self-signed cert."""
     import os
     import sys
+    if not os.path.isdir("/root/reference/h2o-py"):
+        pytest.importorskip("h2o")     # the child below has to import it
     crt, key = cert
     script = tmp_path / "flow.py"
     script.write_text(f"""
