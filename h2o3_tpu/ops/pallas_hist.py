@@ -8,26 +8,40 @@ kernel"). For every feature f, tree node n, bin b:
 
 XLA's ``segment_sum`` lowering of this inside the fused tree program runs at
 ~110 ms/level on 500k×28 (scatter-add serialization); this kernel instead
-rides the MXU: per (row-tile, feature) grid step it builds the transposed
-bin one-hot [S, T] on the VPU and contracts it against a per-tile
-node×stat spread matrix ns[T, Nb*3] (computed once per tile into VMEM
-scratch), accumulating histograms in a resident VMEM output block.
+rides the MXU: per row tile it builds the node×stat spread matrix
+ns[Nb*3, T] once, and contracts the transposed bin one-hots of EVERY feature
+of the block ([S, T] a feature, built on the VPU, a few features stacked to
+one left-hand side) against it, accumulating histograms in a resident VMEM
+output block.
 
-Tiling (round-3 lift of the depth-6/narrow-F cliff): the grid is
-(node-blocks, feature-blocks, row-tiles, features-in-block). The output
-block holds one (feature-block × node-block) slab and stays VMEM-resident
-across the row sweep; node blocks beyond the first re-read the inputs, so
-HBM traffic scales with ``ceil(N / NODE_BLOCK)`` — the dispatch layer caps
-how many blocks are worth it (measured crossover vs the scatter path; see
-``_MAX_NODE_BLOCKS`` and ROOFLINE.md). FLOP cost is R·F·2·S·3·N MACs and
-doubles per level — the MXU wins while the arithmetic stays under the
-scatter path's serialization, not asymptotically.
+Tiling: the grid is (node-blocks, feature-blocks, row-tiles); a step takes
+one ``[Fb, T]`` block of bins — all the block's features for the row tile in
+one DMA. The output block holds one (feature-block × node-block) slab and
+stays VMEM-resident across the row sweep; node blocks beyond the first
+re-read the inputs, so HBM traffic scales with ``ceil(N / NODE_BLOCK)`` — the
+dispatch layer caps how many blocks are worth it (measured crossover vs the
+scatter path; see ``_MAX_NODE_BLOCKS`` and ROOFLINE.md). ``_plan`` picks the
+blocks and the row tile from the shapes alone: the body is straight-line
+code, so the tile is as long as ``_STEP_MATMULS`` MXU instructions and
+``_VMEM_BUDGET`` allow (4,096 rows at 28 features × 64 bins, 1,024 at 256
+bins).
+
+The MXU streams the one-hot's rows (one a cycle and MXU) past the latched
+statistics, so a call costs R/128 · F · S row-cycles a PASS whatever the
+node count — and the bf16 digits of the statistics (``_MXU_MODE``) need not
+be passes: while ``digits · Nb·3`` columns fit the MXU's 128 lanes they sit
+side by side in ONE right-hand side ``[hi | lo]`` and the halves of the
+product are added afterwards — the same products and float32 sums as a pass
+a digit, at half (a third) of the rows streamed. Wider node blocks keep a
+pass a digit. FLOP cost is R·F·2·S·3·N MACs and doubles per level — the MXU
+wins while the arithmetic stays under the scatter path's serialization, not
+asymptotically.
 
 Layout notes (Mosaic constraints): the bin one-hot is built TRANSPOSED
 ([S, T], bins on sublanes) because dynamic lane indexing is unsupported;
-binned is passed pre-transposed [F, 1, R] so each grid step DMAs a
-contiguous [1, 1, T] row block; the per-feature output offset uses an
-8-aligned padded bin stride S.
+binned is passed pre-transposed [F, R] so a step's block is ``Fb`` contiguous
+row runs; the per-feature output offset uses an 8-aligned padded bin
+stride S.
 """
 
 from __future__ import annotations
@@ -37,17 +51,17 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-_TILE = 1024
+from h2o3_tpu.utils.telemetry import HIST_GRID_STEPS, HIST_KERNEL_LEVELS
+
 #: MXU precision mode for the one-hot contraction. The one-hot operand is
 #: EXACTLY representable in bf16 (entries 0/1), so only the stats operand
-#: needs splitting: "hilo" = 2 bf16 passes (stats to 16-bit mantissa,
-#: ~1.5e-5 relative — vs the ~4e-3 of a single bf16 pass that flips
-#: near-tie splits), "hilo3" = 3 passes (24-bit mantissa, f32-exact),
+#: needs splitting: "hilo" = 2 bf16 digits (stats to 16-bit mantissa,
+#: ~1.5e-5 relative — vs the ~4e-3 of a single bf16 digit that flips
+#: near-tie splits), "hilo3" = 3 digits (24-bit mantissa, f32-exact),
 #: "highest" = XLA's 6-pass f32 decomposition (the round-3 default).
-#: 2 passes ≈ 3x the MXU throughput of HIGHEST for identical tree quality
-#: at the tolerance the split scan already works in (f32 cumsums).
+#: A digit is a pass of the one-hot through the MXU only where the digits do
+#: not fit its lanes side by side (``_packed``).
 _MXU_MODE = os.environ.get("H2O3TPU_HIST_MXU", "hilo").strip().lower()
 if _MXU_MODE not in ("hilo", "hilo3", "highest"):
     raise ValueError(
@@ -62,26 +76,99 @@ _NODE_BLOCK = 64     # nodes per resident output slab
 #: 1M×28×64bins: 3.8× win at N=2048 (32 blocks), loss at N=4096 — so 32
 #: blocks ≡ tree depth ≤ 11 stays on the kernel (ROOFLINE.md has the table).
 _MAX_NODE_BLOCKS = 32
-#: validated up to 10.7MB resident (257 bins × 64 nodes × 28 features in one
-#: slab) on v5e's 16MB VMEM — keep 256-bin × F≈28 configs single-block
-_VMEM_BUDGET = 11 * 1024 * 1024
+#: the scoped VMEM the kernel asks the compiler for (a v5e has 128 MiB; the
+#: default scope is 16 MiB), and what ``_vmem_bytes`` may add up to under
+#: it: the compiler keeps spills and matmul staging of its own beside what
+#: the account names
+_VMEM_LIMIT = 32 * 1024 * 1024
+_VMEM_BUDGET = 24 * 1024 * 1024
+_LANES = 128         # the MXU's and a vector register's width
+#: longest row tile, and the MXU instructions (16 one-hot rows each) one
+#: grid step may unroll to: the body is straight-line code, four bundles an
+#: instruction, and a step of this many runs 10 µs against the 0.35 µs a
+#: grid step costs by itself
+_TILE_MAX = 4096
+_STEP_MATMULS = 4096
+#: one-hot rows stacked to one left-hand side, at the least: enough to hide
+#: the latching of the statistics behind the streaming (one feature's 72
+#: rows cost 8% more a call, its 264 rows 3%; all 28 features buy nothing
+#: over these), few enough that the stacked one-hot stays small where the
+#: compiler materialises it
+_GROUP_ROWS = 512
+
+
+def _ceil_to(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _digits() -> int:
+    """bf16 digits the statistics are split into (``highest``: none, the
+    float32 operands go to the MXU as they are)."""
+    return {"hilo": 2, "hilo3": 3, "highest": 1}[_MXU_MODE]
+
+
+def _packed(Nb: int) -> bool:
+    """Whether every digit of a node block's statistics fits the MXU's lanes
+    side by side, so that one pass of the one-hot serves them all."""
+    return _MXU_MODE != "highest" and _digits() * Nb * 3 <= _LANES
+
+
+def _passes(Nb: int) -> int:
+    """Times a step streams the one-hot through the MXU: once where the
+    digits are packed, else a pass a digit (``highest``: XLA's six) for
+    every 128 columns of the node block's statistics."""
+    if _packed(Nb):
+        return 1
+    return (6 if _MXU_MODE == "highest" else _digits()) * -(-Nb * 3 // _LANES)
+
+
+def _group(S: int, Fb: int) -> int:
+    """Features whose one-hots are stacked to one left-hand side."""
+    return min(Fb, -(-_GROUP_ROWS // S))
+
+
+def _vmem_bytes(Nb: int, Fb: int, T: int, S: int, out_blocks: int = 1) -> int:
+    """What a grid step holds in VMEM, by the padded shapes Mosaic gives
+    them ((8, 128) tiles of 32-bit words, 16 sublanes of bf16, 32 of int8;
+    bins counted at two bytes, their wider storage). The output slab is
+    resident; where the call has more than one (``out_blocks``: node blocks
+    x feature blocks) the pipeline holds the next one's buffer too."""
+    k3 = Nb * 3
+    digits, G = _digits(), _group(S, Fb)
+    item = 4 if _MXU_MODE == "highest" else 2       # the MXU's operands
+    cols = digits * k3 if _packed(Nb) else k3       # of one product
+    inputs = 2 * T * (_ceil_to(Fb, 32) * 2 + 8 * 4 + 8 * 4)  # double-buffered
+    ns = _ceil_to(k3, 8) * T * 4
+    rhs = digits * _ceil_to(k3, 16) * T * item
+    if _packed(Nb):                                 # side by side, via f32
+        rhs += _ceil_to(cols, 8) * T * 4 + _ceil_to(cols, 16) * T * item
+    bins = _ceil_to(Fb, 8) * T * 4                  # upcast to int32
+    onehot = G * S * T * item
+    acc = G * S * _ceil_to(cols, _LANES) * 4
+    out = Fb * S * _ceil_to(k3, _LANES) * 4 * min(out_blocks, 2)
+    return inputs + ns + rhs + bins + onehot + acc + out
 
 
 def _plan(n_nodes: int, n_feat: int, n_bins_tot: int):
-    """(node_block, feat_block) tile sizes, or None if out of envelope."""
-    S = ((n_bins_tot + 7) // 8) * 8
+    """(node_block, feat_block, row_tile), or None if out of envelope. The
+    feature block is the whole frame where that fits, else a multiple of 32
+    (a sublane tile of every bin storage); the row tile is the longest
+    multiple of 128 that ``_STEP_MATMULS`` and ``_VMEM_BUDGET`` allow."""
+    S = _ceil_to(n_bins_tot, 8)
     Nb = min(n_nodes, _NODE_BLOCK)
-    if (n_nodes + Nb - 1) // Nb > _MAX_NODE_BLOCKS:
+    n_gb = -(-n_nodes // Nb)
+    if n_gb > _MAX_NODE_BLOCKS:
         return None
-    # resident out slab Fb*S*Nb*3*4 within budget after fixed costs
-    fixed = (_TILE * Nb * 3 * 4          # ns scratch
-             + S * _TILE * 4             # bin one-hot
-             + 3 * _TILE * 128 * 4 * 2)  # padded input double-buffers
-    per_feat = S * Nb * 3 * 4
-    Fb = max(1, min(n_feat, (_VMEM_BUDGET - fixed) // per_feat))
-    if Fb < 1 or fixed + per_feat > _VMEM_BUDGET:
-        return None
-    return Nb, Fb
+    for Fb in [n_feat, *range((n_feat - 1) // 32 * 32, 0, -32)]:
+        blocks = n_gb * -(-n_feat // Fb)
+        per_128_rows = -(-Fb * S // 16) * _passes(Nb)
+        T = _LANES * max(1, min(_TILE_MAX // _LANES,
+                                _STEP_MATMULS // per_128_rows))
+        while T > _LANES and _vmem_bytes(Nb, Fb, T, S, blocks) > _VMEM_BUDGET:
+            T -= _LANES
+        if _vmem_bytes(Nb, Fb, T, S, blocks) <= _VMEM_BUDGET:
+            return Nb, Fb, T
+    return None
 
 
 def pallas_available(n_nodes: int, n_feat: int, n_bins_tot: int,
@@ -98,14 +185,13 @@ def pallas_available(n_nodes: int, n_feat: int, n_bins_tot: int,
     return _plan(n_nodes, n_feat, n_bins_tot) is not None
 
 
-def _hist_kernel(b_ref, n_ref, s_ref, out_ref, ns_ref, *, Nb, S, T, Fb):
+def _hist_kernel(b_ref, n_ref, s_ref, out_ref, *, Nb, S, Fb):
     import jax.experimental.pallas as pl
 
     gb = pl.program_id(0)      # node block
     i = pl.program_id(2)       # row tile
-    fi = pl.program_id(3)      # feature within block
 
-    @pl.when(jnp.logical_and(i == 0, fi == 0))
+    @pl.when(i == 0)
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
@@ -113,45 +199,45 @@ def _hist_kernel(b_ref, n_ref, s_ref, out_ref, ns_ref, *, Nb, S, T, Fb):
     # (node-block, row-tile). Inputs arrive ROW-MAJOR-TRANSPOSED ([3, R],
     # [1, R]): a narrow [R, 3] array in HBM pads its 3-wide minor dim to 128
     # lanes (42x memory blowup at 11M rows); [3, R] pads 3 sublanes to 8.
-    @pl.when(fi == 0)
-    def _():
-        nd = n_ref[0, :]
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, (Nb * 3, 1), 0)
-        ghw_rep = jnp.concatenate([s_ref[:]] * Nb, axis=0)         # [Nb*3, T]
-        ns_ref[:] = jnp.where(nd[None, :] == gb * Nb + iota_k // 3,
-                              ghw_rep, 0.0)
-
-    binf = b_ref[0, 0, :].astype(jnp.int32)   # i8/i16 in HBM (gbm._bin_frame
-    #                                           packs <=125-bin configs to
-    #                                           int8); upcast per tile
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (S, 1), 0)
+    k3 = Nb * 3
+    nd = n_ref[0, :]
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (k3, 1), 0)
+    ghw_rep = jnp.concatenate([s_ref[:]] * Nb, axis=0)             # [Nb*3, T]
+    ns = jnp.where(nd[None, :] == gb * Nb + iota_k // 3, ghw_rep, 0.0)
+    digits, packed = _digits(), _packed(Nb)
     if _MXU_MODE == "highest":
-        bin_oh_T = (iota_r == binf[None, :]).astype(jnp.float32)   # [S, T]
-        acc = jax.lax.dot_general(
-            bin_oh_T, ns_ref[:], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)                   # [S, Nb*3]
+        oh_dtype, precision, rhs = jnp.float32, jax.lax.Precision.HIGHEST, [ns]
     else:
         # one-hot is bf16-exact; split only the stats operand into bf16
         # digits and accumulate the partial products in f32 — 2 (or 3)
-        # MXU passes instead of HIGHEST's 6 (see _MXU_MODE)
-        oh16 = (iota_r == binf[None, :]).astype(jnp.bfloat16)      # [S, T]
+        # digits instead of HIGHEST's 6 passes (see _MXU_MODE)
+        oh_dtype, precision, rhs, r = jnp.bfloat16, None, [], ns
+        for _ in range(digits):
+            rhs.append(r.astype(jnp.bfloat16))
+            r = r - rhs[-1].astype(jnp.float32)
+        if packed:
+            # [hi | lo] side by side: one pass of the one-hot for all digits
+            rhs = [jnp.concatenate([m.astype(jnp.float32) for m in rhs], 0
+                                   ).astype(jnp.bfloat16)]
 
-        def bdot(rhs16):
-            return jax.lax.dot_general(
-                oh16, rhs16, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        ns = ns_ref[:]
-        hi = ns.astype(jnp.bfloat16)
-        acc = bdot(hi)
-        r1 = ns - hi.astype(jnp.float32)
-        m1 = r1.astype(jnp.bfloat16)
-        acc += bdot(m1)
-        if _MXU_MODE == "hilo3":
-            r2 = (r1 - m1.astype(jnp.float32)).astype(jnp.bfloat16)
-            acc += bdot(r2)
-    out_ref[0, 0, pl.ds(fi * S, S), :] += acc
+    # i8/i16 in HBM (gbm._bin_frame packs <=125-bin configs to int8);
+    # upcast per tile
+    bins = b_ref[:].astype(jnp.int32)                              # [Fb, T]
+    iota_r = jax.lax.broadcasted_iota(jnp.int32, (S, 1), 0)
+    G = _group(S, Fb)
+    for f0 in range(0, Fb, G):
+        f1 = min(f0 + G, Fb)
+        oh = jnp.concatenate([iota_r == bins[f:f + 1, :]
+                              for f in range(f0, f1)], 0).astype(oh_dtype)
+        parts = [jax.lax.dot_general(oh, m, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32,
+                                     precision=precision) for m in rhs]
+        if packed:                      # the digits' columns of one product
+            parts = [parts[0][:, d * k3:(d + 1) * k3] for d in range(digits)]
+        acc = parts[0]
+        for part in parts[1:]:          # hi + lo (+ lo2), as a pass a digit
+            acc = acc + part
+        out_ref[0, 0, f0 * S:f1 * S, :] += acc
 
 
 @partial(jax.jit, static_argnames=("n_nodes", "n_bins_tot"))
@@ -160,12 +246,13 @@ def hist_pallas(binned_T, node, g, h, w, n_nodes: int, n_bins_tot: int):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    N, Bt, T = n_nodes, n_bins_tot, _TILE
+    N, Bt = n_nodes, n_bins_tot
     F, R = binned_T.shape
-    S = ((Bt + 7) // 8) * 8
-    Nb, Fb = _plan(N, F, Bt)
-    n_gb = (N + Nb - 1) // Nb
-    n_fb = (F + Fb - 1) // Fb
+    S = _ceil_to(Bt, 8)
+    Nb, Fb, T = _plan(N, F, Bt)
+    T = min(T, _ceil_to(R, _LANES))     # a frame shorter than the tile
+    n_gb = -(-N // Nb)
+    n_fb = -(-F // Fb)
     padf = n_fb * Fb - F
     if padf:
         # feature padding: rows read a duplicate of the last feature; the
@@ -180,29 +267,33 @@ def hist_pallas(binned_T, node, g, h, w, n_nodes: int, n_bins_tot: int):
         h = jnp.pad(h, (0, pad))
         w = jnp.pad(w, (0, pad))
     Rp = binned_T.shape[1]
+    # counted where the kernel is TRACED (a cached trace adds nothing)
+    HIST_KERNEL_LEVELS.labels(
+        contraction="packed" if _packed(Nb) else "passes").inc()
+    HIST_GRID_STEPS.inc(n_gb * n_fb * (Rp // T))
     act = node >= 0
     # stats-major [3, R] / [1, R]: see layout note in the kernel
     ghw_T = jnp.stack([g, h, w], 0) * act[None, :].astype(jnp.float32)
     nodec = jnp.where(act, node, -1)[None, :]
     out = pl.pallas_call(
-        partial(_hist_kernel, Nb=Nb, S=S, T=T, Fb=Fb),
+        partial(_hist_kernel, Nb=Nb, S=S, Fb=Fb),
         interpret=_INTERPRET,
         out_shape=jax.ShapeDtypeStruct((n_gb, n_fb, Fb * S, Nb * 3),
                                        jnp.float32),
-        grid=(n_gb, n_fb, Rp // T, Fb),
+        grid=(n_gb, n_fb, Rp // T),
         in_specs=[
-            pl.BlockSpec((1, 1, T), lambda gb, fb, i, fi: (fb * Fb + fi, 0, i),
+            pl.BlockSpec((Fb, T), lambda gb, fb, i: (fb, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, T), lambda gb, fb, i, fi: (0, i),
+            pl.BlockSpec((1, T), lambda gb, fb, i: (0, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, T), lambda gb, fb, i, fi: (0, i),
+            pl.BlockSpec((3, T), lambda gb, fb, i: (0, i),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, Fb * S, Nb * 3),
-                               lambda gb, fb, i, fi: (gb, fb, 0, 0),
+                               lambda gb, fb, i: (gb, fb, 0, 0),
                                memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((Nb * 3, T), jnp.float32)],
-    )(binned_T[:, None, :], nodec, ghw_T)
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+    )(binned_T, nodec, ghw_T)
     # [n_gb, n_fb, Fb*S, Nb*3] → [F, N, S, 3] → clip padding → [F, N*Bt, 3]
     out = out.reshape(n_gb, n_fb, Fb, S, Nb, 3)
     out = out.transpose(1, 2, 0, 4, 3, 5).reshape(n_fb * Fb, n_gb * Nb, S, 3)

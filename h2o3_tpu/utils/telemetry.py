@@ -547,6 +547,19 @@ BIN_COLUMNS = METRICS.counter(
 ROUTE_LEVELS = METRICS.counter(
     "h2o3_route_levels", "tree levels traced, by row-routing path", ("path",))
 
+# the Pallas histogram kernel (ops/pallas_hist.py ``hist_pallas``), counted
+# where a call is TRACED (jit traces a signature once: two levels of one
+# shape, or a cached program, add nothing): one increment a traced call, by
+# how the statistics' bf16 digits meet the one-hot — ``packed`` side by side
+# in the MXU's lanes, one pass for all, or ``passes``, a pass a digit — and
+# the grid steps that call runs (node blocks x feature blocks x row tiles).
+HIST_KERNEL_LEVELS = METRICS.counter(
+    "h2o3_hist_kernel_levels",
+    "histogram kernel calls traced, by how the digits are contracted",
+    ("contraction",))
+HIST_GRID_STEPS = METRICS.counter(
+    "h2o3_hist_grid_steps", "grid steps of the histogram kernel calls traced")
+
 # host-driven convergence loops (models/*.py drivers): per-iteration wall
 # time — IRLS steps, boosting chunks, DL epochs. The before/after evidence
 # for host-sync batching fixes (graftlint TRC003) lives here: fewer

@@ -73,7 +73,10 @@ def test_dry_cpu_runs_every_stage(tmp_path):
 
 def test_hist_kernel_compiles_for_v5e(monkeypatch):
     """AOT: libtpu compiles for a described topology with no chip attached.
-    Both ends of the bin-storage envelope must lower to a Mosaic call."""
+    Both ends of the bin-storage envelope must lower to a Mosaic call, at a
+    full node block, at the benchmark's own shapes (its cells' rows, storage
+    and deepest call) and at a frame wider than one feature block: a tile
+    that overflows VMEM there fails here, on the CPU."""
     monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
     monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
     from jax.experimental import topologies
@@ -85,18 +88,22 @@ def test_hist_kernel_compiles_for_v5e(monkeypatch):
     assert topo.devices[0].device_kind == "TPU v5 lite"
     assert pallas_hist._INTERPRET is False
     on_chip = SingleDeviceSharding(topo.devices[0])
-    rows, feats = 1_000_000, 28
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
 
-    for n_bins_tot, dtype in ((65, jnp.int8), (257, jnp.int16)):
+    for rows, feats, n_bins_tot, dtype, n_nodes in (
+            (1_000_000, 28, 65, jnp.int8, 64),
+            (1_000_000, 28, 257, jnp.int16, 64),
+            (22_000_000, 28, 65, jnp.int8, 16),
+            (11_000_000, 28, 257, jnp.int16, 16),
+            (1_000_000, 500, 257, jnp.int16, 64)):
         exe = pallas_hist.hist_pallas.lower(
             spec((feats, rows), dtype), spec((rows,), jnp.int32),
             spec((rows,), jnp.float32), spec((rows,), jnp.float32),
             spec((rows,), jnp.float32),
-            n_nodes=64, n_bins_tot=n_bins_tot).compile()
-        assert "tpu_custom_call" in exe.as_text(), n_bins_tot
+            n_nodes=n_nodes, n_bins_tot=n_bins_tot).compile()
+        assert "tpu_custom_call" in exe.as_text(), (rows, feats, n_bins_tot)
 
 
 def test_kernel_refuses_an_operand_on_several_devices(monkeypatch):
