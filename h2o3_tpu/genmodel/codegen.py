@@ -75,6 +75,8 @@ Only dependency: numpy. Entry points:
 """
 import numpy as np
 
+inf, nan = np.inf, np.nan    # as the array literals below print them
+
 '''
 
 _ROW_HELPERS = '''

@@ -53,7 +53,7 @@ class DecisionTree(SharedTreeBuilder):
         w = weights * valid
         yy = jnp.where(w > 0, yy, 0.0)
 
-        tp = TreeParams(max_depth=int(p["max_depth"]), nbins=int(p["nbins"]),
+        tp = TreeParams(max_depth=int(p["max_depth"]), nbins=self._n_bins,
                         min_rows=float(p["min_rows"]), reg_lambda=0.0,
                         min_split_improvement=float(p["min_split_improvement"]))
         # identity-gradient trick: g = -w*y, h = w ⇒ leaf = Σwy/Σw (node mean)

@@ -34,8 +34,8 @@ from jax import lax
 
 from h2o3_tpu.models.tree import (Tree, _grow_tree_device, _walk_binned,
                                   fold_binned, predict_binned, predict_raw)
-from h2o3_tpu.ops.quantile import (bin_column, bin_dtype, bin_features,
-                                    compute_bin_edges)
+from h2o3_tpu.ops.quantile import (bin_column, bin_features, bin_levels,
+                                    cat_bins_for_codes, compute_bin_edges)
 
 
 def tree_matrix(frame: Frame, cols: list[str], domains: dict[str, tuple]) -> jax.Array:
@@ -597,7 +597,7 @@ class SharedTreeBuilder(ModelBuilder):
             calibrate_model=False,           # CalibrationHelper.java:18
             calibration_frame=None,
             calibration_method="PlattScaling",   # or IsotonicRegression
-            nbins_cats=1024,                 # DHistogram enum bins (capped at nbins here)
+            nbins_cats=1024,                 # DHistogram enum bins: a bin a level up to this, whatever nbins is
             categorical_encoding="AUTO",     # AUTO/enum = group splits; ordinal = thresholds
             offset_column=None,              # per-row margin offset (Model.Parameters._offset)
         )
@@ -661,10 +661,16 @@ class SharedTreeBuilder(ModelBuilder):
         # the two phases a profile reads by name: host numpy while the
         # device waits, then the instant binning is first dispatched (it
         # runs on asynchronously and drains at _fit's f0 fetch)
-        with timed_event("phase", f"{self.algo}:prepare.edges"):
-            edges = jnp.asarray(compute_bin_edges(
-                sample, int(self.params["nbins"]), w_sample))
         self._setup_cat_info(frame, x)
+        with timed_event("phase", f"{self.algo}:prepare.edges"):
+            edges = compute_bin_edges(sample, int(self.params["nbins"]),
+                                      w_sample)
+            # the edges' width IS the engine's bin count (bin_column and
+            # bin_features read it off them): inf-padded up to it where a
+            # categorical column has more bins than ``nbins``
+            edges = jnp.asarray(np.pad(
+                edges, ((0, 0), (0, self._n_bins - 1 - edges.shape[1])),
+                constant_values=np.inf))
         with timed_event("phase", f"{self.algo}:prepare.bin"):
             binned = self._bin_frame(frame, x, edges)
         from h2o3_tpu.models.data_info import response_as_float
@@ -678,32 +684,35 @@ class SharedTreeBuilder(ModelBuilder):
         edges <= x (``quantile.bin_column``: fused compares, no gather — a
         binary search's per-step gathers from the edge table cost 21 s of
         a 33 s build at 255 edges on the v5e, PERF.md PR 25); a categorical
-        column's is its (range-grouped) level code. The dtype is the
-        narrowest that holds every bin id PLUS the Pallas pad sentinel
-        (n_bins_tot + 1): int8 up to 125 bins halves HBM reads of the
-        histogram kernel's dominant input vs int16 (the default 64-bin
-        config packs; the 256-bin XGBoost config stays int16) — VERDICT r4
-        next #2."""
-        from h2o3_tpu.models.tree import cat_bins_for_codes
-        nbins = int(self.params["nbins"])
-        dtype = bin_dtype(nbins)
+        column's is its level code (``quantile.bin_levels``; range-grouped
+        past ``nbins_cats``). Bin count, NA bin and dtype are the edges'
+        width's (``self._n_bins - 1``): the dtype is the narrowest that
+        holds every bin id PLUS the Pallas pad sentinel (n_bins_tot + 1):
+        int8 up to 125 bins halves HBM reads of the histogram kernel's
+        dominant input vs int16 (the default 64-bin config packs; the
+        256-bin XGBoost config stays int16) — VERDICT r4 next #2."""
+        n_bins = edges.shape[1] + 1
         cc, cat_bins = (self._cat_info if self._cat_info is not None
                         else (None, 0))
         cols = []
         for j, c in enumerate(x):
             v = frame.vec(c).as_float()
             if cc is not None and int(cc[j]) > 0:
-                b = cat_bins_for_codes(v[:, None], cc[j:j + 1], cat_bins)[:, 0]
-                b = jnp.where(jnp.isnan(v), nbins, b).astype(dtype)
+                b = bin_levels(v, int(cc[j]), cat_bins, n_bins)
             else:
                 b = bin_column(v, edges[j])
             cols.append(b)
         return jnp.stack(cols, axis=1)
 
     def _setup_cat_info(self, frame: Frame, x: list[str]) -> None:
-        """Categorical group-split binning state (reference: DHistogram gives
-        enums one bin per level up to ``nbins_cats``, then range-groups;
-        ``categorical_encoding="ordinal"`` opts back into threshold splits)."""
+        """Categorical group-split binning state, and the engine's bin count
+        ``self._n_bins`` (reference: DHistogram gives an enum one bin per
+        level up to ``nbins_cats`` whatever ``nbins`` is, then range-groups;
+        ``categorical_encoding="ordinal"`` opts back into threshold splits).
+        One bin count for the whole engine: ``nbins``, or the largest
+        categorical column's ``min(cardinality, nbins_cats)`` where that is
+        more, the NA bin after it; numeric columns leave the upper bins
+        empty. A model without categorical columns has ``nbins``."""
         enc = str(self.params.get("categorical_encoding") or "AUTO").lower()
         cat_card = np.zeros(len(x), np.int32)
         if enc in ("auto", "enum"):
@@ -713,26 +722,27 @@ class SharedTreeBuilder(ModelBuilder):
         elif enc not in ("ordinal", "label_encoder", "labelencoder"):
             raise ValueError(f"unsupported categorical_encoding {enc!r}; "
                              "have AUTO, enum, ordinal/label_encoder")
+        nbins = int(self.params["nbins"])
         if cat_card.any():
-            nbins = int(self.params["nbins"])
-            cat_bins = min(nbins, int(self.params.get("nbins_cats") or nbins))
+            cat_bins = int(self.params.get("nbins_cats") or nbins)
             self._cat_info = (jnp.asarray(cat_card), cat_bins)
+            self._n_bins = max(nbins, min(int(cat_card.max()), cat_bins))
         else:
             self._cat_info = None
+            self._n_bins = nbins
 
     def _apply_cat_bins(self, X, binned):
-        """Re-bin categorical columns: bin = (possibly range-grouped) level
-        code, missing stays the overflow bin."""
+        """Re-bin the categorical columns of a ``bin_features`` result (a
+        validation frame): bin = (possibly range-grouped) level code,
+        missing stays the NA bin; count and dtype are ``binned``'s own."""
         if self._cat_info is None:
             return binned
         cc, cat_bins = self._cat_info
-        from h2o3_tpu.models.tree import cat_bins_for_codes
-        nbins = int(self.params["nbins"])
         cb = cat_bins_for_codes(X, cc, cat_bins)
         is_cat = cc[None, :] > 0
         nan = jnp.isnan(X)
         out = jnp.where(is_cat & ~nan, cb, binned)
-        return jnp.where(is_cat & nan, nbins, out).astype(binned.dtype)
+        return jnp.where(is_cat & nan, self._n_bins, out).astype(binned.dtype)
 
     @property
     def _cat_feats(self):
@@ -889,6 +899,10 @@ class SharedTreeBuilder(ModelBuilder):
                 int(self._cat_info[1]):
             raise ValueError("checkpoint nbins_cats differs; immutable "
                              "across resume")
+        if cp.output["edges"].shape[1] + 1 != self._n_bins:
+            raise ValueError(
+                "checkpoint bin count differs (a categorical column's "
+                "cardinality changed); immutable across resume")
         # learn_rate scales EVERY tree at scoring time — changing it across a
         # resume would silently rescale the checkpoint's trees too
         if "learn_rate" in self.params and "learn_rate" in cp.params:
@@ -1039,13 +1053,13 @@ class GBM(SharedTreeBuilder):
             # fold (not sum-then-scale): the resumed margins must match the
             # uninterrupted scan's accumulation order bit-for-bit, so the
             # remaining trees come out identical (exact-resume contract)
-            Fcur = fold_binned(binned, trees, int(p["nbins"]), lr, Fcur)
+            Fcur = fold_binned(binned, trees, self._n_bins, lr, Fcur)
         ntrees = int(p["ntrees"])
         done = len(trees)
         keys = jax.random.split(key, ntrees * 3).reshape(ntrees, 3, 2)[done:]
         job.update(0.1, f"growing {ntrees - done} trees (one fused program)")
         kwargs = dict(
-            dist=dist, depth=int(p["max_depth"]), n_bins=int(p["nbins"]),
+            dist=dist, depth=int(p["max_depth"]), n_bins=self._n_bins,
             col_rate=self._effective_col_rate(),
             sample_rate=float(p["sample_rate"]),
             col_tree_rate=float(p["col_sample_rate_per_tree"]),
@@ -1167,7 +1181,7 @@ class GBM(SharedTreeBuilder):
         if wcol and wcol in vf:
             wv = wv * vf.vec(wcol).data
         yv = jnp.where(wv > 0, yv, 0.0)
-        nbins = int(self.params["nbins"])
+        nbins = self._n_bins
         if nclass > 1:
             Fval = jnp.broadcast_to(
                 jnp.asarray(f0, jnp.float32)[None, :],
@@ -1408,14 +1422,14 @@ class GBM(SharedTreeBuilder):
             # per-class sequential fold matches the scan's per-round
             # accumulation order exactly (see the single-class path)
             Fcur = jnp.stack(
-                [fold_binned(binned, ts, int(p["nbins"]), lr, Fcur[:, ki])
+                [fold_binned(binned, ts, self._n_bins, lr, Fcur[:, ki])
                  for ki, ts in enumerate(trees_multi)], axis=1)
         ntrees = int(p["ntrees"])
         keys = jax.random.split(key, ntrees * 3).reshape(ntrees, 3, 2)[done:]
         job.update(0.1, f"growing {(ntrees - done) * K} trees (one fused program)")
         kwargs = dict(
             dist="multinomial", depth=int(p["max_depth"]),
-            n_bins=int(p["nbins"]), col_rate=self._effective_col_rate(),
+            n_bins=self._n_bins, col_rate=self._effective_col_rate(),
             sample_rate=float(p["sample_rate"]),
             col_tree_rate=float(p["col_sample_rate_per_tree"]),
             min_rows=float(p["min_rows"]), reg_lambda=float(p["reg_lambda"]),
@@ -1560,7 +1574,7 @@ class DRF(SharedTreeBuilder):
                 binned, edges, yc, w, fmask,
                 jnp.zeros((binned.shape[0], nclass), jnp.float32), keys,
                 dist="multinomial", depth=int(p["max_depth"]),
-                n_bins=int(p["nbins"]), col_rate=mtries / F,
+                n_bins=self._n_bins, col_rate=mtries / F,
                 sample_rate=float(p["sample_rate"]), col_tree_rate=1.0,
                 min_rows=float(p["min_rows"]), reg_lambda=0.0, reg_alpha=0.0,
                 gamma=0.0,
@@ -1606,7 +1620,7 @@ class DRF(SharedTreeBuilder):
         _, heap, _, _ = _boost_scan(
             binned, edges, yc, w, fmask,
             jnp.zeros(binned.shape[0], jnp.float32), keys,
-            dist="gaussian", depth=int(p["max_depth"]), n_bins=int(p["nbins"]),
+            dist="gaussian", depth=int(p["max_depth"]), n_bins=self._n_bins,
             col_rate=mtries / F, sample_rate=float(p["sample_rate"]),
             col_tree_rate=1.0, min_rows=float(p["min_rows"]), reg_lambda=0.0,
             reg_alpha=0.0, gamma=0.0,

@@ -39,8 +39,9 @@ from jax import lax
 from jax import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
+from h2o3_tpu.ops.quantile import cat_bins_for_codes
 from h2o3_tpu.utils.costs import accounted_jit
-from h2o3_tpu.utils.telemetry import ROUTE_LEVELS
+from h2o3_tpu.utils.telemetry import ROUTE_LEVELS, SPLIT_LEVELS
 
 
 @dataclasses.dataclass
@@ -210,15 +211,19 @@ def _find_splits(hists, n_bins: int, min_rows, reg_lambda, reg_alpha, gamma,
     na = hist4[:, :, n_bins, :]                   # [F,N,3]
     cum = jnp.cumsum(reg, axis=2)                 # [F,N,B,3]
     rank = None
+    # counted where a level is TRACED (a cached program adds nothing)
+    SPLIT_LEVELS.labels(
+        kind="threshold" if cat_feats is None else "group").inc()
     if cat_feats is not None:
         # rank bins by mean gradient; empty bins sort to the end so prefix
         # candidates enumerate only occupied categories first
-        ratio = reg[..., 0] / jnp.maximum(reg[..., 1], 1e-12)
-        ratio = jnp.where(reg[..., 2] > 0, ratio, jnp.inf)
-        order = jnp.argsort(ratio, axis=2)                      # [F,N,B]
-        reg_sorted = jnp.take_along_axis(reg, order[..., None], axis=2)
-        cum_sorted = jnp.cumsum(reg_sorted, axis=2)
-        rank = jnp.argsort(order, axis=2)                       # bin → rank
+        with jax.named_scope("rank"):
+            ratio = reg[..., 0] / jnp.maximum(reg[..., 1], 1e-12)
+            ratio = jnp.where(reg[..., 2] > 0, ratio, jnp.inf)
+            order = jnp.argsort(ratio, axis=2)                  # [F,N,B]
+            reg_sorted = jnp.take_along_axis(reg, order[..., None], axis=2)
+            cum_sorted = jnp.cumsum(reg_sorted, axis=2)
+            rank = jnp.argsort(order, axis=2)                   # bin → rank
         cum = jnp.where(cat_feats[:, None, None, None], cum_sorted, cum)
     tot = cum[:, :, -1, :] + na                   # [F,N,3] (same for all f)
     G, H, W = tot[0, :, 0], tot[0, :, 1], tot[0, :, 2]
@@ -736,15 +741,17 @@ def _predict_raw_impl(X, feat_s, tv_s, na_s, sp_s, leaf_s):
 def _predict_raw_masked(X, cat_card, feat_s, tv_s, mask_s, na_s, sp_s, leaf_s,
                         n_bins: int):
     """Raw traversal with group splits: categorical features map raw codes
-    to their histogram bin (range-grouped when cardinality > bins) and test
-    membership; numeric features compare against the edge threshold."""
+    to their histogram bin (range-grouped when cardinality > ``n_bins``, the
+    model's ``cat_bins``) and test membership in the node's mask, whose
+    width is the engine's bin count; numeric features compare against the
+    edge threshold."""
     cat_bin = cat_bins_for_codes(X, cat_card, n_bins)   # [rows, F] int32
 
     def walk_one(feat, tv, mask, na_l, is_sp):
         def goes_left(idx, f, v):
             x, b = v
             return jnp.where(cat_card[f] > 0,
-                             mask[idx, jnp.clip(b, 0, n_bins - 1)],
+                             mask[idx, jnp.minimum(b, mask.shape[-1] - 1)],
                              x < tv[idx])
 
         return _walk(feat, na_l, is_sp, (X, cat_bin),
@@ -752,18 +759,6 @@ def _predict_raw_masked(X, cat_card, feat_s, tv_s, mask_s, na_s, sp_s, leaf_s,
 
     return _add_leaves(walk_one, (feat_s, tv_s, mask_s, na_s, sp_s, leaf_s),
                        jnp.zeros(X.shape[0], jnp.float32))
-
-
-def cat_bins_for_codes(X, cat_card, n_bins: int) -> jax.Array:
-    """Map raw categorical codes to histogram bins: identity when the
-    cardinality fits, contiguous range-grouping otherwise (reference
-    DHistogram nbins_cats grouping)."""
-    code = jnp.nan_to_num(X, nan=0.0).astype(jnp.int32)
-    card = jnp.maximum(cat_card, 1)[None, :]
-    grouped = (code * n_bins) // card
-    return jnp.where(cat_card[None, :] > n_bins,
-                     jnp.clip(grouped, 0, n_bins - 1),
-                     jnp.clip(code, 0, n_bins - 1)).astype(jnp.int32)
 
 
 def predict_raw(X, trees: list[Tree], cat_card=None, n_bins: int = 0) -> jax.Array:

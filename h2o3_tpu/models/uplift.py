@@ -146,7 +146,7 @@ class UpliftDRF(SharedTreeBuilder):
         pt = min(max(pt, 1e-6), 1 - 1e-6)
         z = yy * t / pt - yy * (1 - t) / (1 - pt)
 
-        tp = TreeParams(max_depth=int(p["max_depth"]), nbins=int(p["nbins"]),
+        tp = TreeParams(max_depth=int(p["max_depth"]), nbins=self._n_bins,
                         min_rows=float(p["min_rows"]), reg_lambda=0.0,
                         min_split_improvement=float(p["min_split_improvement"]))
         ntrees = int(p["ntrees"])

@@ -182,7 +182,7 @@ class XGBoost(GBM):
 
         lr = float(p["learn_rate"])
         ntrees = int(p["ntrees"])
-        nbins = int(p["nbins"])
+        nbins = self._n_bins
         seed = int(p["seed"]) if int(p["seed"]) >= 0 else 42
         rng = np.random.default_rng(seed)
         key = jax.random.PRNGKey(seed)

@@ -22,6 +22,8 @@ a default direction, matching XGBoost's learned-default-direction semantics.
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -98,6 +100,37 @@ def bin_column(col: jax.Array, e: jax.Array) -> jax.Array:
     a time) and validation frames (``bin_features``) both go through it."""
     BIN_COLUMNS.labels(path="compare").inc()
     return _bin_by_compare(col, e)
+
+
+def cat_bins_for_codes(X, cat_card, cat_bins: int) -> jax.Array:
+    """Map raw categorical codes ``X`` [rows, F] to histogram bins: a bin a
+    level while the cardinality ``cat_card`` [F] is at most ``cat_bins``
+    (the builder's ``nbins_cats``), contiguous range-grouping into
+    ``cat_bins`` bins past it (reference DHistogram nbins_cats grouping).
+    A NaN reads bin 0: the caller puts missing rows where it wants them."""
+    code = jnp.nan_to_num(X, nan=0.0).astype(jnp.int32)
+    card = jnp.maximum(cat_card, 1)[None, :]
+    grouped = (code * cat_bins) // card
+    return jnp.where(cat_card[None, :] > cat_bins,
+                     jnp.clip(grouped, 0, cat_bins - 1),
+                     jnp.clip(code, 0, cat_bins - 1)).astype(jnp.int32)
+
+
+@partial(jax.jit, static_argnames=("cat_bins", "n_bins"))
+def _bin_by_level(col, card, cat_bins: int, n_bins: int):
+    b = cat_bins_for_codes(col[:, None], card[None], cat_bins)[:, 0]
+    return jnp.where(jnp.isnan(col), n_bins, b).astype(bin_dtype(n_bins))
+
+
+def bin_levels(col: jax.Array, card: int, cat_bins: int,
+               n_bins: int) -> jax.Array:
+    """Bin one CATEGORICAL column [rows] of level codes (as floats) by level
+    code (:func:`cat_bins_for_codes`) → bins in ``bin_dtype(n_bins)``;
+    NaN → ``n_bins``, the engine's missing bin, which a numeric column's
+    edges give it by their width (``n_bins - 1``). One program for every
+    cardinality (``card`` is an operand)."""
+    BIN_COLUMNS.labels(path="levels").inc()
+    return _bin_by_level(col, jnp.int32(card), cat_bins, n_bins)
 
 
 def bin_features(X: jax.Array, edges: jax.Array) -> jax.Array:

@@ -533,11 +533,20 @@ MODEL_BUILD_SECONDS = METRICS.histogram(
     "h2o3_model_build_seconds", "model build wall time", ("algo",),
     buckets=BUILD_BUCKETS)
 
-# tree builders' binning (ops/quantile.py): one increment a column binned
-# against quantile edges, by the mechanism that binned it. One path today
-# (compare-and-count); the label is there so that a second one shows.
+# tree builders' binning (ops/quantile.py): one increment a column binned,
+# by the mechanism that binned it: ``compare`` (a numeric column against its
+# quantile edges, compare-and-count) or ``levels`` (a categorical column by
+# its level code, ``bin_levels``).
 BIN_COLUMNS = METRICS.counter(
-    "h2o3_bin_columns", "columns binned against quantile edges", ("path",))
+    "h2o3_bin_columns", "columns binned for the tree engine", ("path",))
+
+# the tree engine's split search (models/tree.py ``_find_splits``): one
+# increment a level of a tree program, counted at TRACE time: ``group``
+# where the level ranks categorical bins by G/H and scans sorted prefixes
+# (``cat_feats`` given), ``threshold`` where every split is ordinal.
+SPLIT_LEVELS = METRICS.counter(
+    "h2o3_split_levels", "tree levels traced, by kind of split search",
+    ("kind",))
 
 # the tree engine's row routing (models/tree.py ``_route_rows``): one
 # increment a level of a tree program, counted at TRACE time where the

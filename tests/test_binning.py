@@ -127,11 +127,14 @@ def _compare_count() -> float:
 def test_counter_counts_the_numeric_columns_a_build_bins():
     fr = _mixed_frame()
     before = _compare_count()
+    levels = BIN_COLUMNS.labels(path="levels")
+    levels_before = levels.value
     GBM(ntrees=2, max_depth=2, nbins=16, seed=1).train(
         x=["x0", "c", "x1"], y="y", training_frame=fr)
     assert _compare_count() - before == 2   # x0 and x1; c is categorical
+    assert levels.value - levels_before == 1    # ... and bins by level code
     paths = {labels["path"] for labels, _ in BIN_COLUMNS.children()}
-    assert paths == {"compare"}             # one path for every edge count
+    assert paths == {"compare", "levels"}   # one path a kind of column
 
 
 def test_counter_counts_a_validation_frames_columns():

@@ -14,6 +14,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(REPO, "chip_smoke.py")
@@ -104,6 +105,43 @@ def test_hist_kernel_compiles_for_v5e(monkeypatch):
             spec((rows,), jnp.float32),
             n_nodes=n_nodes, n_bins_tot=n_bins_tot).compile()
         assert "tpu_custom_call" in exe.as_text(), (rows, feats, n_bins_tot)
+
+
+@pytest.mark.parametrize("n_nodes, contraction, blocks", [
+    (16, "packed", 1), (32, "passes", 1), (64, "passes", 1),
+    (256, "passes", 4)])
+def test_hist_kernel_compiles_at_the_categorical_cells_shapes(
+        monkeypatch, n_nodes, contraction, blocks):
+    """``gbm100-airline-cat-build``: 10M rows x 8 features x 301 bins
+    (int16), up to 256 parent slots: digits packed up to 21 slots, a pass a
+    digit past that, four node blocks at the deepest level. Compiled for
+    the v5e with no chip attached."""
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from h2o3_tpu.ops import pallas_hist
+    from h2o3_tpu.utils.telemetry import HIST_KERNEL_LEVELS
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    on_chip = SingleDeviceSharding(topo.devices[0])
+    rows, feats, n_bins_tot = 10_000_000, 8, 301
+    Nb, Fb, _T = pallas_hist._plan(n_nodes, feats, n_bins_tot)
+    assert (Fb, -(-n_nodes // Nb)) == (feats, blocks)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    counted = HIST_KERNEL_LEVELS.labels(contraction=contraction)
+    before = counted.value
+    exe = pallas_hist.hist_pallas.lower(
+        spec((feats, rows), jnp.int16), spec((rows,), jnp.int32),
+        spec((rows,), jnp.float32), spec((rows,), jnp.float32),
+        spec((rows,), jnp.float32),
+        n_nodes=n_nodes, n_bins_tot=n_bins_tot).compile()
+    assert "tpu_custom_call" in exe.as_text()
+    assert counted.value == before + 1
 
 
 def test_kernel_refuses_an_operand_on_several_devices(monkeypatch):
