@@ -196,13 +196,15 @@ def _check_spread(fr) -> dict:
 
 
 def _hist_paths(want_kernel: bool) -> dict:
-    """The trace-time path counts of the build just traced. On one device
-    every level with a ``_plan`` takes the kernel and none the scatter."""
+    """The trace-time path counts of the build just traced: a tree's levels
+    and its last level's per-node totals. On one device every one with a
+    ``_plan`` takes the kernel and none the scatter; over a mesh the levels
+    fuse and the totals stay three scatter-adds."""
     from h2o3_tpu.models.tree import HIST_PATHS
     paths = {k: HIST_PATHS[k] for k in ("pallas", "fused_scatter", "scatter")}
-    assert sum(paths.values()) == DEPTH, paths
+    assert sum(paths.values()) == DEPTH + 1, paths
     if want_kernel:
-        assert paths == {"pallas": DEPTH, "fused_scatter": 0,
+        assert paths == {"pallas": DEPTH + 1, "fused_scatter": 0,
                          "scatter": 0}, paths
     return paths
 
@@ -251,8 +253,9 @@ def stage_train(rows: int, dry: bool) -> tuple[object, dict]:
         out["gbm_auc_one_device"] = checked(one, "gbm on one device")
         assert abs(out["gbm_auc"] - out["gbm_auc_one_device"]) <= 1e-3, out
     elif not dry:
-        # the kernel is in the executable that ran, once per level
-        assert hlo.count("tpu_custom_call") >= DEPTH, \
+        # the kernel is in the executable that ran, once per level and once
+        # for the last level's totals
+        assert hlo.count("tpu_custom_call") >= DEPTH + 1, \
             hlo.count("tpu_custom_call")
 
     # the XGBoost configuration: 256 bins — the int16 / 257-bin envelope

@@ -96,7 +96,8 @@ def _level_histograms(binned, node_local, g, h, w, n_nodes: int, n_bins_tot: int
 #: cannot be fused (no named ``rows`` axis, or rows not divisible by it).
 UNFUSED = "unfused"
 
-#: which histogram path each level took, counted where the branch is taken
+#: which histogram path each level, and the last level's per-node totals
+#: (:func:`_node_totals`), took, counted where the branch is taken
 #: — at TRACE time, so a cached program adds nothing. The caller resets it
 #: (``HIST_PATHS.clear()``) before the build it wants to read.
 HIST_PATHS: collections.Counter = collections.Counter()
@@ -170,12 +171,49 @@ def _histograms(binned, binned_T, node_local, g, h, w, n_nodes: int,
     return _level_histograms(binned, node_local, g, h, w, n_nodes, n_bins_tot)
 
 
-def _node_totals(node_local, g, h, w, n_nodes: int):
-    """Per-node (G, H, W) sums — the feature-independent stats the final
-    level needs (cheaper than a full histogram build). Summed per 1-D stat
-    column: a [rows, 3] stack would pad its minor dim to 128 lanes in HBM
-    (42x memory at 11M rows)."""
+#: the most segments :func:`_node_totals` sums through the histogram kernel;
+#: past it the three scatter-adds are the cheaper ones. The kernel's call
+#: streams rows x N one-hot rows through the MXU and ``_plan``'s row tile
+#: shrinks past 2,048 segments (4,096 rows, then 2,048 / 896 / 128 at 4,096
+#: / 8,192 / 16,384); the scatter-adds cost the same whatever N. On the v5e
+#: (PERF.md section 6, PR 31; ms, the whole function timed alone, kernel /
+#: scatter-adds, node ids uniform or Zipf, whichever is nearer): 22M rows
+#: 7.2 / 580 at 64, 35 / 580 at 1,024, 64 / 452 at 2,048, 124 / 445 at
+#: 4,096, 247 / 445 at 8,192, 669 / 444 at 16,384; 11M rows 3.8 / 290,
+#: 17.5 / 290, 32 / 226, 62 / 222, 123 / 222, 338 / 222. 8,192 is the
+#: largest size measured at which the kernel wins at both.
+_TOTALS_KERNEL_MAX_NODES = 8192
+
+
+def _node_totals(node_local, g, h, w, n_nodes: int, mesh=None):
+    """Per-node (G, H, W) sums [n_nodes, 3] over the rows the last ``route``
+    left in each node: what the final level's leaves and covers are made of.
+    Rows of node -1 (frozen) count nowhere.
+
+    On one TPU device, up to :data:`_TOTALS_KERNEL_MAX_NODES` nodes, they
+    are ONE call of the histogram kernel with one feature, whose bin is the
+    row's node id, and one node slot: ``sum_rows [node == n] * (g, h, w)`` is
+    that feature's histogram. It is the cheaper one there (the constant's
+    readings) and the nearer one: a 4,096-row tile is summed on the MXU in
+    the kernel's two bf16 digits, where the chip's scatter-add accumulates
+    a segment's rows one by one in float32 (three digits short on a
+    300,000-row leaf). Only ``[1, rows]`` operands exist on that path: a
+    ``[rows, 1]`` array pads its minor dimension to 128 lanes in HBM.
+
+    Elsewhere (off the TPU, an operand that spans a mesh, more nodes than
+    the kernel wins at) three ``segment_sum``s under implicit SPMD, summed
+    per 1-D stat column: a [rows, 3] stack would pad its minor dim to 128
+    lanes (42x memory at 11M rows). Counted in :data:`HIST_PATHS` like a
+    level (``pallas`` / ``scatter``)."""
+    from h2o3_tpu.ops.pallas_hist import hist_pallas, pallas_available
     active = node_local >= 0
+    if (n_nodes <= _TOTALS_KERNEL_MAX_NODES
+            and pallas_available(1, 1, n_nodes, one_device=mesh is None)):
+        HIST_PATHS["pallas"] += 1
+        # slot -1 drops a frozen row, as sibling subtraction's masked rows
+        return hist_pallas(node_local[None, :], jnp.where(active, 0, -1),
+                           g, h, w, 1, n_nodes)[0]
+    HIST_PATHS["scatter"] += 1
     ids = jnp.where(active, node_local, 0)
     outs = [jax.ops.segment_sum(jnp.where(active, v, 0.0), ids,
                                 num_segments=n_nodes) for v in (g, h, w)]
@@ -527,10 +565,11 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
                     leaf, member if cat_feats is not None else None, B)
 
     # final level: all surviving nodes become leaves; only per-node totals
-    # are needed (no split search), so skip the full histogram build
+    # are needed (no split search): a one-feature call of the histogram
+    # kernel over the node ids in place of a level's F-feature one
     N = 2 ** depth
     with jax.named_scope("leaves"):
-        tot = _node_totals(node_local, g, h, w, N)
+        tot = _node_totals(node_local, g, h, w, N, mesh=mesh)
         leaf = clamp(_leaf_value(tot[:, 0], tot[:, 1], tot[:, 2], reg_lambda,
                                  reg_alpha), bounds)
         lv_feat.append(jnp.full(N, -1, jnp.int32))

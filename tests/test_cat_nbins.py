@@ -10,6 +10,7 @@ same comparisons decide ``correct`` on the chip at the cell's size
 
 import importlib.util
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -346,6 +347,37 @@ def test_node_totals_at_the_cells_last_level():
     for k, v in enumerate((g, h, w)):
         want = np.bincount(node[live], v[live].astype(np.float64), n)
         np.testing.assert_allclose(got[:, k], want, rtol=1e-4, atol=1e-4)
+
+
+def _a_mesh():
+    from jax.sharding import Mesh
+    return Mesh(np.array(jax.devices()), ("rows",))
+
+
+@pytest.mark.parametrize("interpret, mesh", [
+    (False, lambda: None),           # off the TPU: pallas_available says no
+    (True, lambda: tree.UNFUSED),    # as if on a TPU, the operand spread
+    (True, _a_mesh)],                # ... over a mesh with a rows axis
+    ids=["off-the-tpu", "unfused", "mesh"])
+def test_node_totals_off_the_kernel_are_three_segment_sums(monkeypatch,
+                                                           interpret, mesh):
+    """The fallback is the parent's code: bit for bit three
+    ``jax.ops.segment_sum``s, and counted as ``scatter``."""
+    monkeypatch.setattr(pallas_hist, "_INTERPRET", interpret)
+    rows, n = 5000, 64
+    rng = np.random.default_rng(6)
+    node = jnp.asarray(rng.integers(-1, n, size=rows).astype(np.int32))
+    g, h, w = (jnp.asarray(v) for v in
+               rng.normal(size=(3, rows)).astype(np.float32))
+    tree.HIST_PATHS.clear()
+    got = tree._node_totals(node, g, h, w, n, mesh=mesh())
+    assert tree.HIST_PATHS == {"scatter": 1}
+    live = node >= 0
+    want = jnp.stack([jax.ops.segment_sum(
+        jnp.where(live, v, 0.0), jnp.where(live, node, 0), num_segments=n)
+        for v in (g, h, w)], axis=1)
+    np.testing.assert_array_equal(np.asarray(got).view(np.int32),
+                                  np.asarray(want).view(np.int32))
 
 
 # --- the timed trees against a float64 replay on the training rows -------

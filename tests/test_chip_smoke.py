@@ -66,7 +66,7 @@ def test_dry_cpu_runs_every_stage(tmp_path):
     assert out["device"] == device
     assert set(out["cold_wall_s"]) == {"kernel", "train", "serve", "glm",
                                        "deeplearning"}
-    kernel_only = {"pallas": 6, "fused_scatter": 0, "scatter": 0}
+    kernel_only = {"pallas": 7, "fused_scatter": 0, "scatter": 0}
     assert out["train"]["gbm_hist_paths"] == kernel_only
     assert out["train"]["xgboost_hist_paths"] == kernel_only
     assert out["compile_cache"]["dir"] == str(tmp_path / "jax_cache")
@@ -76,8 +76,9 @@ def test_hist_kernel_compiles_for_v5e(monkeypatch):
     """AOT: libtpu compiles for a described topology with no chip attached.
     Both ends of the bin-storage envelope must lower to a Mosaic call, at a
     full node block, at the benchmark's own shapes (its cells' rows, storage
-    and deepest call) and at a frame wider than one feature block: a tile
-    that overflows VMEM there fails here, on the CPU."""
+    and deepest call, and the one-feature call of their last level's totals)
+    and at a frame wider than one feature block: a tile that overflows VMEM
+    there fails here, on the CPU."""
     monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
     monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
     from jax.experimental import topologies
@@ -98,7 +99,10 @@ def test_hist_kernel_compiles_for_v5e(monkeypatch):
             (1_000_000, 28, 257, jnp.int16, 64),
             (22_000_000, 28, 65, jnp.int8, 16),
             (11_000_000, 28, 257, jnp.int16, 16),
-            (1_000_000, 500, 257, jnp.int16, 64)):
+            (1_000_000, 500, 257, jnp.int16, 64),
+            # the last level's totals: one feature whose bin is the node id
+            (22_000_000, 1, 64, jnp.int32, 1),
+            (20_000_000, 1, 1024, jnp.int32, 1)):
         exe = pallas_hist.hist_pallas.lower(
             spec((feats, rows), dtype), spec((rows,), jnp.int32),
             spec((rows,), jnp.float32), spec((rows,), jnp.float32),

@@ -7,7 +7,9 @@ Covers the MXU precision modes: "hilo" (2 bf16 digits, default), "hilo3"
 XLA segment-sum ground truth — and every case the body distinguishes: the
 digits side by side in one product or a pass each, int8 and int16 bins,
 feature blocks narrower than the frame, rows short of a tile, one node,
-several node blocks, a call under ``vmap``; and ``_plan``'s VMEM account.
+several node blocks, a call under ``vmap``; ``_plan``'s VMEM account; and the
+last level's per-node totals as the kernel's one-feature call
+(``tree._node_totals``).
 """
 
 import numpy as np
@@ -51,6 +53,14 @@ def _check(rng, R, F, B, N, rtol, atol, dtype=np.int16):
 def _set_mode(monkeypatch, mode):
     monkeypatch.setattr(pallas_hist, "_MXU_MODE", mode)
     pallas_hist.hist_pallas._clear_cache()
+
+
+def _drop_grow_traces():
+    """The counters move where a program is TRACED: forget the tree
+    program's traces, so that the next grow traces again."""
+    from h2o3_tpu.models import tree
+    tree._grow_batched.clear_executables()
+    tree._grow_batched._jit.clear_cache()
 
 
 @pytest.mark.parametrize("mode,rtol", [("hilo", 5e-4), ("hilo3", 1e-5),
@@ -177,6 +187,115 @@ def test_hilo_split_exactness():
         assert abs((hi + lo) - v) <= abs(v) * 2 ** -15
 
 
+# -- the last level's per-node totals: a one-feature call ----------------------
+
+def _totals_f64(node, g, h, w, n):
+    live = node >= 0
+    return np.stack([np.bincount(node[live], v[live].astype(np.float64), n)
+                     for v in (g, h, w)], axis=1)
+
+
+@pytest.mark.parametrize("n_nodes,rows", [
+    (64, 9000),        # GBM-64's and XGBoost-256's last level; 2.2 tiles
+    (1024, 5000),      # the categorical cell's: most segments hold 0-8 rows
+    (1024, 1),         # a frame shorter than a lane
+    (4, 4097)])        # fewer segments than a sublane tile; a row past a tile
+def test_node_totals_through_the_kernel(monkeypatch, n_nodes, rows, rng):
+    """Frozen rows (-1) count nowhere, empty segments read 0, rows need be
+    no multiple of the tile; counts are exact and sums hold the kernel's
+    tolerance against float64."""
+    from h2o3_tpu.models import tree
+    _set_mode(monkeypatch, "hilo")
+    assert pallas_hist._plan(1, 1, n_nodes)[1:] == (1, 4096)
+    node, g, h, w = (np.array(v) for v in _data(rng, rows, 1, 1, n_nodes)[1:])
+    node[node == n_nodes // 2] = -1            # an empty segment for certain
+    tree.HIST_PATHS.clear()
+    before = _counters()
+    got = np.asarray(tree._node_totals(
+        *(jnp.asarray(v) for v in (node, g, h, w)), n_nodes))
+    assert tree.HIST_PATHS == {"pallas": 1}
+    assert tuple(a - b for a, b in zip(_counters(), before)) == (
+        1, 0, -(-rows // 4096))
+    want = _totals_f64(node, g, h, w, n_nodes)
+    assert got.shape == (n_nodes, 3) and (want[n_nodes // 2] == 0).all()
+    np.testing.assert_array_equal(got[:, 2], want[:, 2])
+    np.testing.assert_allclose(got, want, rtol=5e-4, atol=5e-3)
+
+
+def test_node_totals_of_long_two_valued_segments_are_sound(monkeypatch):
+    """What the chip showed at 20M rows (PERF.md, PR 30), in the small: a
+    first tree's g and h take two values, and a scatter-add that adds a
+    segment's rows one by one in float32 rounds every addend the same way
+    (a leaf of 300,000 rows read 3e-3 off). The kernel sums a 4,096-row
+    tile on the MXU and adds tiles: a leaf within 1e-4 of float64."""
+    from h2o3_tpu.models import tree
+    _set_mode(monkeypatch, "hilo")
+    rows, n = 1_000_000, 64
+    rng = np.random.default_rng(11)
+    zipf = 1.0 / np.arange(1, n + 1)
+    node = rng.choice(n, size=rows, p=zipf / zipf.sum()).astype(np.int32)
+    y = rng.random(rows) < 0.47
+    p = np.float32(0.53)
+    g = np.where(y, p - 1, p).astype(np.float32)
+    h = np.full(rows, p * (1 - p), np.float32)
+    w = np.ones(rows, np.float32)
+    got = np.asarray(tree._node_totals(
+        *(jnp.asarray(v) for v in (node, g, h, w)), n), np.float64)
+    want = _totals_f64(node, g, h, w, n)
+    assert want[:, 2].max() > 200_000
+    np.testing.assert_array_equal(got[:, 2], want[:, 2])
+    np.testing.assert_allclose(got[:, 1], want[:, 1], rtol=5e-5)
+    np.testing.assert_allclose(-got[:, 0] / got[:, 1],
+                               -want[:, 0] / want[:, 1], rtol=0, atol=1e-4)
+
+
+def test_node_totals_past_the_measured_crossover_stay_scatter_adds():
+    from h2o3_tpu.models import tree
+    n = 2 * tree._TOTALS_KERNEL_MAX_NODES
+    assert pallas_hist.pallas_available(1, 1, tree._TOTALS_KERNEL_MAX_NODES)
+    assert pallas_hist._plan(1, 1, n) is not None       # a plan, not a win
+    node = jnp.asarray(np.arange(-1, 499, dtype=np.int32))
+    ones = jnp.ones(500, jnp.float32)
+    tree.HIST_PATHS.clear()
+    got = tree._node_totals(node, ones, ones, ones, n)
+    assert tree.HIST_PATHS == {"scatter": 1}
+    assert float(got.sum()) == 3 * 499.0
+
+
+@pytest.mark.parametrize("K", [1, 3])
+def test_a_depth_6_tree_calls_the_kernel_seven_times(monkeypatch, K):
+    """Six levels and the last level's totals, under the multinomial
+    round's ``vmap`` too; the totals are sums over the rows the last route
+    left in a node, so with unit weights a split node of level 5 has exactly
+    its two children's rows."""
+    from h2o3_tpu.models import tree
+    from h2o3_tpu.models.tree import TreeParams, grow_trees_batched
+    _set_mode(monkeypatch, "hilo")
+    rng = np.random.default_rng(K)
+    rows, F, nbins, depth = 6000, 4, 16, 6
+    binned = jnp.asarray(rng.integers(0, nbins, size=(rows, F)).astype(np.int8))
+    edges = jnp.asarray(np.tile(np.arange(1, nbins, dtype=np.float32), (F, 1)))
+    g = jnp.asarray(rng.normal(size=(K, rows)).astype(np.float32))
+    ones = jnp.ones((K, rows), jnp.float32)
+    _drop_grow_traces()
+    tree.HIST_PATHS.clear()
+    trees, preds = grow_trees_batched(
+        binned, edges, g, ones, ones,
+        TreeParams(max_depth=depth, nbins=nbins, min_rows=1.0),
+        jnp.ones(F, bool))
+    assert tree.HIST_PATHS == {"pallas": depth + 1}
+    assert len(trees) == K and preds.shape == (K, rows)
+    for t in trees:
+        cover, is_split = np.asarray(t.cover), np.asarray(t.is_split)
+        parents = np.arange(2 ** (depth - 1) - 1, 2 ** depth - 1)
+        assert is_split[parents].sum() > 8
+        kids = cover[2 * parents + 1] + cover[2 * parents + 2]
+        np.testing.assert_array_equal(kids[is_split[parents]],
+                                      cover[parents][is_split[parents]])
+        assert (kids[~is_split[parents]] == 0).all()
+    _drop_grow_traces()
+
+
 # -- the counters --------------------------------------------------------------
 
 def _counters():
@@ -188,8 +307,10 @@ def _counters():
 
 def test_counters_rise_where_a_build_is_traced(monkeypatch):
     """One increment a TRACED call and its grid's size: a depth-3 tree calls
-    the kernel three times (1 node, then 1 and 2 slots under sibling
-    subtraction) and traces two signatures, since jit traces a shape once."""
+    the kernel three times for its levels (1 node, then 1 and 2 slots under
+    sibling subtraction) and once for the last level's totals (one feature
+    of 8 bins), and traces three signatures, since jit traces a shape
+    once."""
     from h2o3_tpu.models import tree
     from h2o3_tpu.models.tree import TreeParams, grow_trees_batched
     _set_mode(monkeypatch, "hilo")
@@ -200,26 +321,24 @@ def test_counters_rise_where_a_build_is_traced(monkeypatch):
     g = jnp.asarray(rng.normal(size=(1, rows)).astype(np.float32))
     ones = jnp.ones((1, rows), jnp.float32)
 
-    def drop():
-        tree._grow_batched.clear_executables()
-        tree._grow_batched._jit.clear_cache()
-    drop()
+    _drop_grow_traces()
     tree.HIST_PATHS.clear()
     before = _counters()
     grow = lambda: grow_trees_batched(binned, edges, g, ones, ones,
                                       TreeParams(max_depth=3, nbins=nbins),
                                       jnp.ones(F, bool))
     grow()
-    assert tree.HIST_PATHS["pallas"] == 3
+    assert tree.HIST_PATHS == {"pallas": 4}
     Nb, Fb, T = pallas_hist._plan(1, F, nbins + 1)
     assert (Fb, T) == (F, 4096) == pallas_hist._plan(2, F, nbins + 1)[1:]
+    assert pallas_hist._plan(1, 1, 8) == (1, 1, T)      # the totals' call
     steps = -(-rows // T)                      # one node and feature block
     packed, passes, total = (a - b for a, b in zip(_counters(), before))
-    assert (packed, passes, total) == (2, 0, 2 * steps)
+    assert (packed, passes, total) == (3, 0, 3 * steps)
     grow()                                     # a cached program adds nothing
     assert _counters() == tuple(b + d for b, d in
-                                zip(before, (2, 0, 2 * steps)))
-    drop()
+                                zip(before, (3, 0, 3 * steps)))
+    _drop_grow_traces()
 
 
 def test_counter_names_the_contraction(monkeypatch, rng):
