@@ -186,11 +186,14 @@ h2o.deeplearning <- function(x = NULL, y = NULL, training_frame,
     initial_weight_scale = 1.0,
     autoencoder = 0,
     score_each_iteration = 0,
+    train_samples_per_iteration = -2,
+    classification_stop = 0.0,
+    ignore_const_cols = 1,
     elastic = 0,
     local_steps = 1) {
   .gen_train("deeplearning", x, y, training_frame, validation_frame, model_id,
-             params = list(seed = seed, nfolds = nfolds, fold_assignment = fold_assignment, fold_column = fold_column, weights_column = weights_column, ignored_columns = ignored_columns, max_runtime_secs = max_runtime_secs, keep_cross_validation_predictions = keep_cross_validation_predictions, checkpoint = checkpoint, auto_recovery_dir = auto_recovery_dir, custom_metric_func = custom_metric_func, hidden = hidden, epochs = epochs, activation = activation, adaptive_rate = adaptive_rate, rho = rho, epsilon = epsilon, rate = rate, rate_annealing = rate_annealing, rate_decay = rate_decay, momentum_start = momentum_start, momentum_ramp = momentum_ramp, momentum_stable = momentum_stable, nesterov_accelerated_gradient = nesterov_accelerated_gradient, input_dropout_ratio = input_dropout_ratio, hidden_dropout_ratios = hidden_dropout_ratios, l1 = l1, l2 = l2, max_w2 = max_w2, loss = loss, huber_alpha = huber_alpha, mini_batch_size = mini_batch_size, standardize = standardize, use_all_factor_levels = use_all_factor_levels, initial_weight_distribution = initial_weight_distribution, initial_weight_scale = initial_weight_scale, autoencoder = autoencoder, score_each_iteration = score_each_iteration, elastic = elastic, local_steps = local_steps),
-             defaults = list(seed = -1, nfolds = 0, fold_assignment = "Modulo", max_runtime_secs = 0.0, keep_cross_validation_predictions = 0, hidden = c(200, 200), epochs = 10.0, activation = "Rectifier", adaptive_rate = 1, rho = 0.99, epsilon = 1e-08, rate = 0.005, rate_annealing = 1e-06, rate_decay = 1.0, momentum_start = 0.0, momentum_ramp = 1000000.0, momentum_stable = 0.0, nesterov_accelerated_gradient = 1, input_dropout_ratio = 0.0, l1 = 0.0, l2 = 0.0, max_w2 = 3.4028235e+38, loss = "Automatic", huber_alpha = 0.9, mini_batch_size = 32, standardize = 1, use_all_factor_levels = 1, initial_weight_distribution = "UniformAdaptive", initial_weight_scale = 1.0, autoencoder = 0, score_each_iteration = 0, elastic = 0, local_steps = 1))
+             params = list(seed = seed, nfolds = nfolds, fold_assignment = fold_assignment, fold_column = fold_column, weights_column = weights_column, ignored_columns = ignored_columns, max_runtime_secs = max_runtime_secs, keep_cross_validation_predictions = keep_cross_validation_predictions, checkpoint = checkpoint, auto_recovery_dir = auto_recovery_dir, custom_metric_func = custom_metric_func, hidden = hidden, epochs = epochs, activation = activation, adaptive_rate = adaptive_rate, rho = rho, epsilon = epsilon, rate = rate, rate_annealing = rate_annealing, rate_decay = rate_decay, momentum_start = momentum_start, momentum_ramp = momentum_ramp, momentum_stable = momentum_stable, nesterov_accelerated_gradient = nesterov_accelerated_gradient, input_dropout_ratio = input_dropout_ratio, hidden_dropout_ratios = hidden_dropout_ratios, l1 = l1, l2 = l2, max_w2 = max_w2, loss = loss, huber_alpha = huber_alpha, mini_batch_size = mini_batch_size, standardize = standardize, use_all_factor_levels = use_all_factor_levels, initial_weight_distribution = initial_weight_distribution, initial_weight_scale = initial_weight_scale, autoencoder = autoencoder, score_each_iteration = score_each_iteration, train_samples_per_iteration = train_samples_per_iteration, classification_stop = classification_stop, ignore_const_cols = ignore_const_cols, elastic = elastic, local_steps = local_steps),
+             defaults = list(seed = -1, nfolds = 0, fold_assignment = "Modulo", max_runtime_secs = 0.0, keep_cross_validation_predictions = 0, hidden = c(200, 200), epochs = 10.0, activation = "Rectifier", adaptive_rate = 1, rho = 0.99, epsilon = 1e-08, rate = 0.005, rate_annealing = 1e-06, rate_decay = 1.0, momentum_start = 0.0, momentum_ramp = 1000000.0, momentum_stable = 0.0, nesterov_accelerated_gradient = 1, input_dropout_ratio = 0.0, l1 = 0.0, l2 = 0.0, max_w2 = 3.4028235e+38, loss = "Automatic", huber_alpha = 0.9, mini_batch_size = 32, standardize = 1, use_all_factor_levels = 1, initial_weight_distribution = "UniformAdaptive", initial_weight_scale = 1.0, autoencoder = 0, score_each_iteration = 0, train_samples_per_iteration = -2, classification_stop = 0.0, ignore_const_cols = 1, elastic = 0, local_steps = 1))
 }
 
 

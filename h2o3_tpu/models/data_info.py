@@ -39,6 +39,9 @@ class DataInfo:
     use_all_factor_levels: bool
     standardize: bool
     ncats_expanded: int
+    # predictors ``make(ignore_const_cols=True)`` left out: constant over the
+    # training rows, so absent from cat_cols / num_cols and from the design
+    ignored_const_cols: tuple[str, ...] = ()
 
     @property
     def ncols_expanded(self) -> int:
@@ -56,7 +59,17 @@ class DataInfo:
 
     @staticmethod
     def make(frame: Frame, x: list[str], standardize: bool = True,
-             use_all_factor_levels: bool = False) -> "DataInfo":
+             use_all_factor_levels: bool = False,
+             ignore_const_cols: bool = False) -> "DataInfo":
+        """``ignore_const_cols`` (reference: ``Model.Parameters
+        ._ignore_const_cols``) leaves out every predictor whose roll-up says
+        it is constant over the training rows: min equal to max, or nothing
+        but missing values. A scoring frame may still hold the column;
+        ``expand`` reads the kept columns by name."""
+        dropped: tuple[str, ...] = ()
+        if ignore_const_cols:
+            dropped = tuple(c for c in x if _is_constant(frame.vec(c)))
+            x = [c for c in x if c not in dropped]
         cat_cols = [c for c in x if frame.vec(c).is_categorical]
         num_cols = [c for c in x if not frame.vec(c).is_categorical]
         for c in num_cols:
@@ -74,7 +87,8 @@ class DataInfo:
             if standardize else np.ones_like(means)
         sub = means if standardize else np.zeros_like(means)
         return DataInfo(cat_cols, num_cols, cat_domains, np.array(offs, np.int32),
-                        means, mul, sub, use_all_factor_levels, standardize, k)
+                        means, mul, sub, use_all_factor_levels, standardize, k,
+                        dropped)
 
     # -- expansion (train or adapted test) ----------------------------------
 
@@ -104,6 +118,13 @@ class DataInfo:
         if v.is_categorical:
             return v.data.astype(jnp.float32), v.cardinality()
         return v.data, 0
+
+
+def _is_constant(vec) -> bool:
+    """One value, or none, over the rows the roll-up saw (reference:
+    ``Vec.isConst() || Vec.isBad()``)."""
+    r = vec.rollups()
+    return r.na_cnt >= r.nrows or r.min == r.max
 
 
 def response_as_float(vec) -> tuple[jax.Array, jax.Array]:

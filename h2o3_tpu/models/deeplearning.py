@@ -24,11 +24,40 @@ Nesterov, ``input_dropout_ratio``/``hidden_dropout_ratios`` (inverted dropout),
 Quadratic/Absolute/Huber, ``initial_weight_distribution`` UniformAdaptive/
 Uniform/Normal, ``autoencoder`` with reconstruction-error anomaly scoring
 (reference ``DlInput``/``Neurons`` semantics).
+
+Three reference parameters whose meaning is about the reference's
+MapReduce iteration, and what they mean in this loop:
+
+- ``train_samples_per_iteration`` (-2): the reference trains that many rows
+  between two model averagings (-2 auto-tuned, -1 every node's rows, 0 one
+  epoch). Here every update already averages the gradient over the whole
+  cluster exactly, so no value can change the weights: -2, -1 and 0 all mean
+  what the loop does, a dispatch of whole epochs. A positive value is
+  accepted with the same effect on the weights (none) and is logged once as
+  setting nothing: the loop reports and checkpoints at epoch boundaries.
+  Values under -2 are refused, as in the reference.
+- ``classification_stop`` (0): the reference stops once the training error it
+  scores between iterations is at or under the value; -1 never stops. This
+  loop does not score between epochs (one fetch a build), so a value >= 0 is
+  NOT honoured: it trains the ``epochs`` asked for, as it always has, and
+  says so in the log once. -1 is exactly what the loop does.
+- ``ignore_const_cols`` (true): predictors constant over the training rows
+  are left out of the network's inputs (``DataInfo.make``); the model's
+  ``data_info.ignored_const_cols`` names them and scoring frames may keep them.
+
+``epochs`` may be fractional, as in the reference: whole epochs are
+``rows // mini_batch_size`` updates each, and the last, partial one is
+``ceil(fraction x rows / mini_batch_size)`` updates on the first rows of a
+fresh permutation (``rows`` the frame's padded length, as every epoch's).
+The elastic mode rounds ``epochs`` up to whole epochs.
 """
 
 from __future__ import annotations
 
+import logging
+import math
 from functools import partial
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -61,32 +90,80 @@ def _act_kind(activation: str) -> tuple[str, bool]:
     return base, drop
 
 
-def _forward(params, X, act: str, train: bool, key, in_drop: float,
-             hid_drops: tuple[float, ...]):
-    """MLP forward pass. Maxout layers hold W of width 2*units and take the
-    pairwise max (reference: 2-channel Maxout, ``Neurons.java``). Dropout is
-    inverted (scale at train time) so scoring needs no rescale."""
-    h = X
-    if train and in_drop > 0:
-        key, sub = jax.random.split(key)
-        keep = jax.random.bernoulli(sub, 1.0 - in_drop, h.shape)
-        h = jnp.where(keep, h / (1.0 - in_drop), 0.0)
-    n_hidden = len(params["W"]) - 1
-    for i in range(n_hidden):
-        z = h @ params["W"][i] + params["b"][i]
-        if act == "tanh":
-            h = jnp.tanh(z)
-        elif act == "rectifier":
-            h = jnp.maximum(z, 0.0)
-        else:  # maxout: [B, 2u] → max over channel pairs → [B, u]
-            u = z.shape[-1] // 2
-            h = jnp.maximum(z[..., :u], z[..., u:])
-        p = hid_drops[i] if i < len(hid_drops) else 0.0
-        if train and p > 0:
+#: what every product of this module states. DEFAULT on float32 operands is,
+#: on a TPU, ONE pass of the MXU over operands rounded to bfloat16 with the
+#: sums kept in float32 (read on the v5e, PERF.md section 6, PR 32); on the
+#: CPU it is a float32 product. Parameters, gradients and optimiser state are
+#: float32 everywhere. Written out so that a changed ``jax_default_matmul_
+#: precision`` cannot move a build; raise it here, never lower it.
+_PRECISION = jax.lax.Precision.DEFAULT
+
+
+def _dense(h, W, b):
+    return jnp.dot(h, W, precision=_PRECISION,
+                   preferred_element_type=jnp.float32) + b
+
+
+def _layer_widths(params, act: str) -> list[int]:
+    """[inputs, units of hidden layer 0, ...] from the weights' shapes."""
+    ws = params["W"]
+    return [ws[0].shape[0]] + [W.shape[1] // (2 if act == "maxout" else 1)
+                               for W in ws[:-1]]
+
+
+def _dropout_masks(key, rows: int, widths, in_drop: float,
+                   hid_drops: tuple[float, ...]) -> list:
+    """The keep-masks of one minibatch: ``[rows, widths[0]]`` for the input,
+    ``[rows, widths[i + 1]]`` after hidden layer ``i`` (``_layer_widths``);
+    None where the ratio is 0. One split of ``key`` a mask, in layer order:
+    the order the forward pass has always drawn them in, so a seed gives the
+    bits it gave. The random stream's one home: a plain reference that is
+    handed the masks as arrays (benchmark/reference/dl_mlp_jnp.py) draws
+    them here."""
+    ratios = [in_drop] + [hid_drops[i] if i < len(hid_drops) else 0.0
+                          for i in range(len(widths) - 1)]
+    masks = []
+    for width, p in zip(widths, ratios):
+        if p > 0:
             key, sub = jax.random.split(key)
-            keep = jax.random.bernoulli(sub, 1.0 - p, h.shape)
-            h = jnp.where(keep, h / (1.0 - p), 0.0)
-    return h @ params["W"][-1] + params["b"][-1]   # linear output (logits / preds)
+            masks.append(jax.random.bernoulli(sub, 1.0 - p, (rows, width)))
+        else:
+            masks.append(None)
+    return masks
+
+
+def _drop(h, keep, p: float):
+    """Inverted dropout: the kept units are scaled at training time, so
+    scoring needs no rescale."""
+    return h if keep is None else jnp.where(keep, h / (1.0 - p), 0.0)
+
+
+def _forward(params, X, act: str, masks=None, in_drop: float = 0.0,
+             hid_drops: tuple[float, ...] = ()):
+    """MLP forward pass. Maxout layers hold W of width 2*units and take the
+    pairwise max (reference: 2-channel Maxout, ``Neurons.java``). ``masks``
+    (``_dropout_masks``) is given at training time only."""
+    n_hidden = len(params["W"]) - 1
+    if masks is None:
+        masks = [None] * (n_hidden + 1)
+    with jax.named_scope("dropout"):
+        h = _drop(X, masks[0], in_drop)
+    for i in range(n_hidden):
+        with jax.named_scope("forward"):
+            z = _dense(h, params["W"][i], params["b"][i])
+            if act == "tanh":
+                h = jnp.tanh(z)
+            elif act == "rectifier":
+                h = jnp.maximum(z, 0.0)
+            else:  # maxout: [B, 2u] → max over channel pairs → [B, u]
+                u = z.shape[-1] // 2
+                h = jnp.maximum(z[..., :u], z[..., u:])
+        with jax.named_scope("dropout"):
+            h = _drop(h, masks[i + 1],
+                      hid_drops[i] if i < len(hid_drops) else 0.0)
+    with jax.named_scope("forward"):
+        # linear output (logits / preds)
+        return _dense(h, params["W"][-1], params["b"][-1])
 
 
 def _row_loss(out, y, w, loss: str, nclasses: int, huber_delta: float):
@@ -126,12 +203,15 @@ def _epoch_steps(params, opt, Xb, yb, wb, key, samples0,
      mom_start, mom_ramp, mom_stable, nesterov,
      l1, l2, max_w2, in_drop, hid_drops, huber_delta) = cfg
 
-    def grad_fn(p, X, y, w, k):
-        out = _forward(p, X, act, True, k, in_drop, hid_drops)
-        if nclasses == 0 and out.shape[-1] == 1 and y.ndim == 1:
-            out = out[:, 0]
-        lsum = _row_loss(out, y, w, loss, nclasses, huber_delta)
-        return lsum / jnp.maximum(w.sum(), 1e-8)
+    widths = _layer_widths(params, act)
+
+    def grad_fn(p, X, y, w, masks):
+        out = _forward(p, X, act, masks, in_drop, hid_drops)
+        with jax.named_scope("loss"):
+            if nclasses == 0 and out.shape[-1] == 1 and y.ndim == 1:
+                out = out[:, 0]
+            lsum = _row_loss(out, y, w, loss, nclasses, huber_delta)
+            return lsum / jnp.maximum(w.sum(), 1e-8)
 
     def apply_l1l2(g, p):
         return jax.tree.map(lambda gi, pi: gi + l2 * pi + l1 * jnp.sign(pi), g, p)
@@ -149,12 +229,7 @@ def _epoch_steps(params, opt, Xb, yb, wb, key, samples0,
             return W * jnp.sqrt(max_w2 / jnp.maximum(ss, max_w2))
         return {"W": [cap(W) for W in p["W"]], "b": p["b"]}
 
-    def step(carry, xs):
-        p, o, k, samples = carry
-        X, y, w = xs
-        k, sub = jax.random.split(k)
-        lossv, g = jax.value_and_grad(grad_fn)(p, X, y, w, sub)
-        g = apply_l1l2(g, p)
+    def update(p, o, g, samples):
         if adaptive:
             # ADADELTA (reference Neurons.java adaDelta branch)
             Eg = jax.tree.map(lambda e, gi: rho * e + (1 - rho) * gi * gi, o["Eg"], g)
@@ -185,13 +260,50 @@ def _epoch_steps(params, opt, Xb, yb, wb, key, samples0,
             else:
                 p = jax.tree.map(jnp.add, p, v)
             o = {"Eg": o["Eg"], "Edx": o["Edx"], "v": v}
-        p = constrain(p)
+        return p, o
+
+    def step(carry, xs):
+        p, o, k, samples = carry
+        X, y, w = xs
+        k, sub = jax.random.split(k)
+        with jax.named_scope("dropout"):
+            masks = _dropout_masks(sub, X.shape[0], widths, in_drop, hid_drops)
+        lossv, g = jax.value_and_grad(grad_fn)(p, X, y, w, masks)
+        with jax.named_scope("regularize"):
+            g = apply_l1l2(g, p)
+        with jax.named_scope("optimizer"):
+            p, o = update(p, o, g, samples)
+        with jax.named_scope("constrain"):
+            p = constrain(p)
         samples = samples + w.sum()
         return (p, o, k, samples), lossv
 
     (params, opt, key, samples), losses = jax.lax.scan(
         step, (params, opt, key, samples0), (Xb, yb, wb))
     return params, opt, key, samples, losses.mean()
+
+
+def _epoch_keys(key):
+    """(the next epoch's key, the key of this epoch's permutation, the key
+    its updates split their dropout keys from): the stream's order, kept in
+    one place for the program and for whoever replays it."""
+    key, pk = jax.random.split(key)
+    key, ek = jax.random.split(key)
+    return key, pk, ek
+
+
+def _epoch_plan(epochs: float, rows: int, B: int) -> tuple[int, int, int]:
+    """(updates a whole epoch, whole epochs, updates of a last partial epoch
+    or 0) for ``epochs`` passes over ``rows`` rows in minibatches of ``B``.
+    Training stops after ``epochs x rows`` samples, as the reference's does:
+    the fraction left after the whole epochs is rounded up to whole updates.
+    Nothing to train (``epochs`` <= 0) is one epoch, as it always was."""
+    nb = rows // B
+    whole = max(int(math.floor(epochs)), 0)
+    last = min(nb, math.ceil((epochs - whole) * rows / B)) if epochs > 0 else 0
+    if not whole and not last:
+        whole = 1
+    return nb, whole, last
 
 
 @accounted_jit("dl:train_epochs", loop="dl_epoch",
@@ -213,12 +325,12 @@ def _train_epochs(params, opt, X, yy, w, key, samples0,
 
     def epoch(carry, _):
         params, opt, key, samples = carry
-        key, pk = jax.random.split(key)
-        perm = jax.random.permutation(pk, X.shape[0])[:used]
-        Xb = jnp.take(X, perm, axis=0).reshape(nb, B, K)
-        wb = jnp.take(w, perm, axis=0).reshape(nb, B)
-        ybt = Xb if autoenc else jnp.take(yy, perm, axis=0).reshape(nb, B)
-        key, ek = jax.random.split(key)
+        key, pk, ek = _epoch_keys(key)
+        with jax.named_scope("shuffle"):
+            perm = jax.random.permutation(pk, X.shape[0])[:used]
+            Xb = jnp.take(X, perm, axis=0).reshape(nb, B, K)
+            wb = jnp.take(w, perm, axis=0).reshape(nb, B)
+            ybt = Xb if autoenc else jnp.take(yy, perm, axis=0).reshape(nb, B)
         params, opt, _, samples, mloss = _epoch_steps(
             params, opt, Xb, ybt, wb, ek, samples, act, loss, nclasses, cfg)
         return (params, opt, key, samples), mloss
@@ -228,14 +340,45 @@ def _train_epochs(params, opt, X, yy, w, key, samples0,
     return params, opt, key, samples, losses
 
 
-@partial(jax.jit, static_argnames=("act",))
-def _dl_forward_score(params, X, act: str):
-    return _forward(params, X, act, False, jax.random.PRNGKey(0), 0.0, ())
+#: rows a scoring block. A frame scored whole holds every layer's activations
+#: for all its rows at once: 12 GB at a million rows under a 2,048-unit layer,
+#: which one v5e chip beside the frame and its design does not have (PERF.md
+#: section 6, PR 32); a block of 16,384 holds 0.2 GB of them
+_SCORE_BLOCK = 16384
+
+
+def _score_block(X) -> int:
+    """The block ``_dl_forward_score`` takes for this design: rows in blocks
+    where the design sits on ONE device and is longer than a block, whole (0)
+    on a mesh, where each device already holds only its share of the rows and
+    a slice of the row axis would gather them."""
+    one_device = len(X.sharding.device_set) == 1
+    return _SCORE_BLOCK if one_device and X.shape[0] > _SCORE_BLOCK else 0
+
+
+@partial(jax.jit, static_argnames=("act", "block"))
+def _dl_forward_score(params, X, act: str, block: int = 0):
+    """The network's outputs for every row of ``X``; ``block`` rows at a time
+    where it is given (``_score_block``). The last block starts where a whole
+    block still fits, so it scores some rows of the one before it again, to
+    the same values."""
+    if not block:
+        return _forward(params, X, act)
+    rows = X.shape[0]
+
+    def body(i, out):
+        start = jnp.minimum(i * block, rows - block)
+        part = _forward(
+            params, jax.lax.dynamic_slice_in_dim(X, start, block, axis=0), act)
+        return jax.lax.dynamic_update_slice_in_dim(out, part, start, axis=0)
+
+    out = jnp.zeros((rows, params["b"][-1].shape[0]), jnp.float32)
+    return jax.lax.fori_loop(0, -(-rows // block), body, out)
 
 
 @partial(jax.jit, static_argnames=("act",))
 def _dl_reconstruction_mse(params, X, act: str):
-    out = _forward(params, X, act, False, jax.random.PRNGKey(0), 0.0, ())
+    out = _forward(params, X, act)
     return ((out - X) ** 2).mean(axis=1)
 
 
@@ -243,12 +386,22 @@ def _dl_reconstruction_mse(params, X, act: str):
 # Model / Builder
 # ---------------------------------------------------------------------------
 
+_LOGGED: set[str] = set()
+
+
+def _log_once(message: str) -> None:
+    if message not in _LOGGED:
+        _LOGGED.add(message)
+        logging.getLogger("h2o3_tpu").info("deeplearning: %s", message)
+
+
 class DeepLearningModel(Model):
     algo = "deeplearning"
 
     def _score_raw(self, frame: Frame) -> jax.Array:
         X = self.data_info.expand(frame)
-        out = _dl_forward_score(self.output["params"], X, self.output["act"])
+        out = _dl_forward_score(self.output["params"], X, self.output["act"],
+                                _score_block(X))
         if self.is_classifier:
             return jax.nn.softmax(out, axis=-1)
         if self.params.get("autoencoder"):
@@ -313,6 +466,11 @@ class DeepLearning(ModelBuilder):
             initial_weight_scale=1.0,
             autoencoder=False,
             score_each_iteration=False,
+            # the reference's, with this loop's meaning of each in the
+            # module docstring
+            train_samples_per_iteration=-2,
+            classification_stop=0.0,
+            ignore_const_cols=True,
             # elastic local-SGD (docs/RELIABILITY.md "Elastic training"):
             # elastic = number of requested workers (0 = off; clamped to
             # the mesh-slice layout), local_steps = local epochs each
@@ -366,14 +524,37 @@ class DeepLearning(ModelBuilder):
         ls = self.params.get("local_steps")
         if ls is not None and int(ls) < 0:
             raise ValueError("local_steps must be >= 0")
+        if int(self.params["train_samples_per_iteration"]) < -2:
+            raise ValueError("train_samples_per_iteration must be -2 (auto), "
+                             "-1 (all rows), 0 (one epoch) or a row count")
 
-    def _fit(self, job: Job, frame: Frame, x, y, weights) -> DeepLearningModel:
+    def _note_iteration_params(self, classifier: bool) -> None:
+        """Say once a process what this loop does with a value it cannot
+        honour (module docstring); refuse what the reference refuses."""
+        self.validate_request()
+        tspi = int(self.params["train_samples_per_iteration"])
+        if tspi > 0:
+            _log_once(f"train_samples_per_iteration={tspi} sets nothing "
+                      "here: every update averages exactly, and the loop "
+                      "reports and checkpoints at epoch boundaries")
+        stop = float(self.params["classification_stop"])
+        if classifier and stop >= 0:
+            _log_once(f"classification_stop={stop:g} is not honoured: this "
+                      "loop does not score between epochs and trains the "
+                      "epochs asked for (-1 is what it does)")
+
+    def _prepare(self, frame: Frame, x, y, weights) -> SimpleNamespace:
+        """Everything a build holds before its first update: the design
+        matrix and its ``DataInfo``, response and weights, layer sizes,
+        initial (or resumed) parameters, zeroed optimiser state, the PRNG key
+        the epochs start from and the static hyperparameter tuple ``cfg`` of
+        ``_epoch_steps``."""
         p = self.params
         act, act_dropout = _act_kind(p["activation"])
         autoenc = bool(p["autoencoder"])
-
         di = DataInfo.make(frame, x, standardize=p["standardize"],
-                           use_all_factor_levels=p["use_all_factor_levels"])
+                           use_all_factor_levels=p["use_all_factor_levels"],
+                           ignore_const_cols=bool(p["ignore_const_cols"]))
         X = di.expand(frame)
         K = X.shape[1]
 
@@ -444,6 +625,26 @@ class DeepLearning(ModelBuilder):
                float(p["input_dropout_ratio"]), tuple(float(d) for d in hid_drops),
                1.0)
 
+        return SimpleNamespace(
+            di=di, X=X, yy=yy, w=w, act=act, autoenc=autoenc, loss=loss,
+            nclasses=nclasses, domain=domain, sizes=sizes, params=params,
+            opt=opt, key=key, cfg=cfg, done_ep=done_ep, samples0=samples0)
+
+    def _fit(self, job: Job, frame: Frame, x, y, weights) -> DeepLearningModel:
+        p = self.params
+        autoenc = bool(p["autoencoder"])
+        self._note_iteration_params(classifier=not autoenc and
+                                    frame.vec(y).is_categorical)
+        with timed_event("phase", f"{self.algo}:prepare"):
+            prep = self._prepare(frame, x, y, weights)
+        act, di, X, yy, w = prep.act, prep.di, prep.X, prep.yy, prep.w
+        loss, nclasses, domain = prep.loss, prep.nclasses, prep.domain
+        sizes, params, opt, key, cfg = (prep.sizes, prep.params, prep.opt,
+                                        prep.key, prep.cfg)
+        done_ep, samples0 = prep.done_ep, prep.samples0
+        _tm.DL_PARAMETERS.set(sum(
+            int(a.size) for a in jax.tree.leaves(params)))
+
         if int(p.get("elastic") or 0):
             # elastic local-SGD: k slice-leased workers train K local
             # epochs per round on their own shard and average parameters
@@ -455,16 +656,14 @@ class DeepLearning(ModelBuilder):
 
         plen = X.shape[0]
         B = min(max(int(p["mini_batch_size"]), 1), plen)
-        nb = plen // B
-        used = nb * B
-        epochs = float(p["epochs"])
-        n_epochs = max(int(np.ceil(epochs)), 1)
+        nb, whole, last = _epoch_plan(float(p["epochs"]), plen, B)
 
         samples = jnp.float32(samples0)
         k_mega = megastep_k()
         epoch_losses = []        # [k] device arrays; fetched once post-loop
         ep = 0
-        n_epochs = max(n_epochs - done_ep, 0)   # remaining after auto-resume
+        # remaining after auto-resume; a partial epoch is the last one
+        n_epochs = max(whole + bool(last) - done_ep, 0)
         dispatches = 0
         import time as _time
         from h2o3_tpu.models.job import JobCancelled
@@ -487,57 +686,65 @@ class DeepLearning(ModelBuilder):
             recovery.snapshot(pm, progress=done_ep + epochs_now,
                               target=done_ep + n_epochs)
 
-        while ep < n_epochs:
-            if job.should_stop:
-                # cooperative deadline/cancel between megasteps: trained
-                # epochs are kept (partial model, job CANCELLED)
-                job.keep_partial()
-                break
-            # K epochs per compiled dispatch (trailing chunk compiles its own
-            # smaller K once); shuffle + minibatching run inside the program,
-            # so the host dispatches WORK, not steps
-            kk = min(k_mega, n_epochs - ep)
-            t0 = _time.time_ns()
-            _in = (params, opt, key, samples)
-            with timed_event("iteration", "dl_epoch"):
-                # retried on transient dispatch failure: the megastep is
-                # functional over its inputs, so a re-run is exact
-                params, opt, key, samples, losses_k = retrying(
-                    "dl_epochs", lambda: _train_epochs(
-                        *_in[:2], X, yy, w, *_in[2:],
-                        act, loss, nclasses, cfg, kk, nb, B, autoenc))
-            # NO per-epoch fetch: the loss series stays on device and is
-            # fetched in one batched transfer below, so megasteps pipeline
-            epoch_losses.append(losses_k)
-            dispatches += 1
-            ep += kk
-            # per-EPOCH latency: megastep wall amortized over its epochs, so
-            # the histogram count keeps matching epochs (same contract as
-            # the GLM loops; like the old per-epoch path this is dispatch
-            # enqueue time — the loss fetch below pays the real wait)
-            dt = (_time.time_ns() - t0) / 1e9
-            for _ in range(kk):
-                _tm.ITER_SECONDS.labels(loop="dl_epoch").observe(dt / kk)
-            if recovery is not None and ep - last_snap >= ckpt_every:
-                _snapshot(ep)
-                last_snap = ep
-            try:
-                job.update(ep / max(n_epochs, 1), f"epoch {ep}/{n_epochs}")
-            except JobCancelled:
-                job.keep_partial()
-                break           # partial-result algorithm: keep the epochs
-            if job.cancelled:
-                break
-        if recovery is not None and job.should_stop and ep > last_snap:
-            _snapshot(ep)       # CANCELLED builds stay resumable
-        publish_dispatch_audit(self, "dl_epoch", iterations=max(ep, 1),
-                               host_syncs=1, device_dispatches=dispatches)
-        score_history = [
-            {"epoch": i + 1, "train_loss": float(v)}
-            for i, v in enumerate(np.concatenate(
-                [np.atleast_1d(np.asarray(a))
-                 for a in jax.device_get(epoch_losses)])
-                if epoch_losses else [])]
+        with timed_event("phase", f"{self.algo}:epochs"):
+            while ep < n_epochs:
+                if job.should_stop:
+                    # cooperative deadline/cancel between megasteps: trained
+                    # epochs are kept (partial model, job CANCELLED)
+                    job.keep_partial()
+                    break
+                # K epochs per compiled dispatch (trailing chunk compiles its own
+                # smaller K once); shuffle + minibatching run inside the program,
+                # so the host dispatches WORK, not steps
+                if last and ep == n_epochs - 1:
+                    kk, steps = 1, last
+                else:
+                    kk, steps = min(k_mega, n_epochs - bool(last) - ep), nb
+                t0 = _time.time_ns()
+                _in = (params, opt, key, samples)
+                with timed_event("iteration", "dl_epoch"):
+                    # retried on transient dispatch failure: the megastep is
+                    # functional over its inputs, so a re-run is exact
+                    params, opt, key, samples, losses_k = retrying(
+                        "dl_epochs", lambda: _train_epochs(
+                            *_in[:2], X, yy, w, *_in[2:],
+                            act, loss, nclasses, cfg, kk, steps, B, autoenc))
+                # NO per-epoch fetch: the loss series stays on device and is
+                # fetched in one batched transfer below, so megasteps pipeline
+                epoch_losses.append(losses_k)
+                dispatches += 1
+                ep += kk
+                _tm.DL_UPDATES.inc(kk * steps)
+                _tm.DL_SAMPLES.inc(kk * steps * B)
+                # per-EPOCH latency: megastep wall amortized over its epochs, so
+                # the histogram count keeps matching epochs (same contract as
+                # the GLM loops; like the old per-epoch path this is dispatch
+                # enqueue time — the loss fetch below pays the real wait)
+                dt = (_time.time_ns() - t0) / 1e9
+                for _ in range(kk):
+                    _tm.ITER_SECONDS.labels(loop="dl_epoch").observe(dt / kk)
+                if recovery is not None and ep - last_snap >= ckpt_every:
+                    _snapshot(ep)
+                    last_snap = ep
+                try:
+                    job.update(ep / max(n_epochs, 1), f"epoch {ep}/{n_epochs}")
+                except JobCancelled:
+                    job.keep_partial()
+                    break           # partial-result algorithm: keep the epochs
+                if job.cancelled:
+                    break
+            if recovery is not None and job.should_stop and ep > last_snap:
+                _snapshot(ep)       # CANCELLED builds stay resumable
+            publish_dispatch_audit(self, "dl_epoch", iterations=max(ep, 1),
+                                   host_syncs=1, device_dispatches=dispatches)
+            # the build's one fetch: it waits for every dispatch above, so the
+            # span that ends here is a synced time
+            score_history = [
+                {"epoch": i + 1, "train_loss": float(v)}
+                for i, v in enumerate(np.concatenate(
+                    [np.atleast_1d(np.asarray(a))
+                     for a in jax.device_get(epoch_losses)])
+                    if epoch_losses else [])]
 
         model = DeepLearningModel(
             key=make_model_key(self.algo, self.model_id),

@@ -598,6 +598,18 @@ GLM_EXPANDED_WIDTH = METRICS.gauge(
     "h2o3_glm_expanded_width",
     "columns of the newest expanded GLM design matrix")
 
+# DeepLearning's minibatch loop (models/deeplearning.py _fit): updates and
+# rows the dispatched epochs carried, added on the host a dispatch's worth
+# at a time, and the parameter count of the newest network
+DL_UPDATES = METRICS.counter(
+    "h2o3_dl_updates", "DeepLearning minibatch updates dispatched")
+DL_SAMPLES = METRICS.counter(
+    "h2o3_dl_samples", "DeepLearning training rows dispatched (updates x "
+    "mini_batch_size)")
+DL_PARAMETERS = METRICS.gauge(
+    "h2o3_dl_parameters", "weights and biases of the newest DeepLearning "
+    "network")
+
 # mesh-slice scheduler (orchestration/scheduler.py): utilization of the
 # disjoint device slices concurrent builds run on (docs/ORCHESTRATION.md).
 # Slice labels are indices ("0".."k-1") or "full" for whole-mesh leases.
