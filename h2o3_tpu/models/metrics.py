@@ -19,9 +19,22 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+
+from h2o3_tpu.utils.telemetry import METRIC_HIST
 
 NBINS = 400  # reference: AUC2.NBINS=400
 
+#: the score histogram's row blocks (:func:`_bucket_sums`): a power of two
+#: that covers the rows in about ``_HIST_STEPS`` steps, between these ends.
+#: The short end is what compiles as fast as the scatter-adds it replaced (a
+#: v5e compiles a 512-row block in 0.13 s and a 30,000-row one in 0.45 s, and
+#: a small frame's first call is all compile); the long end is what runs
+#: fastest on many rows (22M rows: 11 ms, 19 ms at 4,096), and keeps a
+#: block's bucket to 2**16 products of 8 significant bits: an exact float32
+#: sum when they are equal (a tree model's few dozen distinct scores).
+_HIST_STEPS = 512
+_HIST_BLOCK_MIN, _HIST_BLOCK_MAX = 512, 65_536
 
 # -- containers --------------------------------------------------------------
 
@@ -257,26 +270,102 @@ def regression_metrics(pred: jax.Array, y: jax.Array, mask: jax.Array,
 # -- binomial -----------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("nbins",))
-def _binomial_pass(p, y, mask, nbins=NBINS):
+def _row_shards(arr) -> int:
+    """Into how many pieces the devices split ``arr``'s rows (1 on one device
+    or replicated), read off its sharding OUTSIDE jit."""
+    sharding = getattr(arr, "sharding", None)
+    if sharding is None or not arr.shape[0]:
+        return 1
+    return arr.shape[0] // sharding.shard_shape(arr.shape)[0]
+
+
+def _hist_block(rows: int) -> int:
+    """Rows a step of the score histogram contracts, for ``rows`` in all."""
+    covering = 1 << (-(-rows // _HIST_STEPS) - 1).bit_length()
+    return min(rows, max(_HIST_BLOCK_MIN, min(covering, _HIST_BLOCK_MAX)))
+
+
+def _bf16_digits(x):
+    """``x`` (float32) as three bfloat16 digits that add back to it exactly:
+    8 + 8 + 8 significant bits. ``lax.reduce_precision``, because XLA's TPU
+    pipeline removes an ``astype(bfloat16).astype(float32)`` pair and the
+    lower digits with it."""
+    def round8(v):
+        return lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+    hi = round8(x)
+    mid = round8(x - hi)
+    return [d.astype(jnp.bfloat16) for d in (hi, mid, round8(x - hi - mid))]
+
+
+def _bucket_sums(p, y, mask, nbins: int, shards: int = 1):
+    """Per score bucket, over the unmasked rows: the counts of ``y`` and
+    ``1 - y`` (``[nbins, 2]`` int32) and the sum of ``p`` (``[nbins]``
+    float32): the AUC2 histogram as a matrix product,
+    ``onehot(bucket)ᵀ · [y, 1 - y, p]``, which the MXU streams where a
+    scatter-add pays 8.7 ns a row and statistic on a v5e.
+
+    Blocks of :func:`_hist_block` rows, one ``[blk, nbins]ᵀ x [blk, 5]``
+    product each, so only a block's one-hot ever exists (XLA makes it inside the
+    product's own fusion); the last block is the array's last ``blk`` rows
+    with the rows an earlier block counted masked out, so nothing is padded
+    or copied. Precision: the one-hot and the 0/1 class indicators are exact
+    in bfloat16, and ``p`` goes in as three bfloat16 digits
+    (:func:`_bf16_digits`), so ONE bfloat16 pass gives float32 sums: every
+    product is exact, the MXU accumulates in float32. (float32 operands at
+    ``Precision.HIGHEST`` would split the one-hot into digits too: six
+    passes for the same numbers.) Blocks' counts add in int32, exact past
+    the 2**24 rows at which a float32 scatter-add stalls; their score sums
+    add in float32, digit by digit. A masked row adds zeros by ``where``:
+    its score may be NaN.
+
+    ``shards`` > 1: ``p``'s rows are split that many ways over devices; each
+    piece is summed where it lives (``vmap`` over the pieces, which implicit
+    SPMD partitions along them) and the pieces' sums added once."""
+    if shards > 1:
+        pieces = [x.reshape(shards, -1) for x in (p, y, mask)]
+        counts, sums = jax.vmap(lambda *a: _bucket_sums(*a, nbins))(*pieces)
+        return counts.sum(0), sums.sum(0)
+    rows = p.shape[0]
+    blk = _hist_block(rows)
+
+    def step(i, acc):
+        start = jnp.minimum(i * blk, rows - blk)
+        pb, yb, mb = (lax.dynamic_slice_in_dim(x, start, blk)
+                      for x in (p, y, mask))
+        mb &= start + lax.iota(jnp.int32, blk) >= i * blk
+        bins = jnp.clip((pb * nbins).astype(jnp.int32), 0, nbins - 1)
+        stats = jnp.stack(
+            [jnp.where(mb, yb, 0.0).astype(jnp.bfloat16),
+             jnp.where(mb, 1.0 - yb, 0.0).astype(jnp.bfloat16),
+             *_bf16_digits(jnp.where(mb, pb, 0.0))], axis=1)
+        part = lax.dot_general(
+            jax.nn.one_hot(bins, nbins, dtype=jnp.bfloat16), stats,
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return acc[0] + part[:, :2].astype(jnp.int32), acc[1] + part[:, 2:]
+
+    counts, sums = lax.fori_loop(
+        0, -(-rows // max(blk, 1)), step,
+        (jnp.zeros((nbins, 2), jnp.int32), jnp.zeros((nbins, 3), jnp.float32)))
+    return counts, sums.sum(1)
+
+
+@partial(jax.jit, static_argnames=("nbins", "shards"))
+def _binomial_pass(p, y, mask, nbins=NBINS, shards=1):
     """One fused pass: 400-bin score histogram (AUC2 semantics) + logloss + MSE."""
+    METRIC_HIST.labels(path="matmul").inc()
     w = mask.astype(jnp.float32)
     n = w.sum()
     pc = jnp.clip(p, 1e-7, 1 - 1e-7)
     logloss = -(w * (y * jnp.log(pc) + (1 - y) * jnp.log1p(-pc))).sum() / n
     err = jnp.where(mask, p - y, 0.0)
     mse = (err * err).sum() / n
-
-    bins = jnp.clip((p * nbins).astype(jnp.int32), 0, nbins - 1)
-    bins = jnp.where(mask, bins, 0)
-    tp_h = jax.ops.segment_sum(w * y, bins, num_segments=nbins)
-    fp_h = jax.ops.segment_sum(w * (1.0 - y), bins, num_segments=nbins)
-    s_h = jax.ops.segment_sum(w * p, bins, num_segments=nbins)
-    return dict(n=n, logloss=logloss, mse=mse, tp_h=tp_h, fp_h=fp_h, s_h=s_h)
+    counts, s_h = _bucket_sums(p, y, mask, nbins, shards)
+    return dict(n=n, logloss=logloss, mse=mse, tp_h=counts[:, 0],
+                fp_h=counts[:, 1], s_h=s_h)
 
 
 def binomial_metrics(p: jax.Array, y: jax.Array, mask: jax.Array) -> ModelMetricsBinomial:
-    r = jax.device_get(_binomial_pass(p, y, mask))
+    r = jax.device_get(_binomial_pass(p, y, mask, shards=_row_shards(p)))
     tp_h, fp_h = np.asarray(r["tp_h"], np.float64), np.asarray(r["fp_h"], np.float64)
     P, N = tp_h.sum(), fp_h.sum()
     # descending threshold sweep: cumulative TP/FP from the top bin down

@@ -569,6 +569,15 @@ HIST_KERNEL_LEVELS = METRICS.counter(
 HIST_GRID_STEPS = METRICS.counter(
     "h2o3_hist_grid_steps", "grid steps of the histogram kernel calls traced")
 
+# the binomial metrics' 400-bucket score histogram (models/metrics.py
+# ``_binomial_pass``): one increment where the pass is TRACED (a cached
+# program adds nothing), by how the buckets are summed. ``matmul``: a blocked
+# one-hot product on the MXU, the one path there is; a fallback to
+# scatter-adds would count itself as ``scatter``.
+METRIC_HIST = METRICS.counter(
+    "h2o3_metric_hist", "binomial metric passes traced, by histogram path",
+    ("path",))
+
 # host-driven convergence loops (models/*.py drivers): per-iteration wall
 # time — IRLS steps, boosting chunks, DL epochs. The before/after evidence
 # for host-sync batching fixes (graftlint TRC003) lives here: fewer

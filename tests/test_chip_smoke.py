@@ -111,6 +111,34 @@ def test_hist_kernel_compiles_for_v5e(monkeypatch):
         assert "tpu_custom_call" in exe.as_text(), (rows, feats, n_bins_tot)
 
 
+@pytest.mark.parametrize("rows", [2_000_000, 22_000_000])
+def test_binomial_pass_compiles_for_v5e_as_a_matrix_product(monkeypatch, rows):
+    """The binomial metrics' pass at the benchmark's row counts, compiled for
+    the v5e with no chip attached: the score histogram is one MXU product a
+    block (no scatter-add), in plain XLA (no Mosaic call: a GLM process
+    never loads Pallas), and only a block's one-hot exists (the whole one at
+    22M rows would be 17.6 GB)."""
+    monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from h2o3_tpu.models.metrics import _binomial_pass
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    on_chip = SingleDeviceSharding(topo.devices[0])
+
+    def spec(dtype):
+        return jax.ShapeDtypeStruct((rows,), dtype, sharding=on_chip)
+
+    exe = _binomial_pass.lower(spec(jnp.float32), spec(jnp.float32),
+                               spec(jnp.bool_)).compile()
+    text = exe.as_text()
+    assert " scatter(" not in text and "tpu_custom_call" not in text
+    assert " convolution(" in text
+    assert exe.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 @pytest.mark.parametrize("n_nodes, contraction, blocks", [
     (16, "packed", 1), (32, "passes", 1), (64, "passes", 1),
     (256, "passes", 4)])

@@ -1,5 +1,6 @@
 """Metrics parity tests (reference: hex/AUC2, ModelMetrics* semantics)."""
 
+import jax
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -81,6 +82,140 @@ def test_gains_lift_table(rng):
     assert m.ks > 0.3
     # KS column max matches the scalar KS metric up to binning
     assert max(r["kolmogorov_smirnov"] for r in gl) == pytest.approx(m.ks, abs=0.05)
+
+
+def _bincount64(p, y, mask, nbins=400):
+    """The score histogram in numpy float64, by the pass's own bucket rule."""
+    y, p = y[mask].astype(np.float64), p[mask]
+    bins = np.clip((p * np.float32(nbins)).astype(np.int32), 0, nbins - 1)
+    return (np.bincount(bins, weights=y, minlength=nbins),
+            np.bincount(bins, weights=1.0 - y, minlength=nbins),
+            np.bincount(bins, weights=p.astype(np.float64), minlength=nbins))
+
+
+def _scores(rng, case):
+    """(p, mask) of one case of the blocked histogram. A block is 512 rows
+    up to 262,144 rows and a power of two that covers the rows in 512 steps
+    past that (``metrics._hist_block``)."""
+    if case == "under-one-block":
+        return rng.random(300).astype(np.float32), None
+    if case == "one-block":
+        return rng.random(512).astype(np.float32), None
+    if case == "three-blocks-and-17":
+        return rng.random(3 * 512 + 17).astype(np.float32), None
+    if case == "longer-blocks":                # 2,048 rows a block, 293 blocks
+        return rng.random(600_000).astype(np.float32), None
+    if case == "one-bucket":
+        return np.full(2 * 512 + 5, 0.3, np.float32), None
+    if case == "zero-and-one":
+        p = rng.random(5000).astype(np.float32)
+        p[:100], p[100:300] = 0.0, 1.0
+        return p, None
+    if case == "masked-nan":
+        p = rng.random(512 + 300).astype(np.float32)
+        mask = rng.random(p.size) < 0.8
+        p[~mask] = np.nan
+        return p, mask
+    assert case == "few-distinct-scores"      # a five-tree GBM's leaves
+    leaves = rng.random(40).astype(np.float32)
+    return leaves[rng.integers(0, 40, size=300_999)], None
+
+
+@pytest.mark.parametrize("case", [
+    "under-one-block", "one-block", "three-blocks-and-17", "longer-blocks",
+    "one-bucket", "zero-and-one", "masked-nan", "few-distinct-scores"])
+def test_binomial_pass_histogram_matches_float64_bincount(rng, case):
+    """The blocked one-hot product against a numpy float64 ``bincount``:
+    counts equal exactly, score sums to float32's last bits."""
+    from h2o3_tpu.models import metrics
+    p, mask = _scores(rng, case)
+    assert metrics._hist_block(p.size) == {
+        "under-one-block": 300, "longer-blocks": 2048,
+        "few-distinct-scores": 1024}.get(case, 512)
+    mask = np.ones(p.size, bool) if mask is None else mask
+    y = (rng.random(p.size) < 0.4).astype(np.float32)
+    y[~mask] = np.nan                      # a masked row's response too
+    r = jax.device_get(metrics._binomial_pass(
+        jnp.asarray(p), jnp.asarray(y), jnp.asarray(mask)))
+    tp, fp, s = _bincount64(p, y, mask)
+    np.testing.assert_array_equal(r["tp_h"], tp)
+    np.testing.assert_array_equal(r["fp_h"], fp)
+    np.testing.assert_allclose(r["s_h"], s, rtol=1e-6, atol=0)
+    assert r["tp_h"].sum() + r["fp_h"].sum() == mask.sum()
+    if case == "zero-and-one":
+        assert tp[0] + fp[0] >= 100 and tp[399] + fp[399] >= 200
+    if case == "one-bucket":
+        assert np.count_nonzero(tp + fp) == 1
+
+
+def test_binomial_pass_sums_row_sharded_scores_where_they_live(rng):
+    """Scores split over the mesh's devices give the histogram of one
+    device, and ``binomial_metrics`` reads the split off the array."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from h2o3_tpu.models import metrics
+    from h2o3_tpu.parallel.mesh import ROWS, get_mesh
+    mesh = get_mesh()
+    ndev = mesh.shape[ROWS]
+    n = ndev * 1000
+    p = rng.random(n).astype(np.float32)
+    y = (rng.random(n) < p).astype(np.float32)
+    mask = rng.random(n) < 0.9
+    rows = NamedSharding(mesh, P(ROWS))
+    pd_, yd, md = (jax.device_put(a, rows) for a in (p, y, mask))
+    assert metrics._row_shards(pd_) == ndev
+    assert metrics._row_shards(jnp.asarray(p)) == 1
+    r = jax.device_get(metrics._binomial_pass(pd_, yd, md, shards=ndev))
+    tp, fp, s = _bincount64(p, y, mask)
+    np.testing.assert_array_equal(r["tp_h"], tp)
+    np.testing.assert_array_equal(r["fp_h"], fp)
+    np.testing.assert_allclose(r["s_h"], s, rtol=1e-6, atol=0)
+    one = binomial_metrics(jnp.asarray(p), jnp.asarray(y), jnp.asarray(mask))
+    split = binomial_metrics(pd_, yd, md)
+    assert split.auc == one.auc and split.ks == one.ks
+    np.testing.assert_array_equal(split.confusion_matrix, one.confusion_matrix)
+
+
+def test_binomial_metrics_from_the_blocked_histogram(rng):
+    """What ``test_auc_matches_sklearn`` and ``test_gains_lift_table`` pin,
+    on scores that span several blocks: every metric read off the histogram
+    equals the same metric worked from a float64 ``bincount``."""
+    n = 7 * 512 + 321
+    p = rng.random(n).astype(np.float32)
+    y = (rng.random(n) < p).astype(np.float32)
+    mask = np.ones(n, bool)
+    m = binomial_metrics(jnp.asarray(p), jnp.asarray(y), jnp.asarray(mask))
+    tp, fp, s = _bincount64(p, y, mask)
+    np.testing.assert_array_equal(m._tp_h, tp)
+    np.testing.assert_array_equal(m._fp_h, fp)
+    tps, fps = np.cumsum(tp[::-1]), np.cumsum(fp[::-1])
+    tpr = np.concatenate([[0.0], tps / tp.sum(), [1.0]])
+    fpr = np.concatenate([[0.0], fps / fp.sum(), [1.0]])
+    assert m.auc == float(np.trapezoid(tpr, fpr))
+    assert m.ks == float(np.max(tps / tp.sum() - fps / fp.sum()))
+    assert m.confusion_matrix.sum() == n and m.nobs == n
+    from sklearn.metrics import roc_auc_score
+    assert abs(m.auc - roc_auc_score(y, p)) < 0.004
+    gl = m.gains_lift(groups=16)
+    assert gl[-1]["cumulative_data_fraction"] == pytest.approx(1.0, abs=1e-9)
+    assert gl[-1]["cumulative_capture_rate"] == pytest.approx(1.0, abs=1e-9)
+    assert gl[-1]["cumulative_score"] == pytest.approx(p.mean(), rel=1e-6)
+
+
+def test_metric_hist_counter_counts_a_trace_once():
+    """``h2o3_metric_hist_total{path="matmul"}`` moves where the pass is
+    traced: once for a new row count, not at all for a cached program."""
+    from h2o3_tpu.models import metrics
+    from h2o3_tpu.utils.telemetry import METRIC_HIST
+    counter = METRIC_HIST.labels(path="matmul")
+    rows = 1237                            # a row count no other test uses
+    p = jnp.linspace(0.0, 1.0, rows, dtype=jnp.float32)
+    y = (p > 0.5).astype(jnp.float32)
+    before = counter.value
+    metrics.binomial_metrics(p, y, jnp.ones(rows, bool))
+    assert counter.value == before + 1
+    metrics.binomial_metrics(p * 0.5, y, jnp.ones(rows, bool))
+    assert counter.value == before + 1
 
 
 def test_auc2_threshold_criteria(rng):
