@@ -17,6 +17,7 @@ excludes them from model training.
 from __future__ import annotations
 
 import math
+import time
 from typing import Sequence
 
 import jax
@@ -27,6 +28,11 @@ from h2o3_tpu.frame.types import CAT_NA, VecType
 from h2o3_tpu.frame.rollups import Rollups, cat_rollups, numeric_rollups
 from h2o3_tpu.parallel.mesh import (ROWS, bound_mesh, num_global_devices,
                                     row_sharding)
+from h2o3_tpu.utils.telemetry import ROLLUP_SECONDS, ROLLUPS
+from h2o3_tpu.utils.timeline import OUTSIDE, PHASE
+
+# exported from the start: 0 roll-up seconds is a reading, not absence
+ROLLUP_SECONDS.labels(phase=OUTSIDE)
 
 # Pad row counts to a multiple of (devices * _ROW_ALIGN) so every shard is
 # sublane-aligned for float32 tiles (8 x 128 min tile).
@@ -234,14 +240,29 @@ class Vec:
 
     def rollups(self) -> Rollups:
         if self._rollups is None:
-            if self.type is VecType.CAT:
-                self._rollups = cat_rollups(self.data, self.nrows)
-            elif self.type.on_device:
-                self._rollups = numeric_rollups(self.data, self.nrows)
-            else:
-                na = int(sum(v is None for v in self.host_values))
-                self._rollups = Rollups(self.nrows, na, float("nan"), float("nan"),
-                                        float("nan"), float("nan"), 0, False, 0, 0)
+            # counted where it runs: the wall ends with the fetch and goes
+            # under the phase that asked (else a build would be charged the
+            # roll-ups of the frame's making); the roll-up program's own
+            # first call, inside that wall, is booked under `frame:rollups`
+            t0 = time.perf_counter()
+            asked = PHASE.get() or OUTSIDE
+            phase = PHASE.set("frame:rollups")
+            try:
+                if self.type is VecType.CAT:
+                    kind = "cat"
+                    self._rollups = cat_rollups(self.data, self.nrows)
+                elif self.type.on_device:
+                    kind = "numeric"
+                    self._rollups = numeric_rollups(self.data, self.nrows)
+                else:
+                    kind = "other"
+                    na = int(sum(v is None for v in self.host_values))
+                    self._rollups = Rollups(self.nrows, na, float("nan"), float("nan"),
+                                            float("nan"), float("nan"), 0, False, 0, 0)
+            finally:
+                PHASE.reset(phase)
+            ROLLUPS.labels(kind=kind).inc()
+            ROLLUP_SECONDS.labels(phase=asked).inc(time.perf_counter() - t0)
         return self._rollups
 
     def invalidate_rollups(self) -> None:

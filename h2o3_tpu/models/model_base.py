@@ -349,6 +349,16 @@ class ModelBuilder:
     def train(self, x: Sequence[str] | None = None, y: str | None = None,
               training_frame: Frame | None = None, validation_frame: Frame | None = None,
               weights: jax.Array | None = None) -> Model:
+        # one phase for the whole build, so that what it does before
+        # `<algo>:fit` and after `<algo>:metrics` (frame adaptation, roll-ups
+        # asked from here, Job, DKV put) has a name where first calls are
+        # booked (utils/compile_cache.py); kind "phase": no memory sample
+        with timed_event("phase", f"{self.algo}:train"):
+            return self._train(x, y, training_frame, validation_frame,
+                               weights)
+
+    def _train(self, x, y, training_frame, validation_frame,
+               weights) -> Model:
         frame = training_frame
         if frame is None:
             raise ValueError("training_frame is required")

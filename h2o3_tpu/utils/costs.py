@@ -52,6 +52,8 @@ import time
 import weakref
 from collections import OrderedDict
 
+from h2o3_tpu.utils import compile_cache, tracing
+
 #: logical site active for compile attribution (innermost scope wins)
 _SITE: contextvars.ContextVar["str | None"] = \
     contextvars.ContextVar("h2o3_cost_site", default=None)
@@ -201,6 +203,8 @@ def cost_of(compiled) -> tuple[float | None, float | None]:
 #: recompile events kept per site / process-wide cap on stored signatures
 MAX_SIGNATURES_PER_SITE = 32
 MAX_RECOMPILE_EVENTS = 64
+#: rows of the first-call table, by (phase, fun_name); least seconds go first
+MAX_FIRST_CALL_ROWS = 256
 
 
 class CostMeter:
@@ -214,6 +218,8 @@ class CostMeter:
         self._sites: "OrderedDict[str, dict]" = OrderedDict()
         # loop -> {"samples": int, "achieved_flops_per_sec": float, ...}
         self._loops: dict[str, dict] = {}
+        # (phase, fun_name) -> [requests, trace s, lower s, backend s, hits]
+        self._first_calls: dict[tuple, list] = {}
         self._wrappers: "weakref.WeakSet[AccountedJit]" = weakref.WeakSet()
 
     # -- site scope (compile-cache attribution) ------------------------------
@@ -287,6 +293,29 @@ class CostMeter:
         _tm.COMPILE_SECONDS.labels(site=site).inc(float(seconds))
         if recompiled:
             _tm.RECOMPILES.labels(site=site).inc()
+
+    def record_first_call(self, phase: str, fun_name: str, trace: float,
+                          lower: float, backend: float, hit: bool) -> None:
+        """One executable request (``utils/compile_cache.py``'s listener on
+        JAX's ``backend_compile_duration``): the backend's seconds (a load
+        on a cache hit, a compile otherwise) with the trace and lower
+        seconds of the same function that preceded it on its thread, under
+        the program's phase: the one per-function record of the stages.
+        The table is bounded; the row with the least seconds goes first."""
+        with self._lock:
+            row = self._first_calls.get((phase, fun_name))
+            if row is None:
+                if len(self._first_calls) >= MAX_FIRST_CALL_ROWS:
+                    del self._first_calls[min(
+                        self._first_calls,
+                        key=lambda k: sum(self._first_calls[k][1:4]))]
+                row = self._first_calls[(phase, fun_name)] = [0, 0.0, 0.0,
+                                                              0.0, 0]
+            row[0] += 1
+            row[1] += trace
+            row[2] += lower
+            row[3] += backend
+            row[4] += bool(hit)
 
     def latest_cost(self, site: str) -> tuple[float | None, float | None]:
         """(flops, bytes) of the site's most recently compiled signature —
@@ -396,9 +425,17 @@ class CostMeter:
                     "recompile_events": [dict(e) for e in rec["recompiles"]],
                 })
             loops = {k: dict(v) for k, v in self._loops.items()}
+            first = sorted(self._first_calls.items(),
+                           key=lambda kv: -sum(kv[1][1:4]))
         return {"backend": backend, "device_kind": kind,
                 "peak": dict(peak) if peak else None,
                 "sites": sites, "loops": loops,
+                "first_calls": [
+                    {"phase": phase, "fun_name": fun, "requests": n,
+                     "trace_seconds": round(tr, 6),
+                     "lower_seconds": round(lo, 6),
+                     "backend_seconds": round(be, 6), "cache_hits": hits}
+                    for (phase, fun), (n, tr, lo, be, hits) in first],
                 "signature_count": sum(len(s["signatures"]) for s in sites),
                 "recompile_events": sum(len(s["recompile_events"])
                                         for s in sites)}
@@ -442,9 +479,14 @@ class CostMeter:
         with self._lock:
             self._sites.clear()
             self._loops.clear()
+            self._first_calls.clear()
 
 
 COSTS = CostMeter()
+
+# what a first call pays is recorded from here on, with the persistent cache
+# on or off (compile_cache.enable() may come first; never twice)
+compile_cache.listen(COSTS)
 
 
 # ---------------------------------------------------------------------------
@@ -586,10 +628,17 @@ class AccountedJit:
     def _compile(self, key, statics, leaves, args, kwargs):
         # a trace or compiler error surfaces here, ONCE: the plain jit path
         # would trace and compile the same program into the same failure
-        with COSTS.scope(self.site):
-            t0 = time.perf_counter()
-            compiled = self._jit.lower(*args, **kwargs).compile()
-            dt = time.perf_counter() - t0
+        # an annotation of its own, so that a recompile inside ANY profiler
+        # session is a host event beside the gap it caused
+        ann = tracing.annotation(f"compile:{self.site}")
+        try:
+            with COSTS.scope(self.site):
+                t0 = time.perf_counter()
+                compiled = self._jit.lower(*args, **kwargs).compile()
+                dt = time.perf_counter() - t0
+        finally:
+            if ann is not None:
+                ann.__exit__(None, None, None)
         flops, nbytes = cost_of(compiled)
         signature = {"args": [_leaf_descr(x) for x in leaves],
                      "statics": {k: repr(v) for k, v in statics}}

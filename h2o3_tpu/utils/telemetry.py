@@ -497,6 +497,19 @@ SPILL_RESTORES = METRICS.counter(
 SPILL_RESTORE_BYTES = METRICS.counter(
     "h2o3_spill_restore_bytes", "bytes faulted back in on access", ("kind",))
 
+# a column's lazy roll-up (frame/vec.py ``Vec.rollups``), counted where it is
+# COMPUTED (a cached one adds nothing): one program and one fetch a column,
+# so a first build on a new frame pays one round trip a predictor. The
+# count by the column's kind, the seconds by the phase that asked (the
+# innermost open ``timed_event``, else "(outside a build)"): seconds over
+# count is what a round trip costs.
+ROLLUPS = METRICS.counter(
+    "h2o3_rollups", "column roll-ups computed", ("kind",))
+ROLLUP_SECONDS = METRICS.counter(
+    "h2o3_rollup_seconds",
+    "wall seconds of computing column roll-ups, each with its fetch",
+    ("phase",))
+
 # DKV (utils/registry.py)
 DKV_PUTS = METRICS.counter("h2o3_dkv_puts", "DKV puts")
 DKV_GETS = METRICS.counter("h2o3_dkv_gets", "DKV gets")
@@ -648,6 +661,20 @@ RECOMPILES = METRICS.counter(
     "h2o3_recompiles",
     "signature changes (a site compiling a 2nd+ distinct signature)",
     ("site",))
+# what a first call pays (utils/compile_cache.py's listeners on JAX's own
+# compile spans): seconds of tracing, lowering and the backend's
+# compile-or-load (a span inside another is the outer one's), and the
+# executables requested, by the program's phase
+# (the innermost open ``timed_event``; a dozen values, never a function
+# name). A steady build moves neither.
+FIRST_CALL_SECONDS = METRICS.counter(
+    "h2o3_first_call_seconds",
+    "seconds of tracing, lowering and backend compile-or-load of first calls",
+    ("phase", "stage"))
+EXECUTABLES = METRICS.counter(
+    "h2o3_executables",
+    "executables requested of the backend: loaded from the persistent "
+    "cache or compiled", ("phase", "source"))
 ACHIEVED_FLOPS = METRICS.gauge(
     "h2o3_achieved_flops_per_sec",
     "achieved FLOP/s of a loop's compiled program (cost_analysis FLOPs / "

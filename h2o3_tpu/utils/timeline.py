@@ -72,6 +72,15 @@ class TimeLine:
 
 TIMELINE = TimeLine()
 
+#: the innermost open ``timed_event``'s name on this thread: the phase under
+#: which ``utils/compile_cache.py`` books what a first call pays (a
+#: ``TRACER`` span cannot be asked: there is none when ``builder.train()`` is
+#: called directly)
+PHASE: contextvars.ContextVar["str | None"] = \
+    contextvars.ContextVar("h2o3_phase", default=None)
+#: the phase label of what runs under no ``timed_event``
+OUTSIDE = "(outside a build)"
+
 
 class timed_event:
     """Context manager recording a timed event into the global timeline.
@@ -96,6 +105,7 @@ class timed_event:
         self._span = self._scope.__enter__()
         self._ann = (_tracing.annotation(self.what)
                      if self._span is None else None)
+        self._phase = PHASE.set(self.what)
         if self.kind == "model":
             # device-byte attribution at build granularity (two full samples
             # per fit — never per iteration, where the live-array fallback
@@ -110,6 +120,7 @@ class timed_event:
 
     def __exit__(self, *exc):
         dur_ns = time.time_ns() - self._t0
+        PHASE.reset(self._phase)
         TIMELINE.record(self.kind, self.what, dur_ns)
         if self._observe is not None:
             self._observe.observe(dur_ns / 1e9)

@@ -718,12 +718,13 @@ def test_builder_phases_land_in_a_profiler_session_the_test_opened(tmp_path):
                 if e.name.startswith("gbm:"):
                     starts.setdefault(e.name, []).append(e.start_ns)
     assert {k: len(v) for k, v in starts.items()} == {
-        "gbm:fit": 2, "gbm:prepare.edges": 2, "gbm:prepare.bin": 2,
-        "gbm:chunk": 2, "gbm:metrics": 2}
-    for fit, edges, bins, chunk, metrics in zip(*(sorted(starts[k]) for k in (
-            "gbm:fit", "gbm:prepare.edges", "gbm:prepare.bin", "gbm:chunk",
-            "gbm:metrics"))):
-        assert fit < edges < bins < chunk < metrics
+        "gbm:train": 2, "gbm:fit": 2, "gbm:prepare.edges": 2,
+        "gbm:prepare.bin": 2, "gbm:chunk": 2, "gbm:metrics": 2}
+    for train, fit, edges, bins, chunk, metrics in zip(*(
+            sorted(starts[k]) for k in (
+                "gbm:train", "gbm:fit", "gbm:prepare.edges",
+                "gbm:prepare.bin", "gbm:chunk", "gbm:metrics"))):
+        assert train < fit < edges < bins < chunk < metrics
 
 
 def test_boost_program_parts_are_named_in_the_compiled_module():
