@@ -62,7 +62,8 @@ class DecisionTree(SharedTreeBuilder):
         key = jax.random.PRNGKey(int(p.get("seed") or 0) or 5)
         trees, _ = grow_trees_batched(binned, edges, g[None], h[None], w[None],
                                       tp, jnp.ones(binned.shape[1], bool),
-                                      key=key, cat_feats=self._cat_feats)
+                                      key=key, cat_feats=self._cat_feats,
+                                      bins_used=self._bins_used)
         job.update(1.0, "tree grown")
 
         return DecisionTreeModel(

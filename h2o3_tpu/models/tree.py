@@ -149,7 +149,7 @@ def _level_histograms_fused(binned, node_local, g, h, w, n_nodes: int,
 
 
 def _histograms(binned, binned_T, node_local, g, h, w, n_nodes: int,
-                n_bins_tot: int, mesh=None):
+                n_bins_tot: int, mesh=None, bins_used=None):
     """Dispatch on :func:`hist_mesh`'s answer. A mesh: one fused-collective
     shard_map reduction over it (``fused_scatter``). ``None`` — the operand
     is on one device: the Pallas MXU kernel inside its envelope
@@ -157,7 +157,10 @@ def _histograms(binned, binned_T, node_local, g, h, w, n_nodes: int,
     :data:`UNFUSED` — segment_sum under implicit SPMD, whose collectives
     XLA inserts: the kernel is single-device, and over a sharded global
     array it would skip the per-level ``psum`` (each shard's partial
-    histogram would be treated as the total)."""
+    histogram would be treated as the total). ``bins_used`` (static: the
+    bins each column can hold, ``hist_pallas``'s contract) spares the kernel
+    the one-hot rows no bin id can match; the segment sums cost the same
+    whatever the ids and take no notice."""
     from h2o3_tpu.ops.pallas_hist import hist_pallas, pallas_available
     if mesh is not None and mesh is not UNFUSED:
         HIST_PATHS["fused_scatter"] += 1
@@ -166,7 +169,8 @@ def _histograms(binned, binned_T, node_local, g, h, w, n_nodes: int,
     if pallas_available(n_nodes, binned.shape[1], n_bins_tot,
                         one_device=mesh is None):
         HIST_PATHS["pallas"] += 1
-        return hist_pallas(binned_T, node_local, g, h, w, n_nodes, n_bins_tot)
+        return hist_pallas(binned_T, node_local, g, h, w, n_nodes, n_bins_tot,
+                           bins_used=bins_used)
     HIST_PATHS["scatter"] += 1
     return _level_histograms(binned, node_local, g, h, w, n_nodes, n_bins_tot)
 
@@ -441,7 +445,8 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
                       depth: int, n_bins: int, min_rows, reg_lambda, reg_alpha,
                       gamma, min_split_improvement, col_rate: float,
                       do_col_sample: bool | None = None,
-                      mono=None, reach=None, cat_feats=None, mesh=None):
+                      mono=None, reach=None, cat_feats=None, mesh=None,
+                      bins_used=None):
     """Grow one whole tree on device; the level loop unrolls at trace time.
 
     Returns heap arrays + per-row training predictions (leaf of each row).
@@ -482,7 +487,8 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
             with jax.named_scope("hist"):
                 if d == 0:
                     hists = _histograms(binned, binned_T, node_local, g, h,
-                                        w, N, Bt, mesh=mesh)
+                                        w, N, Bt, mesh=mesh,
+                                        bins_used=bins_used)
                 else:
                     P = N // 2
                     # chosen child id per parent; rows elsewhere mask to -1
@@ -493,7 +499,7 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
                     at_chosen = act & (node_local == chosen[par])
                     node_slot = jnp.where(at_chosen, par, -1)
                     part = _histograms(binned, binned_T, node_slot, g, h, w,
-                                       P, Bt, mesh=mesh)
+                                       P, Bt, mesh=mesh, bins_used=bins_used)
                     part4 = part.reshape(F, P, Bt, 3)
                     prev4 = prev_hists.reshape(F, P, Bt, 3)
                     # sibling by subtraction — only where the parent really
@@ -600,24 +606,27 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
 @accounted_jit("gbm:grow_batched", loop="gbm_chunk",
                static_argnames=("depth", "n_bins", "col_rate", "min_rows",
                                 "reg_lambda", "reg_alpha", "gamma",
-                                "min_split_improvement", "mesh"))
+                                "min_split_improvement", "mesh",
+                                "bins_used"))
 def _grow_batched(binned, edges, g, h, w, feat_mask, keys,
                   depth: int, n_bins: int, min_rows, reg_lambda, reg_alpha,
                   gamma, min_split_improvement, col_rate: float,
-                  mono=None, reach=None, cat_feats=None, mesh=None):
+                  mono=None, reach=None, cat_feats=None, mesh=None,
+                  bins_used=None):
     """K trees in ONE dispatch: vmap over the stats axis (class trees of a
     multinomial round, or K=1). binned/edges are shared (in_axes=None)."""
     binned_T = binned.T   # once per round; the Pallas kernel wants [F, rows]
     fn = lambda gk, hk, wk, mk, kk: _grow_tree_device(
         binned, binned_T, edges, gk, hk, wk, mk, kk, depth, n_bins, min_rows,
         reg_lambda, reg_alpha, gamma, min_split_improvement, col_rate,
-        mono=mono, reach=reach, cat_feats=cat_feats, mesh=mesh)
+        mono=mono, reach=reach, cat_feats=cat_feats, mesh=mesh,
+        bins_used=bins_used)
     return jax.vmap(fn)(g, h, w, feat_mask, keys)
 
 
 def grow_trees_batched(binned, edges, g, h, w, params: TreeParams, feat_mask,
                        col_rate: float = 1.0, key: jax.Array | None = None,
-                       mono=None, reach=None, cat_feats=None
+                       mono=None, reach=None, cat_feats=None, bins_used=None
                        ) -> tuple[list[Tree], jax.Array]:
     """Grow K trees (leading axis of g/h/w) in one compiled program.
 
@@ -642,7 +651,7 @@ def grow_trees_batched(binned, edges, g, h, w, params: TreeParams, feat_mask,
         float(params.reg_lambda), float(params.reg_alpha),
         float(params.gamma), float(params.min_split_improvement),
         float(col_rate), mono=mono, reach=reach, cat_feats=cat_feats,
-        mesh=hist_mesh(binned))
+        mesh=hist_mesh(binned), bins_used=bins_used)
     hf, ht, htv, hna, hsp, hlf, hg, hc = out[:8]
     hm = out[8] if cat_feats is not None else None
     preds = out[-1]

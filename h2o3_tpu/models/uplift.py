@@ -169,7 +169,7 @@ class UpliftDRF(SharedTreeBuilder):
             grown, _ = grow_trees_batched(
                 binned, edges, jnp.stack(gs), jnp.stack(hs), jnp.stack(ws),
                 tp, jnp.ones(binned.shape[1], bool), col_rate, keys[-1],
-                cat_feats=self._cat_feats)
+                cat_feats=self._cat_feats, bins_used=self._bins_used)
             trees.extend(grown)
             job.update((s + k) / ntrees, f"{s + k}/{ntrees} trees")
 

@@ -242,7 +242,8 @@ class XGBoost(GBM):
             new, pred = grow_trees_batched(
                 binned, edges, g[None], h[None], wt[None], tp, tmask,
                 col_rate=self._effective_col_rate(), key=kt,
-                mono=mono, reach=reach, cat_feats=self._cat_feats)
+                mono=mono, reach=reach, cat_feats=self._cat_feats,
+                bins_used=self._bins_used)
             pred = pred[0]
             if k:
                 # renormalize (XGBoost DART): tree: new w = lr/(k+lr),

@@ -24,35 +24,52 @@ scatter path; see ``_MAX_NODE_BLOCKS`` and ROOFLINE.md). ``_plan`` picks the
 blocks and the row tile from the shapes alone: the body is straight-line
 code, so the tile is as long as ``_STEP_MATMULS`` MXU instructions and
 ``_VMEM_BUDGET`` allow (4,096 rows at 28 features × 64 bins, 1,024 at 256
-bins).
+bins), by the one-hot rows the block really streams.
 
 The MXU streams the one-hot's rows (one a cycle and MXU) past the latched
-statistics, so a call costs R/128 · F · S row-cycles a PASS whatever the
-node count — and the bf16 digits of the statistics (``_MXU_MODE``) need not
-be passes: while ``digits · Nb·3`` columns fit the MXU's 128 lanes they sit
-side by side in ONE right-hand side ``[hi | lo]`` and the halves of the
-product are added afterwards — the same products and float32 sums as a pass
-a digit, at half (a third) of the rows streamed. Wider node blocks keep a
-pass a digit. FLOP cost is R·F·2·S·3·N MACs and doubles per level — the MXU
-wins while the arithmetic stays under the scatter path's serialization, not
-asymptotically.
+statistics, so a call costs R/128 · (one-hot rows a row) · passes row-cycles
+whatever the node count. Three things set that product:
+
+- The one-hot rows a row are the bins a feature CAN hold, not the engine's
+  one bin count: a call told so (``bins_used``, static: a frame with a
+  300-level column runs 301 bins on its 7-level and its numeric columns too)
+  builds, streams and accumulates, feature by feature, only the 8-row groups
+  ``[0, ceil8(used_f))`` and the group of the missing bin ``[S − 8, S)``;
+  every other row of the feature's stride ``S`` keeps the 0.0 the slab was
+  cleared to. Dense, ``F · S``, is the case in which every feature holds
+  ``n_bins_tot``. On the v5e, 20M rows × 8 columns of 12 to 300 bins at 301
+  engine bins: 944 one-hot rows a row of 2,432, 0.0266–0.0336 s a pass where
+  dense takes 0.0661–0.0765 (PERF.md, PR 36); rounding the groups to the
+  bf16 tile's 16 rows instead costs 7% (1,024 rows) and buys nothing, the
+  stacked one-hot is cast whole.
+- The bf16 digits of the statistics (``_MXU_MODE``) need not be passes:
+  while ``digits · Nb·3`` columns fit the MXU's 128 lanes they sit side by
+  side in ONE right-hand side ``[hi | lo]`` and the halves of the product
+  are added afterwards — the same products and float32 sums as a pass a
+  digit, at half (a third) of the rows streamed. Wider node blocks keep a
+  pass a digit.
+- FLOP cost is R·Σrows·2·3·N MACs and doubles per level — the MXU wins while
+  the arithmetic stays under the scatter path's serialization, not
+  asymptotically.
 
 Layout notes (Mosaic constraints): the bin one-hot is built TRANSPOSED
 ([S, T], bins on sublanes) because dynamic lane indexing is unsupported;
 binned is passed pre-transposed [F, R] so a step's block is ``Fb`` contiguous
 row runs; the per-feature output offset uses an 8-aligned padded bin
-stride S.
+stride S, and a feature's one-hot pieces are stacked at 8-row offsets (the
+tile of the 32-bit compares they are made of) before the one cast.
 """
 
 from __future__ import annotations
 
 import os
-from functools import partial
+from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
 
-from h2o3_tpu.utils.telemetry import HIST_GRID_STEPS, HIST_KERNEL_LEVELS
+from h2o3_tpu.utils.telemetry import (HIST_GRID_STEPS, HIST_KERNEL_LEVELS,
+                                      HIST_ONEHOT_ROWS)
 
 #: MXU precision mode for the one-hot contraction. The one-hot operand is
 #: EXACTLY representable in bf16 (entries 0/1), so only the stats operand
@@ -95,6 +112,10 @@ _STEP_MATMULS = 4096
 #: over these), few enough that the stacked one-hot stays small where the
 #: compiler materialises it
 _GROUP_ROWS = 512
+#: one-hot rows are built, streamed and skipped in groups of this many: the
+#: sublane tile of the float32 output slab and of the 32-bit compares the
+#: one-hot is made of, so a group starts on a tile of both
+_ROW_GROUP = 8
 
 
 def _ceil_to(n: int, m: int) -> int:
@@ -122,19 +143,82 @@ def _passes(Nb: int) -> int:
     return (6 if _MXU_MODE == "highest" else _digits()) * -(-Nb * 3 // _LANES)
 
 
-def _group(S: int, Fb: int) -> int:
-    """Features whose one-hots are stacked to one left-hand side."""
-    return min(Fb, -(-_GROUP_ROWS // S))
+def _first_rows(S: int, used: int) -> int:
+    """One-hot rows of a feature's FIRST range ``[0, ceil8(used))``, of the
+    ``S`` its stride holds; ``S`` itself where that range and the group of the
+    missing bin, ``[S - 8, S)``, leave nothing between them to skip."""
+    u = _ceil_to(used, _ROW_GROUP)
+    return S if u + _ROW_GROUP >= S else u
 
 
-def _vmem_bytes(Nb: int, Fb: int, T: int, S: int, out_blocks: int = 1) -> int:
+def _block_first_rows(S: int, Fb: int, n_feat: int, bins_used) -> tuple:
+    """:func:`_first_rows` of each position of a feature block: all that a
+    call's plan, body and counters know of ``bins_used``, so a tuple that
+    skips nothing IS the call without one (``(S,) * Fb``), to the last
+    instruction. The body is one code for every feature block of a call,
+    so a position streams the most that any block's feature needs there;
+    the features padded onto the last block read the last feature's bins."""
+    if bins_used is None:
+        return (S,) * Fb
+    if len(bins_used) != n_feat:
+        raise ValueError(f"bins_used names {len(bins_used)} features, "
+                         f"the frame has {n_feat}")
+    padf = -(-n_feat // Fb) * Fb - n_feat
+    used = tuple(bins_used) + (bins_used[-1],) * padf
+    return tuple(_first_rows(S, max(used[j::Fb])) for j in range(Fb))
+
+
+def _streamed(S: int, first: tuple) -> list[int]:
+    """One-hot rows a position streams: its first range and, apart from it,
+    the missing bin's group."""
+    return [u if u == S else u + _ROW_GROUP for u in first]
+
+
+def _groups(rows: list[int]) -> list[tuple[int, int]]:
+    """Consecutive features ``[f0, f1)`` whose one-hots are stacked to one
+    left-hand side: a group closes at ``_GROUP_ROWS`` rows."""
+    groups, f0, n = [], 0, 0
+    for f, r in enumerate(rows):
+        n += r
+        if n >= _GROUP_ROWS:
+            groups.append((f0, f + 1))
+            f0, n = f + 1, 0
+    if f0 < len(rows):
+        groups.append((f0, len(rows)))
+    return groups
+
+
+def _runs(S: int, first: tuple, f0: int, f1: int) -> list[tuple[int, int, int]]:
+    """Where the rows of group ``[f0, f1)``'s product go in the output slab:
+    ``(row of the product, row of the slab, rows)``, one a stretch that is
+    contiguous in both (the product's rows follow each other) — a feature's
+    missing-bin group and the next feature's first range are one."""
+    runs, a = [], 0
+    for f in range(f0, f1):
+        u = first[f]
+        for o, n in ([(f * S, S)] if u == S
+                     else [(f * S, u), (f * S + S - _ROW_GROUP, _ROW_GROUP)]):
+            if runs and runs[-1][1] + runs[-1][2] == o:     # the slab's too
+                runs[-1] = (*runs[-1][:2], runs[-1][2] + n)
+            else:
+                runs.append((a, o, n))
+            a += n
+    return runs
+
+
+def _vmem_bytes(Nb: int, Fb: int, T: int, S: int, out_blocks: int = 1,
+                rows: list[int] | None = None) -> int:
     """What a grid step holds in VMEM, by the padded shapes Mosaic gives
     them ((8, 128) tiles of 32-bit words, 16 sublanes of bf16, 32 of int8;
     bins counted at two bytes, their wider storage). The output slab is
     resident; where the call has more than one (``out_blocks``: node blocks
-    x feature blocks) the pipeline holds the next one's buffer too."""
+    x feature blocks) the pipeline holds the next one's buffer too. ``rows``:
+    the one-hot rows each feature of the block streams (``S`` each without
+    it); the stacked one-hot and its product are the largest group's."""
     k3 = Nb * 3
-    digits, G = _digits(), _group(S, Fb)
+    digits = _digits()
+    rows = rows or [S] * Fb
+    stacked = max(sum(rows[f0:f1]) for f0, f1 in _groups(rows))
     item = 4 if _MXU_MODE == "highest" else 2       # the MXU's operands
     cols = digits * k3 if _packed(Nb) else k3       # of one product
     inputs = 2 * T * (_ceil_to(Fb, 32) * 2 + 8 * 4 + 8 * 4)  # double-buffered
@@ -143,17 +227,19 @@ def _vmem_bytes(Nb: int, Fb: int, T: int, S: int, out_blocks: int = 1) -> int:
     if _packed(Nb):                                 # side by side, via f32
         rhs += _ceil_to(cols, 8) * T * 4 + _ceil_to(cols, 16) * T * item
     bins = _ceil_to(Fb, 8) * T * 4                  # upcast to int32
-    onehot = G * S * T * item
-    acc = G * S * _ceil_to(cols, _LANES) * 4
+    onehot = stacked * T * item
+    acc = stacked * _ceil_to(cols, _LANES) * 4
     out = Fb * S * _ceil_to(k3, _LANES) * 4 * min(out_blocks, 2)
     return inputs + ns + rhs + bins + onehot + acc + out
 
 
-def _plan(n_nodes: int, n_feat: int, n_bins_tot: int):
+def _plan(n_nodes: int, n_feat: int, n_bins_tot: int, bins_used=None):
     """(node_block, feat_block, row_tile), or None if out of envelope. The
     feature block is the whole frame where that fits, else a multiple of 32
     (a sublane tile of every bin storage); the row tile is the longest
-    multiple of 128 that ``_STEP_MATMULS`` and ``_VMEM_BUDGET`` allow."""
+    multiple of 128 that ``_STEP_MATMULS`` and ``_VMEM_BUDGET`` allow, by
+    the one-hot rows the block really streams (``bins_used``, as
+    :func:`hist_pallas` takes it): the fewer, the longer."""
     S = _ceil_to(n_bins_tot, 8)
     Nb = min(n_nodes, _NODE_BLOCK)
     n_gb = -(-n_nodes // Nb)
@@ -161,12 +247,14 @@ def _plan(n_nodes: int, n_feat: int, n_bins_tot: int):
         return None
     for Fb in [n_feat, *range((n_feat - 1) // 32 * 32, 0, -32)]:
         blocks = n_gb * -(-n_feat // Fb)
-        per_128_rows = -(-Fb * S // 16) * _passes(Nb)
+        rows = _streamed(S, _block_first_rows(S, Fb, n_feat, bins_used))
+        per_128_rows = -(-sum(rows) // 16) * _passes(Nb)
         T = _LANES * max(1, min(_TILE_MAX // _LANES,
                                 _STEP_MATMULS // per_128_rows))
-        while T > _LANES and _vmem_bytes(Nb, Fb, T, S, blocks) > _VMEM_BUDGET:
+        while (T > _LANES
+               and _vmem_bytes(Nb, Fb, T, S, blocks, rows) > _VMEM_BUDGET):
             T -= _LANES
-        if _vmem_bytes(Nb, Fb, T, S, blocks) <= _VMEM_BUDGET:
+        if _vmem_bytes(Nb, Fb, T, S, blocks, rows) <= _VMEM_BUDGET:
             return Nb, Fb, T
     return None
 
@@ -185,7 +273,7 @@ def pallas_available(n_nodes: int, n_feat: int, n_bins_tot: int,
     return _plan(n_nodes, n_feat, n_bins_tot) is not None
 
 
-def _hist_kernel(b_ref, n_ref, s_ref, out_ref, *, Nb, S, Fb):
+def _hist_kernel(b_ref, n_ref, s_ref, out_ref, *, Nb, S, first):
     import jax.experimental.pallas as pl
 
     gb = pl.program_id(0)      # node block
@@ -223,11 +311,19 @@ def _hist_kernel(b_ref, n_ref, s_ref, out_ref, *, Nb, S, Fb):
     # i8/i16 in HBM (gbm._bin_frame packs <=125-bin configs to int8);
     # upcast per tile
     bins = b_ref[:].astype(jnp.int32)                              # [Fb, T]
-    iota_r = jax.lax.broadcasted_iota(jnp.int32, (S, 1), 0)
-    G = _group(S, Fb)
-    for f0 in range(0, Fb, G):
-        f1 = min(f0 + G, Fb)
-        oh = jnp.concatenate([iota_r == bins[f:f + 1, :]
+
+    @cache          # one column of bin ids a range, whatever features share it
+    def bin_ids(u):
+        """The bin of each one-hot row a feature of first range ``u``
+        streams: ``[0, u)``, then the missing bin's group, the stride's last."""
+        if u == S:
+            return jax.lax.broadcasted_iota(jnp.int32, (S, 1), 0)
+        r = jax.lax.broadcasted_iota(jnp.int32, (u + _ROW_GROUP, 1), 0)
+        return jnp.where(r < u, r, r + (S - _ROW_GROUP - u))
+
+    rows = _streamed(S, first)
+    for f0, f1 in _groups(rows):
+        oh = jnp.concatenate([bin_ids(first[f]) == bins[f:f + 1, :]
                               for f in range(f0, f1)], 0).astype(oh_dtype)
         parts = [jax.lax.dot_general(oh, m, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32,
@@ -237,22 +333,37 @@ def _hist_kernel(b_ref, n_ref, s_ref, out_ref, *, Nb, S, Fb):
         acc = parts[0]
         for part in parts[1:]:          # hi + lo (+ lo2), as a pass a digit
             acc = acc + part
-        out_ref[0, 0, f0 * S:f1 * S, :] += acc
+        # each run of the product's rows to where its bins sit in the slab
+        # (stride S a feature); the rows in between keep their zeros. A
+        # dense group is one run, the whole product
+        for a, o, n in _runs(S, first, f0, f1):
+            out_ref[0, 0, o:o + n, :] += (acc if n == acc.shape[0]
+                                          else acc[a:a + n])
 
 
-@partial(jax.jit, static_argnames=("n_nodes", "n_bins_tot"))
-def hist_pallas(binned_T, node, g, h, w, n_nodes: int, n_bins_tot: int):
-    """[F, n_nodes*n_bins_tot, 3] histograms (same layout as the XLA path)."""
+@partial(jax.jit, static_argnames=("n_nodes", "n_bins_tot", "bins_used"))
+def hist_pallas(binned_T, node, g, h, w, n_nodes: int, n_bins_tot: int,
+                bins_used=None):
+    """[F, n_nodes*n_bins_tot, 3] histograms (same layout as the XLA path).
+
+    ``bins_used``: a static tuple of ``F`` ints, the bins each feature can
+    hold; its one-hot rows past them are neither built nor streamed, and
+    their histogram bins read exactly 0.0. THE CALLER'S PART: a bin id of
+    feature ``f`` lies in ``[0, bins_used[f])`` or is the missing bin
+    ``n_bins_tot - 1``; a row whose id does not is dropped from that
+    feature's histogram without a word. ``None``: every feature holds
+    ``n_bins_tot``; a tuple that skips nothing is the same call."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     N, Bt = n_nodes, n_bins_tot
     F, R = binned_T.shape
     S = _ceil_to(Bt, 8)
-    Nb, Fb, T = _plan(N, F, Bt)
+    Nb, Fb, T = _plan(N, F, Bt, bins_used)
     T = min(T, _ceil_to(R, _LANES))     # a frame shorter than the tile
     n_gb = -(-N // Nb)
     n_fb = -(-F // Fb)
+    first = _block_first_rows(S, Fb, F, bins_used)
     padf = n_fb * Fb - F
     if padf:
         # feature padding: rows read a duplicate of the last feature; the
@@ -271,12 +382,14 @@ def hist_pallas(binned_T, node, g, h, w, n_nodes: int, n_bins_tot: int):
     HIST_KERNEL_LEVELS.labels(
         contraction="packed" if _packed(Nb) else "passes").inc()
     HIST_GRID_STEPS.inc(n_gb * n_fb * (Rp // T))
+    HIST_ONEHOT_ROWS.labels(kind="streamed").inc(n_fb * sum(_streamed(S, first)))
+    HIST_ONEHOT_ROWS.labels(kind="dense").inc(n_fb * Fb * S)
     act = node >= 0
     # stats-major [3, R] / [1, R]: see layout note in the kernel
     ghw_T = jnp.stack([g, h, w], 0) * act[None, :].astype(jnp.float32)
     nodec = jnp.where(act, node, -1)[None, :]
     out = pl.pallas_call(
-        partial(_hist_kernel, Nb=Nb, S=S, Fb=Fb),
+        partial(_hist_kernel, Nb=Nb, S=S, first=first),
         interpret=_INTERPRET,
         out_shape=jax.ShapeDtypeStruct((n_gb, n_fb, Fb * S, Nb * 3),
                                        jnp.float32),

@@ -581,6 +581,13 @@ HIST_KERNEL_LEVELS = METRICS.counter(
     ("contraction",))
 HIST_GRID_STEPS = METRICS.counter(
     "h2o3_hist_grid_steps", "grid steps of the histogram kernel calls traced")
+# the one-hot rows a row of the frame costs those calls: ``streamed``, what a
+# call builds and sends through the MXU for the bins its features can hold
+# (``bins_used``); ``dense``, the whole 8-aligned stride of every feature
+HIST_ONEHOT_ROWS = METRICS.counter(
+    "h2o3_hist_onehot_rows",
+    "one-hot rows a frame row costs the histogram kernel calls traced",
+    ("kind",))
 
 # the binomial metrics' 400-bucket score histogram (models/metrics.py
 # ``_binomial_pass``): one increment where the pass is TRACED (a cached

@@ -25,7 +25,8 @@ from h2o3_tpu.models import tree
 from h2o3_tpu.models.gbm import DRF, GBM, tree_matrix
 from h2o3_tpu.ops import pallas_hist
 from h2o3_tpu.ops.quantile import bin_dtype
-from h2o3_tpu.utils.telemetry import ROUTE_LEVELS, SPLIT_LEVELS
+from h2o3_tpu.utils.telemetry import (HIST_ONEHOT_ROWS, ROUTE_LEVELS,
+                                      SPLIT_LEVELS)
 
 LEVELS = 40
 DOMAIN = tuple(f"L{j:02d}" for j in range(LEVELS))
@@ -108,6 +109,7 @@ def test_builders_inherit_the_bin_count(name):
         b.params["ntrees"] = 3
     model = b.train(y="y", training_frame=frame)
     assert b._n_bins == LEVELS
+    assert b._bins_used == (LEVELS, 5, 16)   # the kernel's part of the schema
     assert model.output["trees"][0].left_mask.shape[1] == LEVELS
     assert model.training_metrics.auc > 0.6
     p = model.predict(frame).vecs[-1].to_numpy()[: frame.nrows]
@@ -267,6 +269,120 @@ def test_threshold_levels_are_counted_apart():
     tree._find_splits(jnp.asarray(hist.reshape(F, N * Bt, 3)), Bt - 1, 10.0,
                       0.0, 0.0, 0.0, jnp.ones(F, bool))
     assert SPLIT_LEVELS.labels(kind="threshold").value == before + 1
+
+
+# --- what each column can hold: the histogram kernel's ``bins_used`` ----------
+
+def _wide_frame(rows=2500, seed=5, na=0.03):
+    """Categorical columns of 7 and 300 levels and a numeric one, with
+    missing values in all three and the numeric one's infinities."""
+    rng = np.random.default_rng(seed)
+    a = rng.integers(0, 7, rows).astype(np.float64)
+    b = rng.integers(0, 300, rows).astype(np.float64)
+    x = rng.normal(size=rows)
+    y = (rng.random(rows) < 1 / (1 + np.exp(-(0.4 * (a == 3) + 0.01 * (b % 7)
+                                              + x)))).astype(np.int32)
+    x[:5], x[5:8] = np.inf, -np.inf
+    for col in (a, b, x):
+        col[rng.random(rows) < na] = np.nan
+
+    def cat(codes, levels):
+        return Vec.from_numpy(np.where(np.isnan(codes), -1, codes).astype(
+            np.int32), type=VecType.CAT,
+            domain=tuple(f"v{j:03d}" for j in range(levels)))
+    return Frame(["a", "b", "x", "y"],
+                 [cat(a, 7), cat(b, 300), Vec.from_numpy(x.astype(np.float32)),
+                  Vec.from_numpy(y, type=VecType.CAT, domain=("N", "Y"))])
+
+
+def _inside(binned, frame, used, n_bins):
+    got = np.asarray(binned)[: frame.nrows]
+    for j, (col, u) in enumerate(zip(("a", "b", "x"), used)):
+        missing = np.isnan(np.asarray(frame.vec(col).as_float())[: frame.nrows])
+        assert missing.any() and (got[missing, j] == n_bins).all()
+        assert 0 <= got[~missing, j].min() and got[~missing, j].max() == u - 1
+
+
+def test_every_bin_lies_inside_what_the_schema_declares():
+    """``bins_used`` from the cardinalities alone, and ``_bin_frame`` and
+    ``_apply_cat_bins`` inside it on every row: what the histogram kernel
+    is told it may skip. An infinite value of the numeric column would
+    count the edges' ``inf`` padding in (bin 299): it bins with the largest
+    finite ones (``_binning_edges``), and the model's edges keep their
+    ``inf``."""
+    frame = _wide_frame()
+    b = GBM(ntrees=1, max_depth=2, nbins=16, seed=2)
+    _, edges, binned, *_ = b._prepare(frame, ["a", "b", "x"], "y")
+    assert b._n_bins == 300 and edges.shape == (3, 299)
+    assert b._bins_used == (7, 300, 16)
+    _inside(binned, frame, b._bins_used, 300)
+    x = np.asarray(frame.vec("x").as_float())[: frame.nrows]
+    got = np.asarray(binned)[: frame.nrows, 2]
+    assert (got[x == np.inf] == 15).all() and (got[x == -np.inf] == 0).all()
+    assert np.isinf(np.asarray(edges)[2, 15:]).all()
+    assert np.isnan(np.asarray(b._binning_edges(edges))[:, 15:]).all()
+    # a validation frame: bin_features, then _apply_cat_bins
+    valid = _wide_frame(rows=1200, seed=6)
+    Xv = tree_matrix(valid, ["a", "b", "x"],
+                     {c: frame.vec(c).domain for c in "ab"})
+    from h2o3_tpu.ops.quantile import bin_features
+    _inside(b._apply_cat_bins(Xv, bin_features(Xv, b._binning_edges(edges))),
+            valid, b._bins_used, 300)
+    # all columns alike, or none categorical: nothing to tell the kernel
+    b._setup_cat_info(frame, ["x"])
+    assert b._bins_used is None and b._n_bins == 16
+    b.params["nbins"] = 300
+    b._setup_cat_info(frame, ["b", "x"])
+    assert b._bins_used is None and b._n_bins == 300
+
+
+def test_a_build_with_bins_used_equals_the_dense_build(monkeypatch):
+    """The whole boost program on one device, two trees of depth 4: the same
+    trees and margins, array for array, whether the kernel skips the one-hot
+    rows the schema rules out or streams them all (interpret mode; the row
+    tile pinned, so that both sum the same rows in one accumulation)."""
+    from h2o3_tpu.models.gbm import _boost_scan
+    monkeypatch.setattr(pallas_hist, "_INTERPRET", True)
+    monkeypatch.setattr(pallas_hist, "_TILE_MAX", 256)
+    frame = _wide_frame()
+    b = GBM(ntrees=2, max_depth=4, nbins=16, seed=1)
+    _, edges, binned, yy, valid, *_ = b._prepare(frame, ["a", "b", "x"], "y")
+    assert b._bins_used == (7, 300, 16)
+    # a frame's arrays span the test mesh; the kernel's operands sit on one
+    # device, as a one-chip build's do
+    one = lambda a: jnp.asarray(np.asarray(a))
+    binned, w = one(binned), one(valid).astype(jnp.float32)
+    yc = jnp.where(w > 0, one(yy), 0.0)
+    keys = jax.random.split(jax.random.PRNGKey(1), 6).reshape(2, 3, 2)
+    rows = HIST_ONEHOT_ROWS.labels(kind="streamed")
+    whole = HIST_ONEHOT_ROWS.labels(kind="dense")
+
+    def build(bins_used):
+        jax.clear_caches()          # the counters move where a call is traced
+        tree.HIST_PATHS.clear()
+        before = rows.value, whole.value
+        Fend, heap, _, _ = _boost_scan(
+            binned, one(edges), yc, w, jnp.ones(3, bool),
+            jnp.zeros(binned.shape[0], jnp.float32), keys, dist="bernoulli",
+            depth=4, n_bins=b._n_bins, col_rate=1.0, sample_rate=1.0,
+            col_tree_rate=1.0, min_rows=5.0, reg_lambda=0.0, reg_alpha=0.0,
+            gamma=0.0, min_split_improvement=1e-5, lr=0.3, bootstrap=False,
+            drf=False, nclass=0, cat_feats=one(b._cat_feats),
+            bins_used=bins_used)
+        assert tree.HIST_PATHS == {"pallas": 5}
+        return ([np.asarray(a) for a in (*heap, Fend)],
+                rows.value - before[0], whole.value - before[1])
+
+    # a traced level call streams 16 + 304 + 24 one-hot rows a row of 3 x 304
+    # (jit traces the 1-, 2- and 4-slot call once each), the totals' call 16
+    sparse, streamed, of = build(b._bins_used)
+    assert (streamed, of) == (3 * 344 + 16, 3 * 912 + 16)
+    dense, streamed, of = build(None)
+    assert (streamed, of) == (3 * 912 + 16, 3 * 912 + 16)
+    assert len(sparse) == 10 and sparse[4].sum() > 8        # is_split
+    for got, want in zip(sparse, dense, strict=True):
+        np.testing.assert_array_equal(got, want)
+    jax.clear_caches()
 
 
 # --- the cell's kernel and route shapes -------------------------------------

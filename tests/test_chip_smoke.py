@@ -139,15 +139,23 @@ def test_binomial_pass_compiles_for_v5e_as_a_matrix_product(monkeypatch, rows):
     assert exe.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
-@pytest.mark.parametrize("n_nodes, contraction, blocks", [
-    (16, "packed", 1), (32, "passes", 1), (64, "passes", 1),
-    (256, "passes", 4)])
+#: what the cell's eight columns can hold (``GBM._bins_used``)
+_CELL_USED = (12, 31, 7, 22, 300, 300, 100, 100)
+
+
+@pytest.mark.parametrize("n_nodes, contraction, blocks, rows, bins_used", [
+    (16, "packed", 1, 10_000_000, None), (32, "passes", 1, 10_000_000, None),
+    (64, "passes", 1, 10_000_000, None), (256, "passes", 4, 10_000_000, None),
+    (16, "packed", 1, 20_000_000, _CELL_USED),
+    (64, "passes", 1, 20_000_000, _CELL_USED)])
 def test_hist_kernel_compiles_at_the_categorical_cells_shapes(
-        monkeypatch, n_nodes, contraction, blocks):
-    """``gbm100-airline-cat-build``: 10M rows x 8 features x 301 bins
-    (int16), up to 256 parent slots: digits packed up to 21 slots, a pass a
-    digit past that, four node blocks at the deepest level. Compiled for
-    the v5e with no chip attached."""
+        monkeypatch, n_nodes, contraction, blocks, rows, bins_used):
+    """``gbm100-airline-cat-build``: 8 features x 301 bins (int16), up to 256
+    parent slots: digits packed up to 21 slots, a pass a digit past that,
+    four node blocks at the deepest level; and, at the cell's 20M rows, the
+    call told what each column can hold: one-hot pieces of 16 to 304 rows
+    stacked at 8-row offsets, longer row tiles. Compiled for the v5e with no
+    chip attached."""
     monkeypatch.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
     monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
     from jax.experimental import topologies
@@ -158,9 +166,11 @@ def test_hist_kernel_compiles_at_the_categorical_cells_shapes(
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     on_chip = SingleDeviceSharding(topo.devices[0])
-    rows, feats, n_bins_tot = 10_000_000, 8, 301
-    Nb, Fb, _T = pallas_hist._plan(n_nodes, feats, n_bins_tot)
+    feats, n_bins_tot = 8, 301
+    Nb, Fb, T = pallas_hist._plan(n_nodes, feats, n_bins_tot, bins_used)
     assert (Fb, -(-n_nodes // Nb)) == (feats, blocks)
+    if bins_used is not None:
+        assert T > pallas_hist._plan(n_nodes, feats, n_bins_tot)[2]
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
@@ -171,7 +181,7 @@ def test_hist_kernel_compiles_at_the_categorical_cells_shapes(
         spec((feats, rows), jnp.int16), spec((rows,), jnp.int32),
         spec((rows,), jnp.float32), spec((rows,), jnp.float32),
         spec((rows,), jnp.float32),
-        n_nodes=n_nodes, n_bins_tot=n_bins_tot).compile()
+        n_nodes=n_nodes, n_bins_tot=n_bins_tot, bins_used=bins_used).compile()
     assert "tpu_custom_call" in exe.as_text()
     assert counted.value == before + 1
 

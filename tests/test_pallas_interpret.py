@@ -7,9 +7,10 @@ Covers the MXU precision modes: "hilo" (2 bf16 digits, default), "hilo3"
 XLA segment-sum ground truth — and every case the body distinguishes: the
 digits side by side in one product or a pass each, int8 and int16 bins,
 feature blocks narrower than the frame, rows short of a tile, one node,
-several node blocks, a call under ``vmap``; ``_plan``'s VMEM account; and the
+several node blocks, a call under ``vmap``; ``_plan``'s VMEM account; the
 last level's per-node totals as the kernel's one-feature call
-(``tree._node_totals``).
+(``tree._node_totals``); and ``bins_used``: the one-hot rows a column's bins
+cannot match are skipped, the histograms are the dense call's.
 """
 
 import numpy as np
@@ -366,5 +367,189 @@ def test_steps_per_call_reads_the_quotient():
     class Parent:                                # no such counter: left out
         after = {"metrics": [
             ("h2o3_route_levels_total", {"path": "select"}, 6.0)]}
+
+    assert metric.read(Parent) is None
+
+
+# -- bins_used: only the one-hot rows a column's bins can match ----------------
+
+#: the categorical airline cell's columns (Month, DayofMonth, DayOfWeek,
+#: UniqueCarrier, Origin, Dest, DepTime, Distance) at 301 engine bins
+CELL_USED, CELL_BT = (12, 31, 7, 22, 300, 300, 100, 100), 301
+
+
+def _data_inside(rng, R, used, Bt, N, dtype):
+    """Bins drawn inside what each column declares, 3% of them missing."""
+    cols = [np.where(rng.random(R) < 0.03, Bt - 1, rng.integers(0, u, R))
+            for u in used]
+    _b, node, g, h, w = _data(rng, R, 1, 1, N)
+    return jnp.asarray(np.stack(cols).astype(dtype)), node, g, h, w
+
+
+def _pin_tile(monkeypatch, rows):
+    """Dense and ragged calls plan different row tiles; the same tile sums
+    the same rows in one MXU accumulation, so the sums agree bit for bit."""
+    monkeypatch.setattr(pallas_hist, "_TILE_MAX", rows)
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("mode", ["hilo", "hilo3"])
+@pytest.mark.parametrize("n_nodes", [1, 16, 64, 256])
+@pytest.mark.parametrize("dtype,used,Bt", [
+    (np.int16, CELL_USED, CELL_BT),
+    (np.int8, (3, 40, 9, 101, 17), 102)])
+def test_bins_used_equals_the_dense_call(monkeypatch, mode, n_nodes, dtype,
+                                         used, Bt, rng):
+    _set_mode(monkeypatch, mode)
+    F, S = len(used), -(-Bt // 8) * 8
+    data = _data_inside(rng, 1300, used, Bt, n_nodes, dtype)
+    call = lambda **kw: np.asarray(
+        pallas_hist.hist_pallas(*data, n_nodes, Bt, **kw))
+    assert (pallas_hist._plan(n_nodes, F, Bt, used)[2]
+            >= pallas_hist._plan(n_nodes, F, Bt)[2])
+    dense, ragged = call(), call(bins_used=used)     # each at its own tile
+    np.testing.assert_allclose(ragged, dense, rtol=5e-4, atol=5e-3)
+    _pin_tile(monkeypatch, 256)
+    assert pallas_hist._plan(n_nodes, F, Bt, used)[2] == 256
+    dense, ragged = call(), call(bins_used=used)
+    assert np.abs(dense).sum() > 0
+    np.testing.assert_array_equal(ragged.view(np.int32), dense.view(np.int32))
+    # what the call skipped reads exactly 0.0: past a column's 8-row groups,
+    # short of the group that holds the missing bin
+    by_bin = ragged.reshape(F, n_nodes, Bt, 3)
+    skipped = [(f, -(-u // 8) * 8, S - 8) for f, u in enumerate(used)
+               if -(-u // 8) * 8 < S - 8]
+    assert skipped
+    for f, lo, hi in skipped:
+        assert not by_bin[f, :, lo:hi].any()
+    jax.clear_caches()
+
+
+def test_a_bin_outside_what_was_declared_is_dropped(monkeypatch, rng):
+    """The contract's other side, pinned so that nobody leans on it: a bin
+    id past a column's declared range and short of the missing bin's group
+    is in no histogram (the dense call counts it)."""
+    _set_mode(monkeypatch, "hilo")
+    binned_T = jnp.full((2, 512), 50, jnp.int16)
+    ones = jnp.ones(512, jnp.float32)
+    node = jnp.zeros(512, jnp.int32)
+    call = lambda **kw: np.asarray(pallas_hist.hist_pallas(
+        binned_T, node, ones, ones, ones, 1, 101, **kw)).reshape(2, 101, 3)
+    assert call()[:, 50, 2].tolist() == [512.0, 512.0]
+    assert call(bins_used=(10, 100))[:, 50, 2].tolist() == [0.0, 512.0]
+
+
+def _jaxpr(F, R, Bt, N, dtype, **kw):
+    shapes = (jax.ShapeDtypeStruct((F, R), dtype),
+              jax.ShapeDtypeStruct((R,), jnp.int32)) + (
+                  jax.ShapeDtypeStruct((R,), jnp.float32),) * 3
+    return str(jax.make_jaxpr(
+        lambda *a: pallas_hist.hist_pallas(*a, N, Bt, **kw))(*shapes))
+
+
+@pytest.mark.parametrize("F,Bt,dtype,used", [
+    (28, 65, jnp.int8, (64,) * 28),            # the HIGGS cells: every
+    (28, 257, jnp.int16, (256,) * 28),         # column holds nbins
+    (3, 65, jnp.int8, (64, 60, 57))])          # 64 rows + the missing group
+def test_a_tuple_that_skips_nothing_is_the_dense_call(F, Bt, dtype, used):
+    """All a call knows of ``bins_used`` is each position's first range, and
+    here that is the dense one: the same plan and, to the last equation, the
+    same program (the guard of the cells whose columns all hold ``nbins``)."""
+    S = -(-Bt // 8) * 8
+    assert pallas_hist._block_first_rows(S, F, F, used) == (S,) * F
+    assert pallas_hist._plan(16, F, Bt, used) == pallas_hist._plan(16, F, Bt)
+    assert (_jaxpr(F, 9000, Bt, 16, dtype, bins_used=used)
+            == _jaxpr(F, 9000, Bt, 16, dtype))
+    assert (_jaxpr(F, 9000, Bt, 16, dtype, bins_used=(8,) + used[1:])
+            != _jaxpr(F, 9000, Bt, 16, dtype))
+    with pytest.raises(ValueError, match="bins_used names"):
+        pallas_hist._plan(16, F, Bt, used[1:])
+
+
+@pytest.mark.parametrize("n_nodes,dense_tile,tile", [
+    (1, 3328, 4096), (16, 3328, 4096), (32, 1664, 4096), (64, 768, 2176),
+    (256, 768, 2176)])
+def test_plan_at_the_cells_columns(monkeypatch, n_nodes, dense_tile, tile):
+    """944 one-hot rows a row where dense streams 2,432: the row tile grows
+    with what ``_STEP_MATMULS`` then allows, inside the VMEM account."""
+    monkeypatch.setattr(pallas_hist, "_MXU_MODE", "hilo")
+    Nb, Fb, T = pallas_hist._plan(n_nodes, 8, CELL_BT, CELL_USED)
+    assert (Fb, T) == (8, tile)
+    assert pallas_hist._plan(n_nodes, 8, CELL_BT) == (Nb, 8, dense_tile)
+    S = 304
+    rows = pallas_hist._streamed(
+        S, pallas_hist._block_first_rows(S, Fb, 8, CELL_USED))
+    assert rows == [24, 40, 16, 32, 304, 304, 112, 112] and sum(rows) == 944
+    # stacked to left-hand sides of at least _GROUP_ROWS rows, but the tail
+    assert pallas_hist._groups(rows) == [(0, 6), (6, 8)]
+    blocks = -(-n_nodes // Nb)
+    assert (pallas_hist._vmem_bytes(Nb, Fb, T, S, blocks, rows)
+            <= pallas_hist._VMEM_BUDGET)
+
+
+def test_feature_blocks_stream_the_most_a_position_needs(monkeypatch, rng):
+    """The body is one code for every feature block: position ``j`` of a
+    block streams what the largest of features ``j, j + Fb, ...`` needs."""
+    _set_mode(monkeypatch, "hilo")
+    F, Bt = 70, 102
+    used = tuple(int(u) for u in rng.integers(2, 100, F))
+    first = pallas_hist._block_first_rows(104, 32, F, used)
+    monkeypatch.setattr(pallas_hist, "_VMEM_BUDGET", pallas_hist._vmem_bytes(
+        8, 32, 128, 104, 3, pallas_hist._streamed(104, first)))
+    # three blocks of 32, 26 features padded onto the last
+    assert pallas_hist._plan(8, F, Bt, used) == (8, 32, 128)
+    assert first[0] == pallas_hist._first_rows(
+        104, max(used[0], used[32], used[64]))
+    assert first[31] == pallas_hist._first_rows(
+        104, max(used[31], used[63], used[69]))
+    assert pallas_hist._first_rows(104, 88) == 88       # 88 + 8 rows of 104
+    assert pallas_hist._first_rows(104, 89) == 104      # nothing to skip
+    data = _data_inside(rng, 600, used, Bt, 8, np.int8)
+    want = _level_histograms(data[0].T, *data[1:], 8, Bt)
+    got = pallas_hist.hist_pallas(*data, 8, Bt, bins_used=used)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=5e-4,
+                               atol=5e-3)
+
+
+def _onehot_rows():
+    rows = telemetry.HIST_ONEHOT_ROWS
+    return (rows.labels(kind="streamed").value,
+            rows.labels(kind="dense").value)
+
+
+def test_onehot_rows_are_counted_where_a_call_is_traced(monkeypatch, rng):
+    """One traced call at the cell's shape: 944 of 2,432; the same call again
+    is a cached trace and adds nothing; a dense call streams what it has."""
+    _set_mode(monkeypatch, "hilo")
+    jax.clear_caches()
+    data = _data_inside(rng, 300, CELL_USED, CELL_BT, 4, np.int16)
+    before = _onehot_rows()
+    pallas_hist.hist_pallas(*data, 4, CELL_BT, bins_used=CELL_USED)
+    assert tuple(a - b for a, b in zip(_onehot_rows(), before)) == (944, 2432)
+    pallas_hist.hist_pallas(*data, 4, CELL_BT, bins_used=CELL_USED)
+    assert tuple(a - b for a, b in zip(_onehot_rows(), before)) == (944, 2432)
+    pallas_hist.hist_pallas(*data, 4, CELL_BT)
+    assert tuple(a - b for a, b in zip(_onehot_rows(), before)) == (
+        944 + 2432, 2 * 2432)
+
+
+def test_onehot_rows_share_reads_the_quotient():
+    from benchmark.plugins import load
+    metric = load("layer_metrics", "kernel.hist_onehot_rows_share")
+    assert (metric.LAYER, metric.UNIT, metric.MOVES) == (
+        "kernel", "%", "train_work_per_s_chip")
+
+    class Reading:                 # ten level calls and the totals' call
+        after = {"metrics": [
+            ("h2o3_hist_onehot_rows_total", {"kind": "streamed"},
+             10 * 944.0 + 1024.0),
+            ("h2o3_hist_onehot_rows_total", {"kind": "dense"},
+             10 * 2432.0 + 1024.0),
+            ("h2o3_hist_grid_steps_total", {}, 7.0)]}
+
+    assert metric.read(Reading) == pytest.approx(41.288, abs=1e-3)
+
+    class Parent:                                # no such counter: left out
+        after = {"metrics": [("h2o3_hist_grid_steps_total", {}, 7.0)]}
 
     assert metric.read(Parent) is None
