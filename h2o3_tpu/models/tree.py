@@ -41,7 +41,8 @@ from jax.sharding import PartitionSpec as P
 
 from h2o3_tpu.ops.quantile import cat_bins_for_codes
 from h2o3_tpu.utils.costs import accounted_jit
-from h2o3_tpu.utils.telemetry import ROUTE_LEVELS, SPLIT_LEVELS
+from h2o3_tpu.utils.telemetry import (ROUTE_LEVELS, SIBLING_LEVELS,
+                                      SPLIT_LEVELS)
 
 
 @dataclasses.dataclass
@@ -366,6 +367,22 @@ def _lookup_f32(table, idx):
     return lax.bitcast_convert_type(bits, jnp.float32)
 
 
+def _sibling_slots(node_local, chosen_left):
+    """Each row's slot in a level's histogram call under sibling
+    subtraction: its parent's index where the row sits in the child the
+    parent chose to histogram (``chosen_left`` [P]: the left one), else -1
+    (the other child, and frozen rows). The chosen child's id is read by
+    :func:`_lookup`, so a level of up to :data:`_SELECT_MAX_ENTRIES`
+    parents holds no per-row gather, under ``vmap`` too, where a plain
+    ``chosen[par]`` becomes a batched gather."""
+    P = chosen_left.shape[0]
+    chosen = jnp.arange(P) * 2 + jnp.where(chosen_left, 0, 1).astype(jnp.int32)
+    act = node_local >= 0
+    par = jnp.where(act, node_local // 2, 0)
+    at_chosen = act & (node_local == _lookup(chosen, par))
+    return jnp.where(at_chosen, par, -1)
+
+
 def _row_splits(node_local, feat, t, na_left, do_split, n_features: int,
                 n_bins: int):
     """Each row's split, read from its node's: ``(split, na_left, t,
@@ -495,13 +512,11 @@ def _grow_tree_device(binned, binned_T, edges, g, h, w, feat_mask, key,
                                         bins_used=bins_used)
                 else:
                     P = N // 2
-                    # chosen child id per parent; rows elsewhere mask to -1
-                    chosen = (jnp.arange(P) * 2
-                              + jnp.where(chosen_left, 0, 1).astype(jnp.int32))
-                    act = node_local >= 0
-                    par = jnp.where(act, node_local // 2, 0)
-                    at_chosen = act & (node_local == chosen[par])
-                    node_slot = jnp.where(at_chosen, par, -1)
+                    if P <= _SELECT_MAX_ENTRIES:
+                        SIBLING_LEVELS.labels(path="select").inc()
+                    else:
+                        SIBLING_LEVELS.labels(path="gather").inc()
+                    node_slot = _sibling_slots(node_local, chosen_left)
                     part = _histograms(binned, binned_T, node_slot, g, h, w,
                                        P, Bt, mesh=mesh, bins_used=bins_used)
                     part4 = part.reshape(F, P, Bt, 3)

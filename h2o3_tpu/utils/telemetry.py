@@ -569,6 +569,15 @@ SPLIT_LEVELS = METRICS.counter(
 ROUTE_LEVELS = METRICS.counter(
     "h2o3_route_levels", "tree levels traced, by row-routing path", ("path",))
 
+# the tree engine's sibling subtraction (models/tree.py ``_grow_tree_device``
+# through ``_sibling_slots``): one increment a level d >= 1 of a tree
+# program, counted at TRACE time, by how the level reads each row's parent's
+# chosen child: ``select`` up to tree._SELECT_MAX_ENTRIES parents, ``gather``
+# past it (``tree._lookup``'s two forms).
+SIBLING_LEVELS = METRICS.counter(
+    "h2o3_sibling_levels", "tree levels traced, by sibling-subtraction path",
+    ("path",))
+
 # the Pallas histogram kernel (ops/pallas_hist.py ``hist_pallas``), counted
 # where a call is TRACED (jit traces a signature once: two levels of one
 # shape, or a cached program, add nothing): one increment a traced call, by

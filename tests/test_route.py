@@ -8,6 +8,11 @@ whatever the table's size); the engine now reads them by broadcast
 compare-and-select over the small axis. It is integer work on the same
 bins, thresholds and masks: the result must equal the gathers' bit for bit,
 and the numeric route must stay free of ``gather``.
+
+The sibling subtraction reads each row's parent's chosen child the same way
+(``_sibling_slots`` through ``_lookup``), where ``chosen[par]`` was a per-row
+index that ``vmap`` turns into a batched gather: the same bits, and no gather
+under the class axis up to the crossover.
 """
 
 from functools import partial
@@ -20,7 +25,7 @@ import pytest
 from h2o3_tpu.models import tree
 from h2o3_tpu.models.tree import TreeParams, grow_trees_batched
 from h2o3_tpu.ops.quantile import bin_dtype
-from h2o3_tpu.utils.telemetry import ROUTE_LEVELS
+from h2o3_tpu.utils.telemetry import ROUTE_LEVELS, SIBLING_LEVELS
 
 PAST_CROSSOVER = 2 * tree._SELECT_MAX_ENTRIES
 
@@ -186,11 +191,14 @@ def fresh_programs():
     drop()
 
 
-@pytest.mark.parametrize("kind,depth", [
-    ("numeric", 3), ("numeric", 6), ("categorical", 4), ("mono", 4),
-    ("reach", 4), ("multinomial", 3)])
-def test_trees_bit_equal_with_routing_by_gather(kind, depth, monkeypatch,
-                                                fresh_programs):
+TREE_KINDS = [("numeric", 3), ("numeric", 6), ("categorical", 4), ("mono", 4),
+              ("reach", 4), ("multinomial", 3)]
+
+
+def _assert_trees_bit_equal_with(name, reference, kind, depth, monkeypatch,
+                                 fresh_programs):
+    """Grow with the engine, then with ``tree.<name>`` swapped for its plain
+    ``reference``: every tree array and the predictions bit for bit."""
     args, kw = _frame(kind)
     params = TreeParams(max_depth=depth, nbins=32, min_rows=2.0)
     fmask = jnp.ones(args[0].shape[1], bool)
@@ -202,7 +210,7 @@ def test_trees_bit_equal_with_routing_by_gather(kind, depth, monkeypatch,
                 for t in trees], np.asarray(preds)
 
     new_trees, new_preds = grow()
-    monkeypatch.setattr(tree, "_route_rows", route_rows_by_gather)
+    monkeypatch.setattr(tree, name, reference)
     fresh_programs()
     old_trees, old_preds = grow()
 
@@ -215,6 +223,48 @@ def test_trees_bit_equal_with_routing_by_gather(kind, depth, monkeypatch,
             np.testing.assert_array_equal(a.view(np.uint8), b.view(np.uint8))
     np.testing.assert_array_equal(new_preds.view(np.int32),
                                   old_preds.view(np.int32))
+
+
+@pytest.mark.parametrize("kind,depth", TREE_KINDS)
+def test_trees_bit_equal_with_routing_by_gather(kind, depth, monkeypatch,
+                                                fresh_programs):
+    _assert_trees_bit_equal_with("_route_rows", route_rows_by_gather, kind,
+                                 depth, monkeypatch, fresh_programs)
+
+
+def sibling_slots_by_gather(node_local, chosen_left):
+    """The plain reference: each row's parent's chosen child read by a
+    per-row index into the table (``chosen[par]``), which ``vmap`` makes a
+    batched gather."""
+    P = chosen_left.shape[0]
+    chosen = jnp.arange(P) * 2 + jnp.where(chosen_left, 0, 1).astype(jnp.int32)
+    act = node_local >= 0
+    par = jnp.where(act, node_local // 2, 0)
+    at_chosen = act & (node_local == chosen[par])
+    return jnp.where(at_chosen, par, -1)
+
+
+@pytest.mark.parametrize("kind,depth", TREE_KINDS)
+def test_trees_bit_equal_with_sibling_by_gather(kind, depth, monkeypatch,
+                                                fresh_programs):
+    _assert_trees_bit_equal_with("_sibling_slots", sibling_slots_by_gather,
+                                 kind, depth, monkeypatch, fresh_programs)
+
+
+@pytest.mark.parametrize("n_parents", [1, 8, 256, PAST_CROSSOVER])
+def test_sibling_slots_equal_the_gather_under_vmap(n_parents):
+    """Seven class trees' levels at once: frozen rows, both children of
+    every parent, either child chosen."""
+    K, rows = 7, 2999
+    rng = np.random.default_rng(n_parents)
+    node = rng.integers(0, 2 * n_parents, size=(K, rows)).astype(np.int32)
+    node[rng.random((K, rows)) < 0.15] = -1
+    chosen_left = jnp.asarray(rng.random((K, n_parents)) < 0.5)
+    node = jnp.asarray(node)
+    got = np.asarray(jax.vmap(tree._sibling_slots)(node, chosen_left))
+    want = np.asarray(jax.vmap(sibling_slots_by_gather)(node, chosen_left))
+    np.testing.assert_array_equal(got, want)
+    assert (got >= 0).any() and (got < 0).any()
 
 
 # -- what a CPU can hold for the chip ----------------------------------------
@@ -251,6 +301,31 @@ def test_leaf_lookup_lowers_to_no_gather_up_to_the_crossover():
     assert "gather" in lower(PAST_CROSSOVER)
 
 
+def _lowered_vmapped_slots(fn, n_parents: int, classes: int = 7,
+                           rows: int = 4096):
+    s = jax.ShapeDtypeStruct
+    return jax.jit(jax.vmap(fn)).lower(
+        s((classes, rows), jnp.int32),
+        s((classes, n_parents), jnp.bool_)).as_text()
+
+
+@pytest.mark.parametrize("n_parents", [1, 8, 256, tree._SELECT_MAX_ENTRIES])
+def test_sibling_slots_lower_to_no_gather_under_vmap(n_parents):
+    """A multinomial round's class trees under ``vmap``: the parents' table
+    read by compare-and-select up to the crossover, no batched gather (about
+    9 ns a row and class on the v5e, 81% of the covtype build)."""
+    text = _lowered_vmapped_slots(tree._sibling_slots, n_parents)
+    assert "gather" not in text
+    assert "compare" in text and "select" in text and "reduce" in text
+
+
+def test_sibling_slots_gather_past_the_crossover():
+    assert "gather" in _lowered_vmapped_slots(tree._sibling_slots,
+                                              PAST_CROSSOVER)
+    # the guard guards: the form this replaced gathers at 8 parents
+    assert "gather" in _lowered_vmapped_slots(sibling_slots_by_gather, 8)
+
+
 # -- the counter --------------------------------------------------------------
 
 def _levels(path: str) -> float:
@@ -277,3 +352,50 @@ def test_counter_names_the_path_past_the_crossover(monkeypatch, fresh_programs):
     grow_trees_batched(*args, TreeParams(max_depth=5, nbins=32), fmask)
     assert _levels("select") - select == 4      # N = 1, 2, 4, 8
     assert _levels("gather") - gather == 1      # N = 16
+
+
+def _sibling(path: str) -> float:
+    return SIBLING_LEVELS.labels(path=path).value
+
+
+def test_sibling_counter_rises_by_depth_less_one_a_traced_tree(fresh_programs):
+    args, kw = _frame("numeric")
+    fmask = jnp.ones(args[0].shape[1], bool)
+    select, gather = _sibling("select"), _sibling("gather")
+    grow_trees_batched(*args, TreeParams(max_depth=5, nbins=32), fmask)
+    assert _sibling("select") - select == 4     # levels 1-4; level 0 has none
+    assert _sibling("gather") - gather == 0
+    grow_trees_batched(*args, TreeParams(max_depth=5, nbins=32), fmask)
+    assert _sibling("select") - select == 4     # a cached program adds nothing
+
+
+def test_sibling_counter_names_the_gather_past_the_crossover(monkeypatch,
+                                                             fresh_programs):
+    args, kw = _frame("multinomial")
+    fmask = jnp.ones(args[0].shape[1], bool)
+    monkeypatch.setattr(tree, "_SELECT_MAX_ENTRIES", 4)
+    select, gather = _sibling("select"), _sibling("gather")
+    grow_trees_batched(*args, TreeParams(max_depth=5, nbins=32), fmask)
+    # one trace for the three class trees under vmap, as the route counts
+    assert _sibling("select") - select == 3     # P = 1, 2, 4
+    assert _sibling("gather") - gather == 1     # P = 8
+
+
+def test_sibling_select_share_reads_the_counter_at_the_windows_end():
+    from benchmark.plugins import load
+    metric = load("layer_metrics", "program.sibling_select_share")
+    assert (metric.LAYER, metric.UNIT, metric.MOVES) == (
+        "program", "%", "train_work_per_s_chip")
+    name = "h2o3_sibling_levels_total"
+
+    class Reading:
+        after = {"metrics": [(name, {"path": "select"}, 8.0),
+                             (name, {"path": "gather"}, 1.0)]}
+
+    assert metric.read(Reading) == pytest.approx(100 * 8 / 9)
+
+    class Parent:                                # no such counter: left out
+        after = {"metrics": [
+            ("h2o3_route_levels_total", {"path": "select"}, 6.0)]}
+
+    assert metric.read(Parent) is None
